@@ -12,7 +12,8 @@ import jax.numpy as jnp
 from repro.core.base_kernels import SquareExponential
 from repro.core.octile import octile_decompose
 from repro.kernels.xmv_block_sparse import pack_graph, xmv_block_sparse
-from repro.kernels.xmv_dense import xmv_dense
+from repro.kernels.xmv_block_sparse import to_tiles
+from repro.kernels.xmv_dense import DENSE_TILE, xmv_dense
 from .common import row, time_fn
 
 EK = SquareExponential(1.0, rank=10)
@@ -42,7 +43,7 @@ def run(n: int = 64, occupancies=(2, 8, 16, 32, 56)) -> list[str]:
         P = jnp.asarray(rng.random((n, n), np.float32))
         Aj, Ej = jnp.asarray(A), jnp.asarray(E)
         us_d = time_fn(lambda a, e, p: xmv_dense(a, e, a, e, p, EK),
-                       Aj, Ej, P, iters=3)
+                       Aj, Ej, to_tiles(P, DENSE_TILE), iters=3)
         p1 = pack_graph(A, E)
         us_s = time_fn(lambda pk, p: xmv_block_sparse(pk, pk, p, EK),
                        p1, P, iters=3)

@@ -34,7 +34,7 @@ from repro.core.base_kernels import Constant, SquareExponential
 from repro.core.graph import Graph, batch_from_graphs
 from repro.core.mgk import mgk_pairs_sparse
 from repro.kernels.ops import row_panel_packs_for_batch
-from repro.kernels.xmv_block_sparse import xmv_row_panel_batched
+from repro.kernels.xmv_block_sparse import to_tiles, xmv_row_panel_batched
 from .common import row, time_fn
 
 VK = Constant(1.0)
@@ -157,7 +157,8 @@ def run(out_path: str = "BENCH_pcg.json", B: int = 4, n: int = 32,
                                     pack_dtype=jnp.bfloat16)
     rng = np.random.default_rng(seed)
     nn = g1.adjacency.shape[1]
-    P = jnp.asarray(rng.random((B, nn, nn)).astype(np.float32))
+    P = to_tiles(jnp.asarray(rng.random((B, nn, nn)).astype(np.float32)),
+                 pf1.tile)
     yf = xmv_row_panel_batched(pf1, pf2, P, EK, mode="mxu")
     yb = xmv_row_panel_batched(pb1, pb2, P, EK, mode="mxu")
     rel = float(np.max(np.abs(np.asarray(yf - yb)))

@@ -53,7 +53,8 @@ from repro.kernels.ops import packs_for_batch, row_panel_packs_for_batch, \
     xmv_block_sparse_unrolled
 from repro.kernels.xmv_block_sparse import xmv_block_sparse_batched, \
     xmv_gram_tile, xmv_row_panel_batched
-from repro.kernels.xmv_dense import xmv_dense_batched
+from repro.kernels.xmv_block_sparse import to_tiles
+from repro.kernels.xmv_dense import DENSE_TILE, xmv_dense_batched
 from .common import row, time_fn
 
 VK = KroneckerDelta(0.5, n_labels=8)
@@ -107,12 +108,13 @@ def _sparse_arms(g1, g2, P, iters, tile: int = 8, with_unrolled=True):
             P, iters=iters)
     out["us_per_matvec_batched"] = time_fn(
         lambda P: xmv_block_sparse_batched(p1, p2, P, EK), P, iters=iters)
+    Pt = to_tiles(P, tile)     # the row-panel kernels' tile-major order
     out["us_per_matvec_row_panel"] = time_fn(
         lambda P: xmv_row_panel_batched(r1, r2, P, EK, mode="elementwise"),
-        P, iters=iters)
+        Pt, iters=iters)
     out["us_per_matvec_row_panel_mxu"] = time_fn(
         lambda P: xmv_row_panel_batched(r1w, r2w, P, EK, mode="mxu"),
-        P, iters=iters)
+        Pt, iters=iters)
     return out
 
 
@@ -172,8 +174,10 @@ def run(out_path: str = "BENCH_xmv.json", sizes=(2, 8, 16),
     B = sizes[-1]
     g1, g2 = _bucket(B, pad_to)
     n = g1.adjacency.shape[1]
-    P = jnp.asarray(rng.random((B, n, n)).astype(np.float32))
-    diag = jnp.asarray(rng.random((B, n, n)).astype(np.float32) + 1.0)
+    P = to_tiles(jnp.asarray(rng.random((B, n, n)).astype(np.float32)),
+                 DENSE_TILE)
+    diag = to_tiles(jnp.asarray(rng.random((B, n, n)).astype(np.float32)
+                                + 1.0), DENSE_TILE)
     args = (g1.adjacency, g1.edge_labels, g2.adjacency, g2.edge_labels)
 
     def unfused(P):
@@ -268,8 +272,9 @@ def run_gram(out_path: str = "BENCH_gram.json",
         g1u, g2u, g1f, g2f = _gram_batches(Bi, Bj, pad_to)
         n = g1u.adjacency.shape[1]
         m = g2u.adjacency.shape[1]
-        P4 = jnp.asarray(rng.random((Bi, Bj, n, m)).astype(np.float32))
-        Pf = P4.reshape(Bi * Bj, n, m)
+        P4 = to_tiles(jnp.asarray(rng.random((Bi, Bj, n, m))
+                                  .astype(np.float32)), 8)
+        Pf = P4.reshape((Bi * Bj,) + P4.shape[2:])
         # per-axis packs (Bi + Bj) vs per-pair stacked packs (Bi*Bj)
         a1 = row_panel_packs_for_batch(g1u)
         a2 = row_panel_packs_for_batch(g2u)

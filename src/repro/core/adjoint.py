@@ -57,7 +57,8 @@ import jax.numpy as jnp
 from .base_kernels import BaseKernel, Constant, ParamDerivative
 from .graph import GraphBatch
 from .mgk import _make_matvec, _make_precond_apply, _make_sparse_matvec, \
-    _outer_flat, adaptive_route, build_product_system, stop_prob_override
+    _outer_flat, _tile_major_precond, adaptive_route, build_product_system, \
+    kernel_tile, stop_prob_override, tile_major_system, to_tile_major
 from .pcg import adjoint_solve, pcg_solve
 from .xmv import xmv_lowrank_precomputed, weighted_operand_grads, \
     weighted_operands
@@ -161,12 +162,20 @@ def mgk_value_fn(
                 " (legacy TilePacks have no differentiable path)")
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
+    # Pallas backends solve (forward AND adjoint) in the kernels'
+    # tile-major vector order; every product-space vector built below
+    # goes through `tiled` (identity for node-major backends)
+    t = kernel_tile("sparse" if sparse else method, packs1)
+
+    def tiled(v):
+        return v if t is None else to_tile_major(v, n, m, t)
+
     pf1, pf2 = precond_factors if precond_factors is not None \
         else (None, None)
-    papply = _make_precond_apply(precond, g1, g2, vertex_kernel,
-                                 edge_kernel, (B, n, m),
-                                 gram_tile=gram_tile, factors1=pf1,
-                                 factors2=pf2, kron_rank=kron_rank)
+    papply = _tile_major_precond(
+        _make_precond_apply(precond, g1, g2, vertex_kernel, edge_kernel,
+                            (B, n, m), gram_tile=gram_tile, factors1=pf1,
+                            factors2=pf2, kron_rank=kron_rank), n, m, t)
     solve_kw = dict(tol=tol, max_iter=max_iter, fixed_iters=fixed_iters,
                     variant=pcg_variant, precond_apply=papply)
 
@@ -188,8 +197,9 @@ def mgk_value_fn(
 
     def _system(theta):
         tv, _, q = _parts(theta)
-        sys_ = build_product_system(g1, g2, vertex_kernel, theta_v=tv,
-                                    q=q)
+        sys_ = tile_major_system(
+            build_product_system(g1, g2, vertex_kernel, theta_v=tv, q=q),
+            n, m, t)
         return sys_, _build_mv(theta, sys_)
 
     def _solve(theta):
@@ -200,10 +210,11 @@ def mgk_value_fn(
         return sol, sys_, mv
 
     # -- the adjoint backward pass --------------------------------------
-    def _edge_grads(te, x_mat, names):
+    def _edge_grads(te, x, names):
         """{name: raw XMV of x with kappa -> ∂kappa/∂θ_name} for ALL
         edge parameters: the sparsity-preserving half of λᵀ (∂A/∂θ) x,
-        [B, n*m] per name. Parameter-independent operand derivation
+        [B, n*m] per name (x and the result in the solve's vector
+        order). Parameter-independent operand derivation
         (device_weighted_pack, weighted operands) is hoisted out of the
         per-name loop — it already carries every parameter's slice."""
         if sparse:
@@ -239,21 +250,21 @@ def mgk_value_fn(
                         values_w=jnp.concatenate([p2.values_w, wg2],
                                                  axis=-3),
                         values_grad=None)
+                    tiles = (n // t, m // t, t, t)
                     if gram_tile is not None:
-                        Bi, Bj = gram_tile
                         y = xmv_gram_tile(
-                            c1, c2, x_mat.reshape(Bi, Bj, n, m),
+                            c1, c2, x.reshape(tuple(gram_tile) + tiles),
                             edge_kernel, mode="mxu")
                     else:
-                        y = xmv_row_panel_batched(c1, c2, x_mat,
-                                                  edge_kernel, mode="mxu")
+                        y = xmv_row_panel_batched(
+                            c1, c2, x.reshape((B,) + tiles), edge_kernel,
+                            mode="mxu")
                     out[name] = y.reshape(B, -1)
                 return out
-            x_flat = x_mat.reshape(B, -1)
             return {name: _make_sparse_matvec(
                 None, packs1, packs2, ParamDerivative(edge_kernel, name),
                 "elementwise", (B, n, m), theta_e=te, raw=True,
-                gram_tile=gram_tile)(x_flat)
+                gram_tile=gram_tile)(x)
                 for name in names}
         if method == "lowrank":
             wo = lambda a, e: weighted_operands(a, e, edge_kernel,  # noqa
@@ -264,15 +275,15 @@ def mgk_value_fn(
             wap = jax.vmap(wo)(g2.adjacency, g2.edge_labels)
             dwa = jax.vmap(dwo)(g1.adjacency, g1.edge_labels)
             dwap = jax.vmap(dwo)(g2.adjacency, g2.edge_labels)
+            x_mat = x.reshape(B, n, m)
             return {name: (
                 jax.vmap(xmv_lowrank_precomputed)(dwa[name], wap, x_mat)
                 + jax.vmap(xmv_lowrank_precomputed)(wa, dwap[name],
                                                     x_mat)
             ).reshape(B, -1) for name in names}
-        x_flat = x_mat.reshape(B, -1)
         return {name: _make_matvec(
             g1, g2, None, ParamDerivative(edge_kernel, name), method,
-            chunk, theta_e=te, raw=True)(x_flat) for name in names}
+            chunk, theta_e=te, raw=True)(x) for name in names}
 
     def _pair_grads(theta, x, ct, sys_, mv):
         """Per-pair hyperparameter gradients, [B] leaves mirroring
@@ -293,12 +304,12 @@ def mgk_value_fn(
             coeff = lam * x * sys_.dx / (sys_.vx * sys_.vx)
             grads["vertex"] = {
                 name: jnp.sum(
-                    coeff * dv[name].reshape(B, -1) * sys_.mask, axis=-1)
+                    coeff * tiled(dv[name].reshape(B, -1)) * sys_.mask,
+                    axis=-1)
                 for name in theta["vertex"]}
         if "edge" in theta:
-            x_mat = x.reshape(B, n, m)
             # ∂A = -(A_x ∘ ∂kappa E_x)  =>  -λᵀ(∂A)x = +λᵀ XMV_∂kappa(x)
-            ys = _edge_grads(te, x_mat, tuple(theta["edge"]))
+            ys = _edge_grads(te, x, tuple(theta["edge"]))
             grads["edge"] = {
                 name: jnp.sum(lam * ys[name], axis=-1)
                 for name in theta["edge"]}
@@ -308,7 +319,7 @@ def mgk_value_fn(
             g1q = stop_prob_override(g1, q)
             g2q = stop_prob_override(g2, q)
             # ∂dx = maskx (m ⊗ d' + d ⊗ m');  qx = q² maskx
-            dxq = sys_.mask * (
+            dxq = sys_.mask * tiled(
                 _outer_flat(g1.node_mask, g2q.degrees)
                 + _outer_flat(g1q.degrees, g2.node_mask))
             drhs = dxq * sys_.qx + sys_.dx * 2.0 * q * sys_.mask

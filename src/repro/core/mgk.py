@@ -27,7 +27,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .base_kernels import BaseKernel, Constant
+from .base_kernels import BaseKernel, Constant, expansion_covers
 from .graph import GraphBatch
 from .pcg import GuardSpec, MatvecFault, PCGResult, pcg_solve, \
     pcg_solve_segmented
@@ -66,6 +66,56 @@ class MGKResult(NamedTuple):
 def _outer_flat(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Batched Kronecker of vectors: [B, n], [B, m] -> [B, n*m]."""
     return (a[:, :, None] * b[:, None, :]).reshape(a.shape[0], -1)
+
+
+def to_tile_major(v: jnp.ndarray, n: int, m: int, t: int) -> jnp.ndarray:
+    """Product vectors ``[..., n*m]`` from node-major (ii' = i*m + i')
+    to the tile-major order of the Pallas kernels (``[nt, mt, t, t]``
+    flattened, ``kernels.xmv_block_sparse.to_tiles``)."""
+    from repro.kernels.xmv_block_sparse import to_tiles
+    lead = v.shape[:-1]
+    return to_tiles(v.reshape(lead + (n, m)), t).reshape(v.shape)
+
+
+def from_tile_major(v: jnp.ndarray, n: int, m: int, t: int) -> jnp.ndarray:
+    """Inverse of :func:`to_tile_major`."""
+    from repro.kernels.xmv_block_sparse import from_tiles
+    lead = v.shape[:-1]
+    return from_tiles(v.reshape(lead + (n // t, m // t, t, t))
+                      ).reshape(v.shape)
+
+
+def kernel_tile(method: str, packs1=None) -> int | None:
+    """Tile edge of the tile-major vector order that a backend's Pallas
+    kernel reads and writes, or None for node-major backends. Solves on
+    a tiled backend permute the product system ONCE at setup and run
+    PCG in that order end to end: PCG is elementwise work plus
+    reductions, so it is indifferent to the order (DESIGN.md §2)."""
+    if method == "pallas":
+        from repro.kernels.xmv_dense import DENSE_TILE
+        return DENSE_TILE
+    from repro.kernels.xmv_block_sparse import RowPanelPack
+    if method == "sparse" and isinstance(packs1, RowPanelPack):
+        return packs1.tile
+    return None
+
+
+def tile_major_system(sys_: "ProductSystem", n: int, m: int,
+                      t: int | None) -> "ProductSystem":
+    """The product system's vectors in the kernels' order (identity for
+    ``t=None``)."""
+    if t is None:
+        return sys_
+    return ProductSystem(*(to_tile_major(f, n, m, t) for f in sys_))
+
+
+def _tile_major_precond(papply, n: int, m: int, t: int | None):
+    """Wrap a node-major ``M^{-1}`` apply (the Kronecker factors act on
+    ``[n, m]`` matrices) for a tile-major solve."""
+    if papply is None or t is None:
+        return papply
+    return lambda r: to_tile_major(papply(from_tile_major(r, n, m, t)),
+                                   n, m, t)
 
 
 def stop_prob_override(g: GraphBatch, q) -> GraphBatch:
@@ -113,7 +163,11 @@ def _make_matvec(g1: GraphBatch, g2: GraphBatch, sys_: ProductSystem,
     returns the pure XMV application ``p -> (A_x .* E_x) p`` (no
     diagonal) — the building block of the adjoint parameter contraction
     ``λᵀ (∂A/∂θ) x``, which runs these same backends with kappa replaced
-    by ∂kappa/∂θ (core/adjoint.py, DESIGN.md §7)."""
+    by ∂kappa/∂θ (core/adjoint.py, DESIGN.md §7).
+
+    ``method="pallas"`` works in the tile-major order of
+    :func:`kernel_tile`: ``sys_`` and the vectors are already permuted
+    (:func:`tile_major_system`)."""
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
     diag = None if raw else sys_.dx / sys_.vx
@@ -133,20 +187,24 @@ def _make_matvec(g1: GraphBatch, g2: GraphBatch, sys_: ProductSystem,
 
     if method == "pallas":
         # imported lazily: kernels package depends on core
-        from repro.kernels import ops as kops
+        from repro.kernels.xmv_block_sparse import xmv_row_panel_batched
+        from repro.kernels.xmv_dense import dense_row_panels
         from .base_kernels import pack_theta
         tvec = None if theta_e is None else pack_theta(edge_kernel,
                                                        theta_e)
-        diag_nm = None if raw else diag.reshape(B, n, m)
+        t = kernel_tile(method)
+        shape = (B, n // t, m // t, t, t)
+        # the dense tiling of (A, E) is loop-invariant: built once here
+        pk1 = dense_row_panels(g1.adjacency, g1.edge_labels, t)
+        pk2 = dense_row_panels(g2.adjacency, g2.edge_labels, t)
+        diag_t = None if raw else diag.reshape(shape)
 
         def matvec(p_vec):
             # fused epilogue: the kernel itself emits diag*p - y, so one
             # launch IS the whole operator application (DESIGN.md §3)
-            P = p_vec.reshape(B, n, m)
-            out = kops.xmv_dense_batched(g1.adjacency, g1.edge_labels,
-                                         g2.adjacency, g2.edge_labels, P,
-                                         edge_kernel, diag=diag_nm,
-                                         theta=tvec)
+            out = xmv_row_panel_batched(pk1, pk2, p_vec.reshape(shape),
+                                        edge_kernel, diag=diag_t,
+                                        mode="elementwise", theta=tvec)
             return out.reshape(B, -1)
         return matvec
 
@@ -253,7 +311,11 @@ def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
     weighted operands ``values_w`` on device from the pack's structural
     fields (``device_weighted_pack``) — unless the pack already carries
     weights and ``theta_e`` is None, in which case the pack-time host
-    precompute is trusted as-is."""
+    precompute is trusted as-is.
+
+    Row-panel packs work in the tile-major order of :func:`kernel_tile`
+    (``sys_`` and the vectors already permuted); legacy TilePacks stay
+    node-major."""
     from repro.kernels.ops import RowPanelPack, device_weighted_pack, \
         xmv_block_sparse_batched, xmv_gram_tile, xmv_row_panel_batched
     from .base_kernels import pack_theta
@@ -282,34 +344,33 @@ def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
             tvec = pack_theta(edge_kernel, theta_e)
         mode = "mxu" if mxu else "elementwise"
 
+    if not row_panel:
+        diag_nm = None if raw else diag.reshape(B, n, m)
+
+        def matvec(p_vec):
+            out = xmv_block_sparse_batched(packs1, packs2,
+                                           p_vec.reshape(B, n, m),
+                                           edge_kernel, diag=diag_nm)
+            return out.reshape(B, -1)
+        return matvec
+
+    t = packs1.tile
     if gram_tile is not None:
         Bi, Bj = gram_tile
         if Bi * Bj != B:
             raise ValueError(
                 f"gram_tile {gram_tile} inconsistent with batch {B}")
-        diag_t = None if raw else diag.reshape(Bi, Bj, n, m)
-
-        def matvec(p_vec):
-            P = p_vec.reshape(Bi, Bj, n, m)
-            out = xmv_gram_tile(packs1, packs2, P, edge_kernel,
-                                diag=diag_t, mode=mode, theta=tvec)
-            return out.reshape(B, -1)
-        return matvec
-
-    diag_nm = None if raw else diag.reshape(B, n, m)
+        shape, xmv = (Bi, Bj, n // t, m // t, t, t), xmv_gram_tile
+    else:
+        shape, xmv = (B, n // t, m // t, t, t), xmv_row_panel_batched
+    diag_t = None if raw else diag.reshape(shape)
 
     def matvec(p_vec):
         # with diag: the fused in-kernel epilogue emits diag*p - y (the
         # full operator application); raw mode (diag None) emits +y, the
         # pure XMV the adjoint contraction needs
-        P = p_vec.reshape(B, n, m)
-        if row_panel:
-            out = xmv_row_panel_batched(packs1, packs2, P, edge_kernel,
-                                        diag=diag_nm, mode=mode,
-                                        theta=tvec)
-        else:
-            out = xmv_block_sparse_batched(packs1, packs2, P, edge_kernel,
-                                           diag=diag_nm)
+        out = xmv(packs1, packs2, p_vec.reshape(shape), edge_kernel,
+                  diag=diag_t, mode=mode, theta=tvec)
         return out.reshape(B, -1)
     return matvec
 
@@ -351,26 +412,36 @@ def mgk_pairs(
     override — see core/pcg.py and DESIGN.md §10. All three reach the
     solve as jit ARGUMENTS (guard/fault static, spd_margin traced), so
     arming them retraces instead of fighting cached traces."""
-    sys_ = build_product_system(g1, g2, vertex_kernel)
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
+    t = kernel_tile(method)
+    sys_ = tile_major_system(build_product_system(g1, g2, vertex_kernel),
+                             n, m, t)
     matvec = _make_matvec(g1, g2, sys_, edge_kernel, method, chunk)
     rhs = sys_.dx * sys_.qx
     diag = sys_.dx / sys_.vx         # paper Alg. 1 line 2
-    papply = _make_precond_apply(precond, g1, g2, vertex_kernel,
-                                 edge_kernel, (B, n, m),
-                                 kron_rank=kron_rank,
-                                 spd_margin=spd_margin)
+    papply = _tile_major_precond(
+        _make_precond_apply(precond, g1, g2, vertex_kernel, edge_kernel,
+                            (B, n, m), kron_rank=kron_rank,
+                            spd_margin=spd_margin), n, m, t)
     sol: PCGResult = pcg_solve(matvec, rhs, diag, tol=tol,
                                max_iter=max_iter, fixed_iters=fixed_iters,
                                variant=pcg_variant,
                                precond_apply=papply, guard=guard,
                                fault=fault)
     values = jnp.sum(sys_.px * sol.x, axis=-1)
-    nodal = sol.x.reshape(B, n, m) if return_nodal else None
+    nodal = _nodal(sol.x, n, m, t) if return_nodal else None
     return MGKResult(values=values, iterations=sol.iterations,
                      converged=sol.converged, nodal=nodal,
                      matvec_pairs=sol.matvec_pairs, status=sol.status)
+
+
+def _nodal(x: jnp.ndarray, n: int, m: int, t: int | None) -> jnp.ndarray:
+    """The [B, n, m] node-wise similarity from a (possibly tile-major)
+    solution vector."""
+    if t is not None:
+        x = from_tile_major(x, n, m, t)
+    return x.reshape(x.shape[0], n, m)
 
 
 def mgk_single(g1: GraphBatch, g2: GraphBatch, **kw) -> MGKResult:
@@ -413,7 +484,6 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
     paths. Returns (route, tile) with ``tile`` shrunk to the largest of
     {tile, 16, 8} dividing the bucket's padded size.
     """
-    import numpy as np
     rank = edge_kernel.feature_rank()
     n, m = g1.adjacency.shape[1], g2.adjacency.shape[1]
     while tile > 8 and (n % tile or m % tile):
@@ -421,12 +491,8 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
     dens = max(tile_density(g1, tile), tile_density(g2, tile))
     # the SE Taylor expansion is only accurate within its label domain —
     # outside it, fall back to exact elementwise paths
-    domain = getattr(edge_kernel, "domain", None)
-    if domain is not None:
-        lmax = max(float(np.abs(np.asarray(g1.edge_labels)).max()),
-                   float(np.abs(np.asarray(g2.edge_labels)).max()))
-        if lmax > domain:
-            rank = None
+    if not expansion_covers(edge_kernel, g1.edge_labels, g2.edge_labels):
+        rank = None
     rank_usable = rank is not None and rank <= max(16, dens * n)
     if dens < density_threshold:
         return ("sparse_mxu" if rank_usable else "sparse_vpu"), tile
@@ -527,25 +593,28 @@ def mgk_pairs_sparse(
     inverse (core/precond.py, DESIGN.md §9); ``factors1``/``factors2``
     optionally supply pack-time cached factors (per-axis under
     ``gram_tile``, mirroring the per-axis packs)."""
-    sys_ = build_product_system(g1, g2, vertex_kernel)
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
+    t = kernel_tile("sparse", packs1)
+    sys_ = tile_major_system(build_product_system(g1, g2, vertex_kernel),
+                             n, m, t)
     diag = sys_.dx / sys_.vx
     matvec = _make_sparse_matvec(sys_, packs1, packs2, edge_kernel,
                                  sparse_mode, (B, n, m),
                                  gram_tile=gram_tile)
-    papply = _make_precond_apply(precond, g1, g2, vertex_kernel,
-                                 edge_kernel, (B, n, m),
-                                 gram_tile=gram_tile, factors1=factors1,
-                                 factors2=factors2, kron_rank=kron_rank,
-                                 spd_margin=spd_margin)
+    papply = _tile_major_precond(
+        _make_precond_apply(precond, g1, g2, vertex_kernel, edge_kernel,
+                            (B, n, m), gram_tile=gram_tile,
+                            factors1=factors1, factors2=factors2,
+                            kron_rank=kron_rank, spd_margin=spd_margin),
+        n, m, t)
 
     rhs = sys_.dx * sys_.qx
     sol = pcg_solve(matvec, rhs, diag, tol=tol, max_iter=max_iter,
                     fixed_iters=fixed_iters, variant=pcg_variant,
                     precond_apply=papply, guard=guard, fault=fault)
     values = jnp.sum(sys_.px * sol.x, axis=-1)
-    nodal = sol.x.reshape(B, n, m) if return_nodal else None
+    nodal = _nodal(sol.x, n, m, t) if return_nodal else None
     return MGKResult(values=values, iterations=sol.iterations,
                      converged=sol.converged, nodal=nodal,
                      matvec_pairs=sol.matvec_pairs, status=sol.status)
@@ -599,9 +668,11 @@ def mgk_pairs_sparse_segmented(
     ``precond=`` (DESIGN.md §9)."""
     from repro.kernels.ops import take_row_panel_pack
 
-    sys_ = build_product_system(g1, g2, vertex_kernel)
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
+    t = kernel_tile("sparse", packs1)
+    sys_ = tile_major_system(build_product_system(g1, g2, vertex_kernel),
+                             n, m, t)
     diag = sys_.dx / sys_.vx
     matvec = _make_sparse_matvec(sys_, packs1, packs2, edge_kernel,
                                  sparse_mode, (B, n, m),
@@ -612,11 +683,12 @@ def mgk_pairs_sparse_segmented(
         # select() re-gathers them for every compacted survivor batch
         factors1, factors2 = _resolve_kron_factors(g1, g2, gram_tile,
                                                    factors1, factors2)
-    papply = _make_precond_apply(precond, g1, g2, vertex_kernel,
-                                 edge_kernel, (B, n, m),
-                                 gram_tile=gram_tile, factors1=factors1,
-                                 factors2=factors2, kron_rank=kron_rank,
-                                 spd_margin=spd_margin)
+    papply = _tile_major_precond(
+        _make_precond_apply(precond, g1, g2, vertex_kernel, edge_kernel,
+                            (B, n, m), gram_tile=gram_tile,
+                            factors1=factors1, factors2=factors2,
+                            kron_rank=kron_rank, spd_margin=spd_margin),
+        n, m, t)
 
     def select(lanes):
         import numpy as np
@@ -649,7 +721,7 @@ def mgk_pairs_sparse_segmented(
                                vertex_kernel, edge_kernel,
                                (len(lanes), n, m), rank=kron_rank,
                                spd_margin=spd_margin)
-        return sub_mv, sub_apply
+        return sub_mv, _tile_major_precond(sub_apply, n, m, t)
 
     rhs = sys_.dx * sys_.qx
     sol = pcg_solve_segmented(matvec, rhs, diag, tol=tol,
@@ -660,7 +732,7 @@ def mgk_pairs_sparse_segmented(
                               precond_apply=papply, guard=guard,
                               fault=fault)
     values = jnp.sum(sys_.px * sol.x, axis=-1)
-    nodal = sol.x.reshape(B, n, m) if return_nodal else None
+    nodal = _nodal(sol.x, n, m, t) if return_nodal else None
     return MGKResult(values=values, iterations=sol.iterations,
                      converged=sol.converged, nodal=nodal,
                      matvec_pairs=sol.matvec_pairs, status=sol.status)

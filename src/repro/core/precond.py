@@ -60,6 +60,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["KronFactors", "kron_factors", "kron_factor_arrays",
@@ -215,7 +216,8 @@ def kron_apply(f1: KronFactors, f2: KronFactors, vertex_kernel,
         Y = a[:, None, None] * (dd * X)
         if rank >= 2:
             Y = Y + b[:, None, None] * jnp.einsum(
-                "bij,bjk,blk->bil", f1.s, X, f2.s)
+                "bij,bjk,blk->bil", f1.s, X, f2.s,
+                precision=jax.lax.Precision.HIGHEST)
         return Y.reshape(B, n * m)
 
     return apply
@@ -241,7 +243,8 @@ def kron_apply_gram(f1: KronFactors, f2: KronFactors, vertex_kernel,
         Y = a[..., None, None] * (dd * X)
         if rank >= 2:
             Y = Y + b[..., None, None] * jnp.einsum(
-                "pij,pqjk,qlk->pqil", f1.s, X, f2.s)
+                "pij,pqjk,qlk->pqil", f1.s, X, f2.s,
+                precision=jax.lax.Precision.HIGHEST)
         return Y.reshape(Bi * Bj, n * m)
 
     return apply

@@ -34,6 +34,10 @@ import jax.numpy as jnp
 
 from .base_kernels import BaseKernel
 
+# f32 contractions at full precision: the TPU's default runs f32 matmuls
+# as single bf16 passes, ~1e-3 relative error in every matvec
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = ["xmv_full", "xmv_gram_full", "xmv_elementwise", "xmv_lowrank",
            "weighted_operands", "weighted_operand_grads",
            "kron_precond_dense"]
@@ -53,7 +57,7 @@ def xmv_full(A, E, Ap, Ep, P, edge_kernel: BaseKernel, theta=None):
     K = _kappa(edge_kernel, E[:, :, None, None], Ep[None, None, :, :],
                theta)
     W = A[:, :, None, None] * Ap[None, None, :, :] * K
-    return jnp.einsum("ijkl,jl->ik", W, P)
+    return jnp.einsum("ijkl,jl->ik", W, P, precision=_HIGHEST)
 
 
 def xmv_gram_full(A1, E1, A2, E2, P, edge_kernel: BaseKernel, theta=None):
@@ -92,7 +96,7 @@ def xmv_elementwise(A, E, Ap, Ep, P, edge_kernel: BaseKernel,
         K = _kappa(edge_kernel, Ej[:, :, None, None],
                    Ep[None, None, :, :], theta)
         W = Aj[:, :, None, None] * Ap[None, None, :, :] * K
-        y = y + jnp.einsum("ickl,cl->ik", W, Pj)
+        y = y + jnp.einsum("ickl,cl->ik", W, Pj, precision=_HIGHEST)
         return y, None
 
     y0 = jnp.zeros((n, m), P.dtype)
@@ -144,13 +148,13 @@ def xmv_lowrank(A, E, Ap, Ep, P, edge_kernel: BaseKernel):
     X n^2 m^2 — asymptotically cheaper AND MXU-eligible."""
     WA = weighted_operands(A, E, edge_kernel)     # [R, n, n]
     WAp = weighted_operands(Ap, Ep, edge_kernel)  # [R, m, m]
-    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp)
+    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp, precision=_HIGHEST)
 
 
 def xmv_lowrank_precomputed(WA, WAp, P):
     """Low-rank XMV with pre-weighted operands (amortized across the CG
     iterations of one solve — the weighting is loop-invariant)."""
-    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp)
+    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp, precision=_HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("edge_kernel", "method", "chunk"))

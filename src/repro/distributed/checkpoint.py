@@ -136,8 +136,10 @@ class ChunkStore:
         quarantined: dict[int, dict] = {}
         notes: list[dict] = []
         raw = data.split(b"\n")
-        n_lines = 0
-        torn = 0
+        # every append ends in a newline, so bytes after the last one
+        # are a torn append, even when they happen to parse as a record
+        tail = raw.pop()
+        n_lines = torn = int(bool(tail.strip()))
         for i, line in enumerate(raw):
             if not line.strip():
                 continue
@@ -146,7 +148,7 @@ class ChunkStore:
                 rec = json.loads(line)
             except ValueError:
                 torn += 1
-                if i < len(raw) - 2:        # not the (possibly torn) tail
+                if i < len(raw) - 1:        # not the (possibly torn) tail
                     warnings.warn(
                         f"manifest journal line {i} unparseable "
                         "(mid-file corruption); skipped")

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.base_kernels import BaseKernel, Constant
+from repro.core.base_kernels import BaseKernel, Constant, \
+    expansion_covers
 from repro.core.graph import GraphBatch
 from repro.core.mgk import MGKResult, mgk_pairs, mgk_pairs_sparse
 from repro.core.pcg import PCG_BREAKDOWN, PCG_DIVERGENCE, PCG_MAX_ITER, \
@@ -379,6 +380,10 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
     (the paper's load-balancing premise, and a known-size scan for the
     static roofline).
 
+    Forward steps carry ``step.lower(g1, g2[, rows, cols])``: the
+    ``jax.stages.Lowered`` program one block's solve compiles (no solve
+    runs) — for HLO inspection and compile accounting.
+
     ``method="pallas_sparse"`` returns a host-driven step: the octile
     row-panel packs are per-graph index structures (not shardable
     tensors), served from a :class:`GraphPackCache` keyed by dataset
@@ -431,19 +436,16 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
         # the expansion's accuracy domain (SE Taylor truncation): under
         # "auto", blocks whose labels leave it run exact elementwise —
         # same guard as mgk_adaptive; explicit "mxu" is honored as given
-        domain = getattr(edge_kernel, "domain", None) \
-            if sparse_mode == "auto" else None
         cache = GraphPackCache(tile=tile, edge_kernel=ek_pack,
                                max_entries=pack_cache_entries,
                                with_grad=with_grad,
                                pack_dtype=pack_dtype)
 
         def _resolve_block_mode(g1, g2):
-            if mode == "mxu" and domain is not None:
-                lmax = max(float(np.abs(np.asarray(g1.edge_labels)).max()),
-                           float(np.abs(np.asarray(g2.edge_labels)).max()))
-                if lmax > domain:
-                    return "elementwise"
+            if mode == "mxu" and sparse_mode == "auto" and \
+                    not expansion_covers(edge_kernel, g1.edge_labels,
+                                         g2.edge_labels):
+                return "elementwise"
             return mode
 
         kron = precond == "kron"
@@ -524,34 +526,41 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
             grad_sparse_step.with_grad = True
             return grad_sparse_step
 
+        def _solve_args(g1, g2, rows, cols):
+            p1, p2, block_mode, gt, (f1, f2) = _block_packs(g1, g2,
+                                                            rows, cols)
+            return ((g1, g2, p1, p2, vertex_kernel, edge_kernel),
+                    dict(sparse_mode=block_mode, gram_tile=gt,
+                         factors1=f1, factors2=f2, guard=guard,
+                         **precond_kw))
+
         def sparse_step(g1: GraphBatch, g2: GraphBatch,
                         rows=None, cols=None, fault=None,
                         spd_margin=None) -> MGKResult:
-            p1, p2, block_mode, gt, facs = _block_packs(g1, g2,
-                                                        rows, cols)
-            f1, f2 = facs
+            args, kw = _solve_args(g1, g2, rows, cols)
             if segment_size is not None:
                 res = mgk_pairs_sparse_segmented(
-                    g1, g2, p1, p2, vertex_kernel, edge_kernel,
-                    sparse_mode=block_mode, tol=tol, max_iter=max_iter,
+                    *args, tol=tol, max_iter=max_iter,
                     segment_size=segment_size, pad_multiple=segment_pad,
-                    pcg_variant=pcg_variant, gram_tile=gt,
-                    factors1=f1, factors2=f2, guard=guard, fault=fault,
-                    spd_margin=spd_margin, **precond_kw)
+                    pcg_variant=pcg_variant, fault=fault,
+                    spd_margin=spd_margin, **kw)
             else:
-                res = mgk_pairs_sparse(g1, g2, p1, p2, vertex_kernel,
-                                       edge_kernel,
-                                       sparse_mode=block_mode,
-                                       gram_tile=gt, factors1=f1,
-                                       factors2=f2, guard=guard,
-                                       fault=fault,
+                res = mgk_pairs_sparse(*args, fault=fault,
                                        spd_margin=spd_margin,
-                                       **solve_kw, **precond_kw)
+                                       **solve_kw, **kw)
             return MGKResult(values=res.values, iterations=res.iterations,
                              converged=res.converged, nodal=None,
                              matvec_pairs=res.matvec_pairs,
                              status=res.status)
 
+        def lower_sparse(g1, g2, rows=None, cols=None):
+            if segment_size is not None:
+                raise ValueError("segmented PCG is host-driven: there is"
+                                 " no single program to lower")
+            args, kw = _solve_args(g1, g2, rows, cols)
+            return mgk_pairs_sparse.lower(*args, **solve_kw, **kw)
+
+        sparse_step.lower = lower_sparse
         sparse_step.pack_cache = cache
         sparse_step.wants_indices = True
         sparse_step.no_pair_pad = gram_tile
@@ -602,6 +611,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                          converged=res.converged, nodal=None,
                          status=res.status)
 
+    dense_step.lower = jstep.lower
     return dense_step
 
 
@@ -629,13 +639,12 @@ def _pad_batch(gb: GraphBatch, to: int) -> GraphBatch:
     )
 
 
-def solve_pair_block(ds: BucketedDataset, block: PairBlock, step: Callable,
-                     pair_width: int, fault=None,
-                     spd_margin=None) -> dict[str, np.ndarray]:
-    """Run one PairBlock through the sharded step; returns host arrays.
-
-    ``fault``/``spd_margin`` forward to the step's injection seams
-    (only passed when set — gradient steps don't take them)."""
+def _block_args(ds: BucketedDataset, block: PairBlock, step: Callable,
+                pair_width: int) -> tuple:
+    """The positional arguments ``step`` takes for one block: both
+    (pair-padded) batches, plus the dataset indices for a pack-caching
+    sparse step (dummy pairs appended by _pad_batch key as -1 inside
+    the cache)."""
     g1 = ds.batch(block.rows, pad_to=block.pad_row)
     g2 = ds.batch(block.cols, pad_to=block.pad_col)
     B = block.n_pairs
@@ -643,18 +652,26 @@ def solve_pair_block(ds: BucketedDataset, block: PairBlock, step: Callable,
     # pair-axis sharding to pad for — dummy pairs would break it)
     to = B if getattr(step, "no_pair_pad", False) \
         else -(-B // pair_width) * pair_width
+    args = (_pad_batch(g1, to), _pad_batch(g2, to))
+    if getattr(step, "wants_indices", False):
+        args += (block.rows, block.cols)
+    return args
+
+
+def solve_pair_block(ds: BucketedDataset, block: PairBlock, step: Callable,
+                     pair_width: int, fault=None,
+                     spd_margin=None) -> dict[str, np.ndarray]:
+    """Run one PairBlock through the sharded step; returns host arrays.
+
+    ``fault``/``spd_margin`` forward to the step's injection seams
+    (only passed when set — gradient steps don't take them)."""
+    B = block.n_pairs
     kw = {}
     if fault is not None:
         kw["fault"] = fault
     if spd_margin is not None:
         kw["spd_margin"] = spd_margin
-    if getattr(step, "wants_indices", False):
-        # pack-caching sparse step: keyed by dataset index (dummy pairs
-        # appended by _pad_batch key as -1 inside the cache)
-        res = step(_pad_batch(g1, to), _pad_batch(g2, to),
-                   rows=block.rows, cols=block.cols, **kw)
-    else:
-        res = step(_pad_batch(g1, to), _pad_batch(g2, to), **kw)
+    res = step(*_block_args(ds, block, step, pair_width), **kw)
     grads = None
     if getattr(step, "with_grad", False):
         res, grads = res
@@ -1022,6 +1039,15 @@ class GramDriver:
             self.store.note(kind="nonconvergence", buckets=per_bucket,
                             max_iter=int(self.max_iter),
                             tol=float(self.tol))
+
+    def lower_block(self, block: PairBlock) -> "jax.stages.Lowered":
+        """The program the base rung compiles for ``block`` (forward
+        solve), lowered ahead of time; nothing is solved. For HLO
+        inspection (is the Pallas kernel a ``tpu_custom_call``?) and
+        compile accounting."""
+        step = self._build_step(False, {})
+        return step.lower(*_block_args(self.ds, block, step,
+                                       self._pair_width()))
 
     def run(self, progress: Callable[[int, int], None] | None = None
             ) -> np.ndarray:

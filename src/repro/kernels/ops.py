@@ -11,11 +11,11 @@ import jax.numpy as jnp
 from .flash_attention import flash_attention
 from .ref import attention_ref, xmv_batched_ref, xmv_ref
 from .xmv_block_sparse import RowPanelPack, TilePack, \
-    device_weighted_pack, pack_graph, pack_graph_row_panels, \
-    pack_octiles, pack_row_panels, xmv_block_sparse, \
+    device_weighted_pack, from_tiles, pack_graph, pack_graph_row_panels, \
+    pack_octiles, pack_row_panels, to_tiles, xmv_block_sparse, \
     xmv_block_sparse_batched, xmv_gram_tile, xmv_row_panel, \
     xmv_row_panel_batched
-from .xmv_dense import pick_tiles, xmv_dense, xmv_dense_batched
+from .xmv_dense import dense_row_panels, xmv_dense, xmv_dense_batched
 
 __all__ = [
     "xmv_dense", "xmv_dense_batched", "xmv_block_sparse",
@@ -25,7 +25,8 @@ __all__ = [
     "xmv_row_panel_batched", "xmv_gram_tile", "stack_row_panel_packs",
     "device_weighted_pack", "take_row_panel_pack",
     "row_panel_packs_for_batch", "flash_attention",
-    "attention_ref", "xmv_ref", "xmv_batched_ref", "pick_tiles",
+    "attention_ref", "xmv_ref", "xmv_batched_ref", "to_tiles",
+    "from_tiles", "dense_row_panels",
 ]
 
 

@@ -11,7 +11,7 @@ warps nor atomics, so (DESIGN.md §2):
   (values + columns) lands in VMEM as ONE pipelined block fetch and is
   reused across every slot pair of the output block, the TPU analog of
   the paper's warp-shared tiles;
-* the grid is (pair, tile_row_i, tile_row_i'): each output block is
+* the grid is (pair, tile_row_i, tile_row_i'): each output tile is
   owned by exactly one grid step, so accumulation is race-free by
   construction (no atomics needed) and the (slot, slot') reduction runs
   as an in-kernel ``fori_loop`` whose trip counts are the row's *actual*
@@ -24,16 +24,20 @@ warps nor atomics, so (DESIGN.md §2):
 Two compute modes per octile pair (paper Sec. IV-B's density-adaptive
 primitive choice, re-targeted to the TPU's two compute units):
 
-* **elementwise (VPU)** — regenerate the [t, t, t, t] product-weight
-  block from ``kappa_e`` and contract on the vector unit; works for any
+* **elementwise (VPU)** — regenerate the product weights from
+  ``kappa_e`` and contract on the vector unit, 2-D work on a
+  lane-flattened partner tile (:func:`_vpu_contrib`); works for any
   edge kernel.
 * **MXU low-rank contraction** — for edge kernels with a feature
   expansion ``kappa(x, y) = sum_r f_r(x) f_r(y)``, the pack precomputes
   per-octile weighted tiles ``w_r = a ∘ f_r(e)`` and each octile pair
-  contracts as ``sum_r w_r @ P_blk @ w'_r^T`` — small matmuls on the
-  systolic array instead of a t^4 broadcast tensor, which is also what
-  makes tile sizes t ∈ {8, 16, 32} worthwhile (t = 32 feeds the MXU
-  with 32x32 operands; the VPU path scales as t^4).
+  contracts as ``sum_r w_r @ P_blk @ w'_r^T`` — one matmul on the
+  systolic array plus R elementwise products (:func:`_mxu_contrib`).
+
+Layout (DESIGN.md §2): P, ``diag`` and the result are TILE-MAJOR,
+``[.., n/t, m/t, t, t]`` (:func:`to_tiles`), so every tile is selected
+by leading-axis indices — what the TPU lowers — and each output tile is
+written once by a per-tile ref store.
 
 The paper's SECOND reuse level — "warps across a thread block can
 further share tiles via the shared memory" — maps to the **Gram-tile**
@@ -52,10 +56,13 @@ Legacy launch granularities kept as benchmark baselines (DESIGN.md §3):
   (B, nt, mt, ka, kb) grid: every (slot, slot') pair is a separate grid
   step that re-fetches its octiles.
 
+These take node-major ``[n, m]`` P with t x t blocks inside the lane
+axis, which the TPU compiler refuses: they run in interpret mode only.
+
 All entry points support a **fused diagonal epilogue**: pass
-``diag = D_x V_x^{-1}`` (reshaped [n, m] / [B, n, m]) and the kernel emits
-the full CG operator application ``diag * p - y`` in the output block —
-no extra XLA op or HBM round-trip per CG iteration (DESIGN.md §3).
+``diag = D_x V_x^{-1}`` (laid out like P) and the kernel emits the full
+CG operator application ``diag * p - y`` in the output tile — no extra
+XLA op or HBM round-trip per CG iteration (DESIGN.md §3).
 
 Intra-tile sparsity (Sec. IV-B, bitmap compaction) lives at the storage
 level: HBM holds only packed non-empty tiles; the kernel computes on dense
@@ -80,7 +87,8 @@ __all__ = ["TilePack", "pack_octiles", "xmv_block_sparse",
            "xmv_block_sparse_batched", "RowPanelPack", "pack_row_panels",
            "pack_graph_row_panels", "xmv_row_panel",
            "xmv_row_panel_batched", "xmv_gram_tile",
-           "gram_tile_vmem_bytes", "device_weighted_pack"]
+           "gram_tile_vmem_bytes", "device_weighted_pack", "to_tiles",
+           "from_tiles"]
 
 
 class TilePack(NamedTuple):
@@ -352,131 +360,164 @@ def device_weighted_pack(pack: RowPanelPack, edge_kernel, theta=None,
     return pack._replace(values_w=w, values_grad=wg)
 
 
-def _contrib(a, e, ap, ep, p, edge_kernel, acc_dtype, theta=None):
-    """One octile-pair contribution: contract the regenerated [t,t,t,t]
-    product-weight block with the [t, t] P block -> [t, t].
-
-    Operands are upcast to the accumulator dtype BEFORE any product so
-    bf16-streamed packs (``pack_dtype``) regenerate edge-kernel values
-    and adjacency products in f32 — storage precision costs one
-    rounding of the inputs, never compounded kernel math (re-cast here
-    so the contract holds regardless of caller-side casts)."""
-    a = a.astype(acc_dtype)
-    ap = ap.astype(acc_dtype)
-    e = e.astype(acc_dtype)
-    ep = ep.astype(acc_dtype)
-    if theta is None:
-        kappa = edge_kernel(e[:, :, None, None], ep[None, None, :, :])
-    else:
-        kappa = edge_kernel.apply(e[:, :, None, None],
-                                  ep[None, None, :, :], theta)
-    kappa = kappa.astype(acc_dtype)
-    w = a[:, :, None, None] * ap[None, None, :, :] * kappa
-    return jnp.sum(w * p[None, :, None, :], axis=(1, 3))
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _mxu_contrib(w, wp, p, acc_dtype):
-    """One octile-pair contribution on the MXU: sum_r w_r @ P @ w'_r^T.
+def to_tiles(x, tile: int):
+    """Node-major ``[..., n, m]`` -> tile-major ``[..., n/t, m/t, t, t]``.
 
-    w/wp: [R, t, t] pre-weighted tiles ``a ∘ f_r(e)``; p: [t, t].
-    Two rank-batched matmuls replace the t^4 broadcast tensor.
-    Operands upcast to the accumulator dtype (bf16 ``pack_dtype``
-    streams half the HBM bytes; the MXU contraction stays f32).
-    """
-    w = w.astype(acc_dtype)
-    wp = wp.astype(acc_dtype)
-    tmp = jax.lax.dot_general(            # [R, t, t]: w_r @ P
-        w, p, (((2,), (0,)), ((), ())), preferred_element_type=acc_dtype)
-    out = jax.lax.dot_general(            # [R, t, t]: (w_r @ P) @ w'_r^T
-        tmp, wp, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=acc_dtype)
-    return jnp.sum(out, axis=0)
+    Every kernel of this module reads and writes the product-space
+    vector P in this layout: a t x t tile is then addressed by indices
+    on LEADING (untiled) axes, which the TPU lowers to plain dynamic
+    offsets, while a tile column picked inside the lane axis of an
+    ``[n, m]`` array is not lowerable (DESIGN.md §2)."""
+    *lead, n, m = x.shape
+    if n % tile or m % tile:
+        raise ValueError(f"shape {x.shape} is not a multiple of tile={tile}")
+    x = x.reshape(*lead, n // tile, tile, m // tile, tile)
+    return jnp.swapaxes(x, -3, -2)
 
 
-def _row_panel_kernel(col1, cnt1, col2, cnt2,   # scalar-prefetch refs
-                      *refs, edge_kernel, acc_dtype, fused, mxu, batched,
-                      tile, rank, with_theta):
-    """Row-panel kernel body: one grid step OWNS output block (i, i').
+def from_tiles(x):
+    """Inverse of :func:`to_tiles`: ``[..., nt, mt, t, t]`` -> node-major."""
+    *lead, nt, mt, t, t2 = x.shape
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, nt * t, mt * t2)
 
-    Grid layout: (nt, mt) per-pair, (B, nt, mt) batched. Both graphs'
-    whole tile rows are VMEM-resident (one pipelined block fetch each)
-    and reused across all ka x kb slot pairs; the slot reduction is an
-    in-kernel ``fori_loop`` bounded by the rows' SMEM slot counts, so
-    padding slots are never touched. Each output block is written
-    exactly once — no cross-step accumulation, no init/epilogue grid
-    predicates.
 
-    ``with_theta`` (elementwise mode only): the first regular input is a
-    (1, P) hyperparameter vector and kappa is regenerated through
-    ``edge_kernel.apply`` — traced parameter values reaching a kernel
-    whose edge_kernel is a static jit argument (DESIGN.md §7).
-    """
+def _lane_replicated(P):
+    """``[..., t, t]`` tiles -> ``[..., t, t*t]`` with lane ``k*t + l``
+    holding ``P[..., j, l]``: each P row repeated once per partner row
+    k, so one VPU op covers all (k, l) of a tile pair (see
+    :func:`_vpu_contrib`). Built once per matvec, outside the kernel."""
+    t = P.shape[-1]
+    return jnp.tile(P, (1,) * (P.ndim - 1) + (t,))
+
+
+def _fold_lanes(acc, t: int):
+    """``[t, t*t]`` accumulator -> ``[t, t]`` tile: sums the t lanes
+    ``k*t .. k*t + t-1`` into column k with one 0/1 matmul."""
+    tt = t * t
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tt, t), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tt, t), 1)
+    fold = (rows // t == cols).astype(acc.dtype)
+    return jax.lax.dot_general(acc, fold, (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=acc.dtype)
+
+
+def _vpu_contrib(acc, a, e, part, prep, edge_kernel, theta):
+    """One octile pair on the vector unit, accumulated lane-flattened.
+
+    a, e: ``[t, t]`` row-graph tiles (rows i, cols j); part: ``[2, t*t]``
+    partner tiles flattened to one row each (adjacency, labels; lane
+    ``k*t + l``); prep: ``[t, t*t]`` lane-replicated P tile. Adds
+    ``a[i,j] a'[k,l] kappa(e[i,j], e'[k,l]) P[j,l]`` at ``acc[i, k*t+l]``
+    for every j — 2-D work only (a column of a/e broadcast against a
+    partner row); :func:`_fold_lanes` sums l at the end of the tile."""
+    apf, epf = part[0:1], part[1:2]
+    for j in range(a.shape[1]):
+        ej = e[:, j:j + 1]
+        kappa = edge_kernel(ej, epf) if theta is None \
+            else edge_kernel.apply(ej, epf, theta)
+        acc = acc + (a[:, j:j + 1] * apf) * kappa.astype(acc.dtype) \
+            * prep[j:j + 1, :]
+    return acc
+
+
+def _mxu_contrib(acc, w, part, prep):
+    """One octile pair as the low-rank contraction
+    ``sum_r w_r @ P @ w'_r^T``, accumulated lane-flattened.
+
+    w: ``[R*t, t]`` the row graph's weighted tiles stacked over r; part:
+    ``[R, t*t]`` the partner's weighted tiles flattened per r; prep:
+    ``[t, t*t]`` lane-replicated P tile. One matmul gives every
+    ``(w_r @ P)[i, l]`` replicated over k; the partner factor is then an
+    elementwise product per r."""
+    t = w.shape[1]
+    tmp = jax.lax.dot_general(w, prep, (((1,), (0,)), ((), ())),
+                              precision=_HIGHEST,
+                              preferred_element_type=acc.dtype)
+    for r in range(part.shape[0]):
+        acc = acc + tmp[r * t:(r + 1) * t] * part[r:r + 1]
+    return acc
+
+
+def _xmv_kernel(col1, cnt1, col2, cnt2,   # scalar-prefetch refs (SMEM)
+                *refs, edge_kernel, acc_dtype, fused, mxu, cross, tile,
+                dims, with_theta):
+    """Shared body of the row-panel and Gram-tile kernels.
+
+    One grid step owns tile row i of one pair and a run of its output
+    tiles (i, ip): a single tile for the row-panel grid (pair, i, ip), the
+    whole strip ip = 0..mt-1 for the Gram-tile grid (bi, i, bj). The row
+    graph's tile row and the partner's panel(s) are VMEM-resident and
+    reused across all slot pairs; the (slot, slot') reduction is an
+    in-kernel ``fori_loop`` bounded by the SMEM slot counts, so padding
+    slots are skipped. Every tile is addressed by leading-axis indices,
+    and each output tile is written once by a per-tile ref store.
+
+    ``with_theta``: the first input is the hyperparameter vector in SMEM
+    and kappa is regenerated through ``edge_kernel.apply`` — traced
+    parameter values reaching a kernel whose edge_kernel is a static jit
+    argument (DESIGN.md §7)."""
     t = tile
-    d = 1 if batched else 0
-    i, ip = pl.program_id(d), pl.program_id(d + 1)
+    nt, mt, ka, kb = dims
+    if cross:
+        r, i, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        ip0 = 0
+    else:
+        r = c = pl.program_id(0)
+        i, ip0 = pl.program_id(1), pl.program_id(2)
     theta = None
     if with_theta:
         from repro.core.base_kernels import unpack_theta
         t_ref, *refs = refs
-        theta = unpack_theta(edge_kernel, t_ref[0])
+        theta = unpack_theta(edge_kernel, t_ref)
     if mxu:
-        w1_ref, w2_ref, p_ref = refs[:3]
-        rest = refs[3:]
+        (w1_ref, part_ref, p_ref), rest = refs[:3], refs[3:]
     else:
-        a1_ref, e1_ref, a2_ref, e2_ref, p_ref = refs[:5]
-        rest = refs[5:]
-    diag_ref, o_ref = (rest if fused else (None, rest[0]))
+        (a1_ref, e1_ref, part_ref, p_ref), rest = refs[:4], refs[4:]
+    diag_ref, o_ref = rest if fused else (None, rest[0])
+    n_ip = o_ref.shape[-3]
+    plead = (0,) * (len(p_ref.shape) - 4)
+    olead = (0,) * (len(o_ref.shape) - 3)
+    row = r * nt + i
 
-    if batched:
-        b = pl.program_id(0)
-        na, nb = cnt1[b, i], cnt2[b, ip]
-        col_a = lambda k: col1[b, i, k]      # noqa: E731
-        col_b = lambda k: col2[b, ip, k]     # noqa: E731
-        at = lambda ref, k: ref[0, 0, k]     # noqa: E731
-        atr = lambda ref, k: ref[0, 0, pl.ds(k * rank, rank)]  # noqa: E731
-    else:
-        na, nb = cnt1[i], cnt2[ip]
-        col_a = lambda k: col1[i, k]         # noqa: E731
-        col_b = lambda k: col2[ip, k]        # noqa: E731
-        at = lambda ref, k: ref[0, k]        # noqa: E731
-        atr = lambda ref, k: ref[0, pl.ds(k * rank, rank)]     # noqa: E731
+    def out_tile(ipl, carry):
+        ip = ip0 + ipl
+        prow = c * mt + ip
 
-    def p_block(ca, cb):
-        blk = (p_ref[0, pl.ds(ca * t, t), pl.ds(cb * t, t)] if batched
-               else p_ref[pl.ds(ca * t, t), pl.ds(cb * t, t)])
-        return blk.astype(acc_dtype)
-
-    def outer(kk, acc):
-        ca = col_a(kk)
-        if mxu:
-            w = atr(w1_ref, kk)                      # [R, t, t], staged row
-        else:
-            a = at(a1_ref, kk).astype(acc_dtype)
-            e = at(e1_ref, kk)
-
-        def inner(kkp, acc):
-            pblk = p_block(ca, col_b(kkp))
+        def outer(kk, acc):
+            ca = col1[row * ka + kk]
             if mxu:
-                contrib = _mxu_contrib(w, atr(w2_ref, kkp), pblk, acc_dtype)
+                w = w1_ref[0, 0, kk].astype(acc_dtype)
             else:
-                contrib = _contrib(a, e, at(a2_ref, kkp).astype(acc_dtype),
-                                   at(e2_ref, kkp), pblk, edge_kernel,
-                                   acc_dtype, theta=theta)
-            return acc + contrib
+                a = a1_ref[0, 0, kk].astype(acc_dtype)
+                e = e1_ref[0, 0, kk].astype(acc_dtype)
 
-        return jax.lax.fori_loop(0, nb, inner, acc)
+            def inner(kkp, acc):
+                prep = p_ref[plead + (ca, col2[prow * kb + kkp])]
+                prep = prep.astype(acc_dtype)
+                part = part_ref[0, ipl, kkp].astype(acc_dtype)
+                if mxu:
+                    return _mxu_contrib(acc, w, part, prep)
+                return _vpu_contrib(acc, a, e, part, prep, edge_kernel,
+                                    theta)
 
-    acc = jax.lax.fori_loop(0, na, outer,
-                            jnp.zeros((t, t), acc_dtype))
+            return jax.lax.fori_loop(0, cnt2[prow], inner, acc)
 
-    if fused:
-        # the operator application diag*p - y, with the p block read from
-        # the already-VMEM-resident P panel
-        dblk = (diag_ref[0] if batched else diag_ref[...]).astype(acc_dtype)
-        pout = p_block(i, ip)
-        acc = dblk * pout - acc
-    res = acc.astype(o_ref.dtype)
-    o_ref[...] = res[None] if batched else res
+        acc = jax.lax.fori_loop(0, cnt1[row], outer,
+                                jnp.zeros((t, t * t), acc_dtype))
+        y = _fold_lanes(acc, t)
+        if fused:
+            # the operator application diag*p - y; the P tile is the
+            # first t lanes of its VMEM-resident replicated copy
+            pblk = p_ref[plead + (i, ip)][:, :t].astype(acc_dtype)
+            y = diag_ref[olead + (ipl,)].astype(acc_dtype) * pblk - y
+        o_ref[olead + (ipl,)] = y.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n_ip, out_tile, 0)
 
 
 def _resolve_mode(mode: str, packs1: RowPanelPack,
@@ -496,87 +537,80 @@ def _resolve_mode(mode: str, packs1: RowPanelPack,
     raise ValueError(f"unknown row-panel mode {mode!r}")
 
 
-def _row_panel_call(packs1, packs2, P, edge_kernel, diag, interpret,
-                    acc_dtype, mode, batched, theta=None):
+def _operands(packs1: RowPanelPack, packs2: RowPanelPack, mxu: bool):
+    """(row-graph operands, partner operands) in kernel layout.
+
+    Row graph: ``[B, nt, ka, t, t]`` adjacency + label tiles (VPU) or the
+    weighted tiles stacked over rank, ``[B, nt, ka, R*t, t]`` (MXU).
+    Partner: one ``[2 or R, t*t]`` row of lane-flattened tiles per slot,
+    ``[B, mt, kb, 2 or R, t*t]``."""
+    t = packs1.tile
+    if mxu:
+        w1 = packs1.values_w
+        w2 = packs2.values_w
+        row = [w1.reshape(w1.shape[:-3] + (w1.shape[-3] * t, t))]
+        part = w2.reshape(w2.shape[:-2] + (t * t,))
+    else:
+        row = [packs1.values_adj, packs1.values_lab]
+        part = jnp.stack([packs2.values_adj, packs2.values_lab], axis=-3)
+        part = part.reshape(part.shape[:-2] + (t * t,))
+    return row, part
+
+
+def _xmv_call(packs1, packs2, P, edge_kernel, diag, interpret, acc_dtype,
+              mode, cross, theta=None):
+    """Shared launcher: ``cross=False`` pairs packs1[b] with packs2[b]
+    (P ``[B, nt, mt, t, t]``, grid (B, nt, mt)); ``cross=True`` pairs
+    every row graph with every column graph (P ``[Bi, Bj, nt, mt, t, t]``,
+    grid (Bi, nt, Bj))."""
     t = packs1.tile
     nt, mt = packs1.n_tile_rows, packs2.n_tile_rows
     ka, kb = packs1.k_max, packs2.k_max
-    if batched:
-        B = packs1.col.shape[0]
-        Bp, n, m = P.shape
-        if Bp != B:
-            raise ValueError(f"P batch {Bp} != pack batch {B}")
-    else:
-        n, m = P.shape
-    if n != nt * t or m != mt * t:
-        raise ValueError(f"P shape {P.shape} inconsistent with tile packs"
-                         f" ({nt}x{t}, {mt}x{t})")
+    B1, B2 = packs1.col.shape[0], packs2.col.shape[0]
+    pairs = (B1, B2) if cross else (B1,)
+    if not cross and B2 != B1:
+        raise ValueError(f"pack batches differ: {B1} vs {B2}")
+    want = pairs + (nt, mt, t, t)
+    if P.shape != want:
+        raise ValueError(f"P shape {P.shape} inconsistent with tile packs:"
+                         f" expected tile-major {want}")
     if packs2.tile != t:
         raise ValueError(f"tile mismatch: {t} vs {packs2.tile}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     fused = diag is not None
     mxu = _resolve_mode(mode, packs1, packs2)
-    rank = packs1.rank if mxu else 0
-    if mxu and packs2.rank != rank:
+    if mxu and packs2.rank != packs1.rank:
         raise ValueError(
-            f"feature rank mismatch: {rank} vs {packs2.rank}")
+            f"feature rank mismatch: {packs1.rank} vs {packs2.rank}")
+    row_ops, part = _operands(packs1, packs2, mxu)
+    tt = t * t
 
-    if batched:
-        def panel1(shape):
-            return pl.BlockSpec((1, 1) + shape,
-                                lambda b, i, ip, c1, n1, c2, n2:
-                                (b, i) + (0,) * len(shape))
-
-        def panel2(shape):
-            return pl.BlockSpec((1, 1) + shape,
-                                lambda b, i, ip, c1, n1, c2, n2:
-                                (b, ip) + (0,) * len(shape))
-
-        p_spec = pl.BlockSpec((1, n, m),
-                              lambda b, i, ip, c1, n1, c2, n2: (b, 0, 0))
-        out_spec = pl.BlockSpec((1, t, t),
-                                lambda b, i, ip, c1, n1, c2, n2: (b, i, ip))
-        grid = (B, nt, mt)
-        out_shape = jax.ShapeDtypeStruct((B, n, m), P.dtype)
+    if cross:
+        grid = (B1, nt, B2)
+        row_map = lambda bi, i, bj, *_: (bi, i, 0, 0, 0)          # noqa
+        part_spec = pl.BlockSpec((1, mt) + part.shape[2:],
+                                 lambda bi, i, bj, *_: (bj, 0, 0, 0, 0))
+        p_spec = pl.BlockSpec((1, 1, nt, mt, t, tt),
+                              lambda bi, i, bj, *_: (bi, bj, 0, 0, 0, 0))
+        out_spec = pl.BlockSpec((1, 1, 1, mt, t, t),
+                                lambda bi, i, bj, *_: (bi, bj, i, 0, 0, 0))
     else:
-        def panel1(shape):
-            return pl.BlockSpec((1,) + shape,
-                                lambda i, ip, c1, n1, c2, n2:
-                                (i,) + (0,) * len(shape))
-
-        def panel2(shape):
-            return pl.BlockSpec((1,) + shape,
-                                lambda i, ip, c1, n1, c2, n2:
-                                (ip,) + (0,) * len(shape))
-
-        p_spec = pl.BlockSpec((n, m),
-                              lambda i, ip, c1, n1, c2, n2: (0, 0))
-        out_spec = pl.BlockSpec((t, t),
-                                lambda i, ip, c1, n1, c2, n2: (i, ip))
-        grid = (nt, mt)
-        out_shape = jax.ShapeDtypeStruct((n, m), P.dtype)
-
+        grid = (B1, nt, mt)
+        row_map = lambda b, i, ip, *_: (b, i, 0, 0, 0)            # noqa
+        part_spec = pl.BlockSpec((1, 1) + part.shape[2:],
+                                 lambda b, i, ip, *_: (b, ip, 0, 0, 0))
+        p_spec = pl.BlockSpec((1, nt, mt, t, tt),
+                              lambda b, i, ip, *_: (b, 0, 0, 0, 0))
+        out_spec = pl.BlockSpec((1, 1, 1, t, t),
+                                lambda b, i, ip, *_: (b, i, ip, 0, 0))
+    in_specs = [pl.BlockSpec((1, 1) + x.shape[2:], row_map)
+                for x in row_ops] + [part_spec, p_spec]
+    inputs = row_ops + [part, _lane_replicated(P)]
     with_theta = theta is not None and not mxu
-    if mxu:
-        # [.., nt, ka, R, t, t] -> [.., nt, ka*R, t, t]: slot-major,
-        # rank-minor, so slot kk's operands are rows [kk*R, (kk+1)*R)
-        w1 = packs1.values_w.reshape(packs1.values_w.shape[:-4]
-                                     + (ka * rank, t, t))
-        w2 = packs2.values_w.reshape(packs2.values_w.shape[:-4]
-                                     + (kb * rank, t, t))
-        in_specs = [panel1((ka * rank, t, t)), panel2((kb * rank, t, t)),
-                    p_spec]
-        inputs = [w1, w2, P]
-    else:
-        in_specs = [panel1((ka, t, t)), panel1((ka, t, t)),
-                    panel2((kb, t, t)), panel2((kb, t, t)), p_spec]
-        inputs = [packs1.values_adj, packs1.values_lab,
-                  packs2.values_adj, packs2.values_lab, P]
     if with_theta:
-        n_theta = theta.shape[-1]
-        in_specs.insert(0, pl.BlockSpec((1, n_theta), lambda *_: (0, 0)))
-        inputs.insert(0, theta.reshape(1, n_theta))
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        inputs.insert(0, jnp.asarray(theta, jnp.float32).reshape(-1))
     if fused:
         in_specs.append(out_spec)
         inputs.append(diag)
@@ -587,15 +621,24 @@ def _row_panel_call(packs1, packs2, P, edge_kernel, diag, interpret,
         in_specs=in_specs,
         out_specs=out_spec,
     )
+    # slot tables go to SMEM flattened: a 2-D/3-D SMEM array pads its
+    # minor axes and a bucket's tables would overflow the scalar memory
+    flat = lambda x: x.reshape(-1).astype(jnp.int32)             # noqa
     return pl.pallas_call(
-        functools.partial(_row_panel_kernel, edge_kernel=edge_kernel,
+        functools.partial(_xmv_kernel, edge_kernel=edge_kernel,
                           acc_dtype=acc_dtype, fused=fused, mxu=mxu,
-                          batched=batched, tile=t, rank=rank,
+                          cross=cross, tile=t, dims=(nt, mt, ka, kb),
                           with_theta=with_theta),
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(want, P.dtype),
         interpret=interpret,
-    )(packs1.col, packs1.count, packs2.col, packs2.count, *inputs)
+        name="xmv_gram_tile" if cross else "xmv_row_panel",
+    )(flat(packs1.col), flat(packs1.count), flat(packs2.col),
+      flat(packs2.count), *inputs)
+
+
+def _with_batch(pack: RowPanelPack) -> RowPanelPack:
+    return RowPanelPack(*(None if f is None else f[None] for f in pack))
 
 
 @functools.partial(jax.jit, static_argnames=("edge_kernel", "interpret",
@@ -605,18 +648,22 @@ def xmv_row_panel(pack1: RowPanelPack, pack2: RowPanelPack, P, edge_kernel,
                   acc_dtype=jnp.float32, theta=None):
     """y = (A (x) A' .* E (x)k E') P via VMEM-staged row panels (one pair).
 
-    ``mode``: "elementwise" (VPU, any edge kernel), "mxu" (low-rank
-    contraction; needs packs built with the edge kernel), or "auto"
-    (mxu iff both packs carry precomputed weighted tiles).
+    ``P`` (and the result) are tile-major ``[nt, mt, t, t]``
+    (:func:`to_tiles`). ``mode``: "elementwise" (VPU, any edge kernel),
+    "mxu" (low-rank contraction; needs packs built with the edge kernel),
+    or "auto" (mxu iff both packs carry precomputed weighted tiles).
 
-    With ``diag`` ([n, m]) the kernel instead returns the fused CG
-    operator application ``diag * P - y``. ``theta`` ([P_theta] f32,
-    ``pack_theta`` order) overrides the edge kernel's hyperparameters
-    with traced values on the elementwise path; the MXU path takes its
-    parameters through ``device_weighted_pack`` instead (DESIGN.md §7).
+    With ``diag`` (tile-major like P) the kernel instead returns the
+    fused CG operator application ``diag * P - y``. ``theta`` ([P_theta]
+    f32, ``pack_theta`` order) overrides the edge kernel's
+    hyperparameters with traced values on the elementwise path; the MXU
+    path takes its parameters through ``device_weighted_pack`` instead
+    (DESIGN.md §7).
     """
-    return _row_panel_call(pack1, pack2, P, edge_kernel, diag, interpret,
-                           acc_dtype, mode, batched=False, theta=theta)
+    out = _xmv_call(_with_batch(pack1), _with_batch(pack2), P[None],
+                    edge_kernel, None if diag is None else diag[None],
+                    interpret, acc_dtype, mode, cross=False, theta=theta)
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("edge_kernel", "interpret",
@@ -629,113 +676,31 @@ def xmv_row_panel_batched(packs1: RowPanelPack, packs2: RowPanelPack, P,
 
     ``packs1``/``packs2`` are stacked RowPanelPacks
     (``ops.stack_row_panel_packs``) with a leading [B] axis on every
-    field; ``P`` is [B, n, m]. Grid (B, nt, mt): the pair axis is the
-    outermost grid dimension, each output block is owned by one grid
-    step, and the (slot, slot') reduction runs in-kernel over the
-    VMEM-staged tile rows (vs a grid step per slot pair in the legacy
-    :func:`xmv_block_sparse_batched`).
+    field; ``P`` is tile-major ``[B, nt, mt, t, t]``. Grid (B, nt, mt):
+    the pair axis is the outermost grid dimension, each output tile is
+    owned by one grid step, and the (slot, slot') reduction runs
+    in-kernel over the VMEM-staged tile rows (vs a grid step per slot
+    pair in the legacy :func:`xmv_block_sparse_batched`).
 
-    With ``diag`` ([B, n, m]) the fused epilogue emits ``diag * P - y``;
-    ``theta`` (shared across the bucket) as in :func:`xmv_row_panel`.
+    With ``diag`` (``[B, nt, mt, t, t]``) the fused epilogue emits
+    ``diag * P - y``; ``theta`` (shared across the bucket) as in
+    :func:`xmv_row_panel`.
     """
-    return _row_panel_call(packs1, packs2, P, edge_kernel, diag, interpret,
-                           acc_dtype, mode, batched=True, theta=theta)
-
-
-def _gram_tile_kernel(col1, cnt1, col2, cnt2,   # scalar-prefetch refs
-                      *refs, edge_kernel, acc_dtype, fused, mxu, tile,
-                      mt, rank, with_theta):
-    """Gram-tile kernel body: one grid step owns the [t, m] output ROW
-    STRIP of pair (bi, bj) at tile row i.
-
-    Grid layout: (Bi, nt, Bj) — the COLUMN-graph pair axis is the grid's
-    inner axis, so graph bi's VMEM-staged tile row (index map (bi, i),
-    constant across the whole inner bj sweep) is fetched ONCE and reused
-    by all Bj partners: the TPU-pipelining analog of the paper's
-    "warps across a thread block share tiles via shared memory", lifted
-    from slot pairs within one pair to the PAIR AXIS of a Gram tile.
-    Graph bj arrives as its whole row-panel pack (all mt tile rows in
-    one block), and the mt loop runs IN-KERNEL — mt-fold fewer grid
-    steps than the per-pair row-panel kernel on the same work.
-
-    Slot reductions stay bounded by the SMEM-prefetched actual counts;
-    the fused epilogue emits the full operator strip diag*p - y from
-    the already-resident P panel.
-    """
-    t = tile
-    bi, i, bj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    theta = None
-    if with_theta:
-        from repro.core.base_kernels import unpack_theta
-        t_ref, *refs = refs
-        theta = unpack_theta(edge_kernel, t_ref[0])
-    if mxu:
-        w1_ref, w2_ref, p_ref = refs[:3]
-        rest = refs[3:]
-    else:
-        a1_ref, e1_ref, a2_ref, e2_ref, p_ref = refs[:5]
-        rest = refs[5:]
-    diag_ref, o_ref = (rest if fused else (None, rest[0]))
-
-    na = cnt1[bi, i]
-    m = mt * t
-
-    def p_block(ca, cb):
-        return p_ref[0, 0, pl.ds(ca * t, t),
-                     pl.ds(cb * t, t)].astype(acc_dtype)
-
-    def row_block(ip, strip):
-        # output block (i, ip) of pair (bi, bj): the usual ka x kb slot
-        # reduction, with graph bj's tile row read out of its whole
-        # VMEM-resident pack at row ip
-        nb = cnt2[bj, ip]
-
-        def outer(kk, acc):
-            ca = col1[bi, i, kk]
-            if mxu:
-                w = w1_ref[0, 0, pl.ds(kk * rank, rank)]     # [R, t, t]
-            else:
-                a = a1_ref[0, 0, kk].astype(acc_dtype)
-                e = e1_ref[0, 0, kk]
-
-            def inner(kkp, acc):
-                pblk = p_block(ca, col2[bj, ip, kkp])
-                if mxu:
-                    wp = w2_ref[0, ip, pl.ds(kkp * rank, rank)]
-                    contrib = _mxu_contrib(w, wp, pblk, acc_dtype)
-                else:
-                    contrib = _contrib(
-                        a, e, a2_ref[0, ip, kkp].astype(acc_dtype),
-                        e2_ref[0, ip, kkp], pblk, edge_kernel, acc_dtype,
-                        theta=theta)
-                return acc + contrib
-
-            return jax.lax.fori_loop(0, nb, inner, acc)
-
-        blk = jax.lax.fori_loop(0, na, outer,
-                                jnp.zeros((t, t), acc_dtype))
-        return jax.lax.dynamic_update_slice(strip, blk, (0, ip * t))
-
-    strip = jax.lax.fori_loop(0, mt, row_block,
-                              jnp.zeros((t, m), acc_dtype))
-    if fused:
-        # operator strip diag*p - y from the VMEM-resident P panel
-        dstrip = diag_ref[0, 0].astype(acc_dtype)
-        pstrip = p_ref[0, 0, pl.ds(i * t, t), :].astype(acc_dtype)
-        strip = dstrip * pstrip - strip
-    o_ref[0, 0] = strip.astype(o_ref.dtype)
+    return _xmv_call(packs1, packs2, P, edge_kernel, diag, interpret,
+                     acc_dtype, mode, cross=False, theta=theta)
 
 
 def gram_tile_vmem_bytes(packs_i: RowPanelPack, packs_j: RowPanelPack,
                          mxu: bool) -> int:
     """Per-grid-step VMEM envelope of :func:`xmv_gram_tile` in bytes
     (x2 for the pipeline's double buffering): graph j's whole
-    pack + graph i's tile row + the P panel + the diag/out strips.
-    Pack operands are costed at their STORED itemsize — bf16 packs
-    (``pack_dtype``) halve the operand share of the envelope, which is
-    exactly what lets larger tiles stay on the Gram-tile kernel.
-    ``gram_pair_step`` uses this to route over-budget buckets to the
-    per-pair :func:`xmv_row_panel_batched` automatically."""
+    pack + graph i's tile row + the pair's lane-replicated P panel + the
+    diag/out strips. Pack operands are costed at their STORED itemsize —
+    bf16 packs (``pack_dtype``) halve the operand share of the envelope,
+    which is exactly what lets larger tiles stay on the Gram-tile
+    kernel. ``gram_pair_step`` uses this to route over-budget buckets to
+    the per-pair :func:`xmv_row_panel_batched`, which stages one partner
+    tile row per step instead of the whole pack."""
     t = packs_i.tile
     nt, mt = packs_i.n_tile_rows, packs_j.n_tile_rows
     ka, kb = packs_i.k_max, packs_j.k_max
@@ -745,7 +710,7 @@ def gram_tile_vmem_bytes(packs_i: RowPanelPack, packs_j: RowPanelPack,
     pack_bytes = np.dtype(packs_i.values_adj.dtype).itemsize
     operands = (ka * ci * t * t          # graph i's tile row
                 + mt * kb * cj * t * t)  # graph j's whole pack
-    fp32 = (n * m                        # the pair's P panel
+    fp32 = (n * m * t                    # the pair's replicated P panel
             + 2 * t * m)                 # diag + out strips
     return 2 * (pack_bytes * operands + 4 * fp32)  # double buffered
 
@@ -760,105 +725,29 @@ def xmv_gram_tile(packs_i: RowPanelPack, packs_j: RowPanelPack, P,
     ``packs_i``/``packs_j`` are stacked RowPanelPacks with a leading
     PER-AXIS batch — Bi packs for the row graphs and Bj for the column
     graphs, NOT Bi*Bj per-pair packs, so each graph's panels live in HBM
-    exactly once per Gram tile. ``P`` is [Bi, Bj, n, m]; the result is
-    the [Bi, Bj, n, m] stack of y = (A_i (x) A'_j .* E_i (x)k E'_j) P_ij.
+    exactly once per Gram tile. ``P`` is tile-major
+    ``[Bi, Bj, nt, mt, t, t]``; the result is the same-shaped stack of
+    y = (A_i (x) A'_j .* E_i (x)k E'_j) P_ij.
 
     Grid (Bi, nt, Bj): graph i's tile row is fetched once per (bi, i)
     and reused across ALL Bj partners (the pair-axis operand reuse the
     paper gets from thread-block shared memory); graph j's whole
-    row-panel pack is staged per step and the output-tile-column loop
-    runs in-kernel, collapsing the per-pair kernel's mt grid axis.
-    VMEM envelope per step (:func:`gram_tile_vmem_bytes`): graph j's
-    pack (4*mt*kb*(2 or R)*t^2 bytes) + one P panel (4*n*m) + graph i's
-    tile row — graph-kernel buckets sit far below the ~16 MB/core
-    budget. This function does NOT guard the envelope itself; the Gram
-    driver's ``gram_pair_step`` checks it and routes over-budget
-    buckets to the per-pair :func:`xmv_row_panel_batched`.
+    row-panel pack is staged per step and the output-tile loop over the
+    strip runs in-kernel, collapsing the per-pair kernel's mt grid axis.
+    VMEM envelope per step: :func:`gram_tile_vmem_bytes`. This function
+    does NOT guard the envelope itself; the Gram driver's
+    ``gram_pair_step`` checks it and routes over-budget buckets to the
+    per-pair :func:`xmv_row_panel_batched`.
 
     ``mode``/``diag``/``theta`` as in :func:`xmv_row_panel_batched`
-    (``diag``: [Bi, Bj, n, m] fused CG epilogue; ``theta``: traced
+    (``diag``: tile-major like P, fused CG epilogue; ``theta``: traced
     hyperparameter vector on the elementwise path).
     """
-    t = packs_i.tile
-    nt, mt = packs_i.n_tile_rows, packs_j.n_tile_rows
-    ka, kb = packs_i.k_max, packs_j.k_max
-    Bi, Bj = packs_i.col.shape[0], packs_j.col.shape[0]
-    if P.ndim != 4:
-        raise ValueError(f"P must be [Bi, Bj, n, m], got shape {P.shape}")
-    Pi, Pj, n, m = P.shape
-    if (Pi, Pj) != (Bi, Bj):
-        raise ValueError(f"P pair axes {(Pi, Pj)} != pack axes"
-                         f" {(Bi, Bj)}")
-    if n != nt * t or m != mt * t:
-        raise ValueError(f"P shape {P.shape} inconsistent with tile packs"
-                         f" ({nt}x{t}, {mt}x{t})")
-    if packs_j.tile != t:
-        raise ValueError(f"tile mismatch: {t} vs {packs_j.tile}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fused = diag is not None
-    mxu = _resolve_mode(mode, packs_i, packs_j)
-    rank = packs_i.rank if mxu else 0
-    if mxu and packs_j.rank != rank:
+    if P.ndim != 6:
         raise ValueError(
-            f"feature rank mismatch: {rank} vs {packs_j.rank}")
-
-    def panel_i(shape):
-        # ONE tile row of graph bi; constant across the inner bj axis
-        return pl.BlockSpec((1, 1) + shape,
-                            lambda bi, i, bj, c1, n1, c2, n2:
-                            (bi, i) + (0,) * len(shape))
-
-    def pack_j(shape):
-        # the WHOLE row-panel pack of graph bj (all mt tile rows)
-        return pl.BlockSpec((1,) + shape,
-                            lambda bi, i, bj, c1, n1, c2, n2:
-                            (bj,) + (0,) * len(shape))
-
-    p_spec = pl.BlockSpec((1, 1, n, m),
-                          lambda bi, i, bj, c1, n1, c2, n2:
-                          (bi, bj, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, t, m),
-                            lambda bi, i, bj, c1, n1, c2, n2:
-                            (bi, bj, i, 0))
-
-    with_theta = theta is not None and not mxu
-    if mxu:
-        # slot-major, rank-minor flattening, as in the row-panel kernel
-        w1 = packs_i.values_w.reshape((Bi, nt, ka * rank, t, t))
-        w2 = packs_j.values_w.reshape((Bj, mt, kb * rank, t, t))
-        in_specs = [panel_i((ka * rank, t, t)),
-                    pack_j((mt, kb * rank, t, t)), p_spec]
-        inputs = [w1, w2, P]
-    else:
-        in_specs = [panel_i((ka, t, t)), panel_i((ka, t, t)),
-                    pack_j((mt, kb, t, t)), pack_j((mt, kb, t, t)),
-                    p_spec]
-        inputs = [packs_i.values_adj, packs_i.values_lab,
-                  packs_j.values_adj, packs_j.values_lab, P]
-    if with_theta:
-        n_theta = theta.shape[-1]
-        in_specs.insert(0, pl.BlockSpec((1, n_theta), lambda *_: (0, 0)))
-        inputs.insert(0, theta.reshape(1, n_theta))
-    if fused:
-        in_specs.append(out_spec)
-        inputs.append(diag)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(Bi, nt, Bj),
-        in_specs=in_specs,
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
-        functools.partial(_gram_tile_kernel, edge_kernel=edge_kernel,
-                          acc_dtype=acc_dtype, fused=fused, mxu=mxu,
-                          tile=t, mt=mt, rank=rank,
-                          with_theta=with_theta),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bi, Bj, n, m), P.dtype),
-        interpret=interpret,
-    )(packs_i.col, packs_i.count, packs_j.col, packs_j.count, *inputs)
+            f"P must be tile-major [Bi, Bj, nt, mt, t, t], got {P.shape}")
+    return _xmv_call(packs_i, packs_j, P, edge_kernel, diag, interpret,
+                     acc_dtype, mode, cross=True, theta=theta)
 
 
 def _kernel(slot_a, col_a, slot_b, col_b,   # scalar-prefetch refs
@@ -884,15 +773,23 @@ def _kernel(slot_a, col_a, slot_b, col_b,   # scalar-prefetch refs
         o_ref[...] = jnp.zeros_like(o_ref)
 
     if batched:
-        a, e = a_ref[0, 0].astype(acc_dtype), e_ref[0, 0]
-        ap, ep = ap_ref[0, 0].astype(acc_dtype), ep_ref[0, 0]
-        p = p_ref[0].astype(acc_dtype)
+        a, e = a_ref[0, 0], e_ref[0, 0]
+        ap, ep = ap_ref[0, 0], ep_ref[0, 0]
+        p = p_ref[0]
     else:
-        a, e = a_ref[0].astype(acc_dtype), e_ref[0]
-        ap, ep = ap_ref[0].astype(acc_dtype), ep_ref[0]
-        p = p_ref[...].astype(acc_dtype)
-    contrib = _contrib(a, e, ap, ep, p, edge_kernel,
-                       acc_dtype).astype(o_ref.dtype)
+        a, e = a_ref[0], e_ref[0]
+        ap, ep = ap_ref[0], ep_ref[0]
+        p = p_ref[...]
+    t = a.shape[-1]
+    # flattening the partner tile in-kernel is fine here: these kernels
+    # run in interpret mode only
+    part = jnp.stack([ap.reshape(t * t), ep.reshape(t * t)])
+    acc = _vpu_contrib(jnp.zeros((t, t * t), acc_dtype),
+                       a.astype(acc_dtype), e.astype(acc_dtype),
+                       part.astype(acc_dtype),
+                       _lane_replicated(p.astype(acc_dtype)), edge_kernel,
+                       None)
+    contrib = _fold_lanes(acc, t).astype(o_ref.dtype)
     if batched:
         contrib = contrib[None]
 

@@ -1,25 +1,26 @@
 """Dense on-the-fly Kronecker XMV — the paper's *tiling & blocking*
 primitive (Sec. III-C / Appendix F), re-tiled for the TPU memory hierarchy.
 
-Mapping from the CUDA kernel (DESIGN.md §2):
+A dense graph is the block-sparse case with every octile present, so the
+dense kernel IS the row-panel kernel of ``kernels/xmv_block_sparse.py``
+run on a dense tiling of (A, E): tile row i holds the nt tiles
+``A[i*t:(i+1)*t, k*t:(k+1)*t]`` in column order and every row counts nt
+slots. Mapping from the CUDA kernel (DESIGN.md §2):
 
   CUDA                                  TPU (this kernel)
   ------------------------------------  --------------------------------
-  t x t octile staged in shared memory  (TI x TJ) / (TIP x TJP) BlockSpec
-                                        blocks staged in VMEM, double-
-                                        buffered by the Pallas pipeline
-  length-r register chunks              VREG-resident 4D broadcast tile
-  warp lanes over product rows          VPU lanes over the (TIP, TJP) axes
-  out block revisit via grid order      reduction grid dims innermost,
-                                        @pl.when zero-init at step 0
+  t x t octile staged in shared memory  a tile row of (A, E) and the
+                                        partner's tile row staged in VMEM,
+                                        double-buffered by the pipeline
+  length-r register chunks              lane-flattened [t, t*t] vregs
+  warp lanes over product rows          VPU lanes over the partner tile
+  out block revisit via grid order      in-kernel slot reduction, one
+                                        write per output tile
 
-For every output block y[I:I+TI, K:K+TIP] the kernel streams the J, L
-contraction blocks of (A, E) and (A', E'), regenerates the product weights
+For every output tile y[i, k] the kernel regenerates the product weights
     w = A[i,j] * A'[k,l] * kappa_e(E[i,j], E'[k,l])
 in VMEM/VREGs (never in HBM — the paper's core idea), multiplies by the
-P[j,l] block and accumulates. Arithmetic intensity grows with the tile
-footprint exactly as the paper's Table I: global traffic per output block
-is O((E+2F)/TILE^2) of the naive kernel's.
+P[j,l] tile and accumulates.
 """
 from __future__ import annotations
 
@@ -27,171 +28,63 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-__all__ = ["xmv_dense", "xmv_dense_batched", "pick_tiles"]
+from .xmv_block_sparse import RowPanelPack, to_tiles, xmv_row_panel, \
+    xmv_row_panel_batched
 
+__all__ = ["DENSE_TILE", "dense_row_panels", "xmv_dense",
+           "xmv_dense_batched"]
 
-def _kernel(*refs, edge_kernel, acc_dtype, fused, with_theta):
-    """One grid step: o[TI, TIP] += contract((A,E) TIxTJ, (A',E') TIPxTJP,
-    P TJxTJP). With ``fused``, the last reduction step instead emits the
-    whole CG operator application diag*p - y for this output block
-    (DESIGN.md §3). With ``with_theta`` the first input ref is a (1, P)
-    hyperparameter vector and kappa is regenerated through
-    ``edge_kernel.apply`` — how traced parameter values reach a kernel
-    whose edge_kernel object is a static jit argument (DESIGN.md §7)."""
-    if with_theta:
-        t_ref, *refs = refs
-    if fused:
-        a_ref, e_ref, ap_ref, ep_ref, p_ref, diag_ref, pe_ref, o_ref = refs
-    else:
-        a_ref, e_ref, ap_ref, ep_ref, p_ref, o_ref = refs
-        diag_ref = pe_ref = None
-    j, l = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(jnp.logical_and(j == 0, l == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a = a_ref[...].astype(acc_dtype)      # [TI, TJ]
-    e = e_ref[...]                        # [TI, TJ]
-    ap = ap_ref[...].astype(acc_dtype)    # [TIP, TJP]
-    ep = ep_ref[...]                      # [TIP, TJP]
-    p = p_ref[...].astype(acc_dtype)      # [TJ, TJP]
-    # regenerate the product-matrix block on the fly: [TI, TJ, TIP, TJP]
-    if with_theta:
-        from repro.core.base_kernels import unpack_theta
-        theta = unpack_theta(edge_kernel, t_ref[0])
-        kappa = edge_kernel.apply(e[:, :, None, None],
-                                  ep[None, None, :, :],
-                                  theta).astype(acc_dtype)
-    else:
-        kappa = edge_kernel(e[:, :, None, None],
-                            ep[None, None, :, :]).astype(acc_dtype)
-    w = a[:, :, None, None] * ap[None, None, :, :] * kappa
-    contrib = jnp.sum(w * p[None, :, None, :], axis=(1, 3))   # [TI, TIP]
-
-    if not fused:
-        o_ref[...] += contrib.astype(o_ref.dtype)
-        return
-
-    acc = o_ref[...] + contrib.astype(o_ref.dtype)
-    last = jnp.logical_and(j == pl.num_programs(2) - 1,
-                           l == pl.num_programs(3) - 1)
-
-    @pl.when(last)
-    def _epilogue():
-        o_ref[...] = (diag_ref[...] * pe_ref[...]).astype(o_ref.dtype) - acc
-
-    @pl.when(jnp.logical_not(last))
-    def _accumulate():
-        o_ref[...] = acc
+# octile edge of the dense tiling (bucket pads are multiples of 8)
+DENSE_TILE = 8
 
 
-def _divisor_tile(dim: int, target: int, quantum: int = 8) -> int:
-    """Largest multiple of ``quantum`` that divides ``dim`` and is <=
-    target; falls back to the largest plain divisor in [2, target]. A
-    prime-ish ``dim`` whose only divisors are 1 and itself is rejected —
-    the old behavior of returning ``dim`` silently blew the VMEM budget
-    once the 4D regeneration tile scaled with it."""
-    if dim <= target:
-        return dim
-    for cand in range(target, 0, -quantum):
-        if cand % quantum == 0 and dim % cand == 0:
-            return cand
-    if dim % quantum == 0:
-        return quantum
-    for cand in range(min(target, dim - 1), 1, -1):
-        if dim % cand == 0:
-            return cand
-    raise ValueError(
-        f"dim={dim} has no tile divisor in [2, {target}]; pad the graph "
-        f"batch to a multiple of {quantum} (e.g. batch_from_graphs("
-        f"pad_to=...)) so the dense XMV kernel can tile it")
+def dense_row_panels(A, E, tile: int = DENSE_TILE) -> RowPanelPack:
+    """Dense ``[..., n, n]`` adjacency/labels -> a RowPanelPack holding
+    every tile: slot k of tile row i is tile column k. Device-side, so
+    the dense path needs no host preprocessing."""
+    values_adj = to_tiles(A, tile)
+    lead, nt = values_adj.shape[:-4], values_adj.shape[-4]
+    col = jnp.broadcast_to(jnp.arange(nt, dtype=jnp.int32), lead + (nt, nt))
+    count = jnp.full(lead + (nt,), nt, jnp.int32)
+    return RowPanelPack(values_adj=values_adj,
+                        values_lab=to_tiles(E, tile), values_w=None,
+                        col=col, count=count)
 
 
-def pick_tiles(n: int, m: int) -> tuple[int, int, int, int]:
-    """Tile-size policy (see EXPERIMENTS.md §Perf for its derivation).
+@functools.partial(jax.jit,
+                   static_argnames=("edge_kernel", "interpret", "acc_dtype"))
+def xmv_dense(A, E, Ap, Ep, P, edge_kernel, *, diag=None, interpret=None,
+              acc_dtype=jnp.float32, theta=None):
+    """Single-pair on-the-fly XMV. A,E: [n,n]; Ap,Ep: [m,m]; P: tile-major
+    ``[n/t, m/t, t, t]`` (``to_tiles``), and so is the result.
 
-    VMEM budget: the 4D regeneration tile TI*TJ*TIP*TJP*4B must stay well
-    under VMEM (~16 MB less pipeline buffers). TJP rides the 128-lane axis;
-    TI*TJ*TIP*TJP = 8*16*8*128 = 128K elements = 512 KB f32 by default.
-    """
-    ti = _divisor_tile(n, 8)
-    tj = _divisor_tile(n, 16)
-    tip = _divisor_tile(m, 8)
-    tjp = _divisor_tile(m, 128)
-    return ti, tj, tip, tjp
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("edge_kernel", "tiles", "interpret", "acc_dtype"))
-def xmv_dense(A, E, Ap, Ep, P, edge_kernel, *, diag=None, tiles=None,
-              interpret=None, acc_dtype=jnp.float32, theta=None):
-    """Single-pair on-the-fly XMV. A,E: [n,n]; Ap,Ep: [m,m]; P: [n,m].
-
-    With ``diag`` ([n, m]) the fused epilogue emits ``diag * P - y``
-    in-kernel — the full CG operator application with no extra XLA op.
+    With ``diag`` (tile-major like P) the fused epilogue emits
+    ``diag * P - y`` in-kernel — the full CG operator application with
+    no extra XLA op.
 
     ``theta`` ([P_theta] f32, ``core.base_kernels.pack_theta`` order)
     overrides the edge kernel's hyperparameters with traced values — the
-    differentiable-MGK path (DESIGN.md §7). It rides as a tiny VMEM
+    differentiable-MGK path (DESIGN.md §7). It rides as a tiny SMEM
     input, so one compiled kernel serves every parameter value."""
-    n, m = A.shape[0], Ap.shape[0]
-    if tiles is None:
-        tiles = pick_tiles(n, m)
-    ti, tj, tip, tjp = tiles
-    if n % ti or n % tj or m % tip or m % tjp:
-        raise ValueError(f"tiles {tiles} must divide shapes n={n}, m={m}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fused = diag is not None
-    with_theta = theta is not None
-    grid = (n // ti, m // tip, n // tj, m // tjp)
-    in_specs = [
-        pl.BlockSpec((ti, tj), lambda i, k, j, l: (i, j)),
-        pl.BlockSpec((ti, tj), lambda i, k, j, l: (i, j)),
-        pl.BlockSpec((tip, tjp), lambda i, k, j, l: (k, l)),
-        pl.BlockSpec((tip, tjp), lambda i, k, j, l: (k, l)),
-        pl.BlockSpec((tj, tjp), lambda i, k, j, l: (j, l)),
-    ]
-    inputs = [A, E, Ap, Ep, P]
-    if with_theta:
-        n_theta = theta.shape[-1]
-        in_specs.insert(0, pl.BlockSpec((1, n_theta),
-                                        lambda i, k, j, l: (0, 0)))
-        inputs.insert(0, theta.reshape(1, n_theta))
-    if fused:
-        in_specs += [pl.BlockSpec((ti, tip), lambda i, k, j, l: (i, k)),
-                     pl.BlockSpec((ti, tip), lambda i, k, j, l: (i, k))]
-        inputs += [diag, P]
-    out = pl.pallas_call(
-        functools.partial(_kernel, edge_kernel=edge_kernel,
-                          acc_dtype=acc_dtype, fused=fused,
-                          with_theta=with_theta),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((ti, tip), lambda i, k, j, l: (i, k)),
-        out_shape=jax.ShapeDtypeStruct((n, m), P.dtype),
-        interpret=interpret,
-    )(*inputs)
-    return out
+    t = P.shape[-1]
+    return xmv_row_panel(dense_row_panels(A, E, t),
+                         dense_row_panels(Ap, Ep, t), P, edge_kernel,
+                         diag=diag, mode="elementwise", interpret=interpret,
+                         acc_dtype=acc_dtype, theta=theta)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("edge_kernel", "interpret", "acc_dtype"))
 def xmv_dense_batched(A, E, Ap, Ep, P, edge_kernel, *, diag=None,
-                      tiles=None, interpret=None, theta=None):
-    """Batched over pairs: leading axis B on every operand (the TPU
-    analogue of 'many graph pairs per kernel launch', paper Sec. V).
-    ``diag`` ([B, n, m], optional) selects the fused-epilogue kernel;
-    ``theta`` ([P_theta], optional, shared across the batch) the traced
+                      interpret=None, acc_dtype=jnp.float32, theta=None):
+    """Batched over pairs in ONE launch: leading axis B on every operand
+    (the TPU analogue of 'many graph pairs per kernel launch', paper
+    Sec. V). P and ``diag`` are tile-major ``[B, n/t, m/t, t, t]``;
+    ``theta`` (optional, shared across the batch) is the traced
     edge-hyperparameter override."""
-    fn = functools.partial(xmv_dense, edge_kernel=edge_kernel, tiles=tiles,
-                           interpret=interpret)
-    if diag is None:
-        return jax.vmap(lambda a, e, ap, ep, p: fn(a, e, ap, ep, p,
-                                                   theta=theta))(
-            A, E, Ap, Ep, P)
-    return jax.vmap(lambda a, e, ap, ep, p, d: fn(a, e, ap, ep, p, diag=d,
-                                                  theta=theta))(
-        A, E, Ap, Ep, P, diag)
+    t = P.shape[-1]
+    return xmv_row_panel_batched(
+        dense_row_panels(A, E, t), dense_row_panels(Ap, Ep, t), P,
+        edge_kernel, diag=diag, mode="elementwise", interpret=interpret,
+        acc_dtype=acc_dtype, theta=theta)

@@ -259,13 +259,7 @@ def moe_layer(p: MoEParams, x, cfg: MoEConfig):
                 aux = jax.lax.pmean(aux, a)
             return out, aux
 
-        # jax.shard_map is jax>=0.6; jax.experimental carries it (with the
-        # pre-rename check_rep kwarg) on the 0.4.x line this image bakes in
-        if hasattr(jax, "shard_map"):
-            smap = functools.partial(jax.shard_map, check_vma=False)
-        else:
-            from jax.experimental.shard_map import shard_map as _shard_map
-            smap = functools.partial(_shard_map, check_rep=False)
+        smap = functools.partial(jax.shard_map, check_vma=False)
         out, aux = smap(
             body, mesh=mesh,
             in_specs=(P(batch_axes, None, None), P(None, None),

@@ -17,11 +17,21 @@ from repro.core.xmv import xmv_elementwise, xmv_full, xmv_lowrank
 from repro.data import make_drugbank_like_dataset
 from repro.kernels.ops import packs_for_batch, xmv_block_sparse_unrolled
 from repro.kernels.xmv_block_sparse import xmv_block_sparse_batched
-from repro.kernels.xmv_dense import xmv_dense_batched
+from repro.kernels.xmv_block_sparse import from_tiles, to_tiles
+from repro.kernels.xmv_dense import DENSE_TILE
+from repro.kernels.xmv_dense import xmv_dense_batched as _xmv_dense_batched
 
 VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=12)
 TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def xmv_dense_batched(A, E, Ap, Ep, P, ek, diag=None):
+    """Node-major view of the tile-major dense kernel."""
+    t = DENSE_TILE
+    return from_tiles(_xmv_dense_batched(
+        A, E, Ap, Ep, to_tiles(P, t), ek,
+        diag=None if diag is None else to_tiles(diag, t)))
 
 
 @pytest.fixture(scope="module")

@@ -368,5 +368,7 @@ def test_gram_tile_vmem_bytes_tracks_pack_dtype():
         f32 = gram_tile_vmem_bytes(pf1, pf2, mxu)
         bf16 = gram_tile_vmem_bytes(pb1, pb2, mxu)
         assert bf16 < f32
-        # operand share halves exactly; the f32 P/diag/out share stays
-        assert f32 - bf16 == (f32 - 8 * (24 * 24 + 2 * 8 * 24)) // 2
+        # operand share halves exactly; the f32 share (the lane-
+        # replicated P panel, t x the pair's n*m, plus diag/out strips)
+        # stays
+        assert f32 - bf16 == (f32 - 8 * (24 * 24 * 8 + 2 * 8 * 24)) // 2

@@ -1,7 +1,10 @@
 """Row-panel block-sparse XMV: parity with the dense oracle across tile
 sizes and modes (elementwise VPU vs MXU low-rank contraction), ragged
 slot counts (including tile rows with ZERO real octiles), the fused
-diagonal epilogue, single-launch jaxpr shape, and the mgk dispatch."""
+diagonal epilogue, single-launch jaxpr shape, and the mgk dispatch.
+
+The kernels read and write P in tile-major order; the helpers below
+convert so every comparison is against the node-major oracle."""
 import numpy as np
 import pytest
 import jax
@@ -15,12 +18,30 @@ from repro.core.xmv import xmv_full
 from repro.data import make_drugbank_like_dataset
 from repro.kernels.ops import row_panel_packs_for_batch, \
     stack_row_panel_packs
-from repro.kernels.xmv_block_sparse import pack_graph_row_panels, \
-    xmv_row_panel, xmv_row_panel_batched
+from repro.kernels.xmv_block_sparse import from_tiles, \
+    pack_graph_row_panels, to_tiles
+from repro.kernels.xmv_block_sparse import xmv_row_panel as _xmv_row_panel
+from repro.kernels.xmv_block_sparse import \
+    xmv_row_panel_batched as _xmv_row_panel_batched
 
 VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=12)
 TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _node_major(kernel):
+    """Node-major view of a tile-major kernel: P/diag in, y out as
+    [..., n, m]."""
+    def call(p1, p2, P, ek, *, diag=None, **kw):
+        t = p1.tile
+        y = kernel(p1, p2, to_tiles(P, t), ek,
+                   diag=None if diag is None else to_tiles(diag, t), **kw)
+        return from_tiles(y)
+    return call
+
+
+xmv_row_panel = _node_major(_xmv_row_panel)
+xmv_row_panel_batched = _node_major(_xmv_row_panel_batched)
 
 
 def _sparse_pair(rng, n, density=0.06, dead_band=None):
@@ -177,8 +198,8 @@ def test_row_panel_is_single_launch(masked_batch):
     for mode in ("elementwise", "mxu"):
         n_calls = _count_primitive(
             jax.make_jaxpr(
-                lambda P: xmv_row_panel_batched(r1, r2, P, EK, mode=mode)
-            )(P).jaxpr, "pallas_call")
+                lambda P: _xmv_row_panel_batched(r1, r2, P, EK, mode=mode)
+            )(to_tiles(P, r1.tile)).jaxpr, "pallas_call")
         assert n_calls == 1, f"{mode}: traced {n_calls} pallas_calls"
 
 

@@ -10,8 +10,16 @@ from repro.core.base_kernels import CompactPolynomial, Constant, \
     SquareExponential
 from repro.core.xmv import xmv_elementwise, xmv_full, xmv_lowrank
 from repro.kernels.ref import xmv_ref
-from repro.kernels.xmv_dense import xmv_dense
+from repro.kernels.xmv_block_sparse import from_tiles, to_tiles
+from repro.kernels.xmv_dense import DENSE_TILE
+from repro.kernels.xmv_dense import xmv_dense as _xmv_dense
 from repro.kernels.xmv_block_sparse import pack_graph, xmv_block_sparse
+
+def xmv_dense(A, E, Ap, Ep, P, ek):
+    """Node-major view of the tile-major dense kernel."""
+    return from_tiles(_xmv_dense(A, E, Ap, Ep,
+                                 to_tiles(jnp.asarray(P), DENSE_TILE), ek))
+
 
 EDGE_KERNELS = [Constant(1.0), SquareExponential(0.8, rank=12),
                 CompactPolynomial(1.0)]

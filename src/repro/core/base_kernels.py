@@ -44,6 +44,8 @@ import math
 
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = [
     "BaseKernel",
     "Constant",
@@ -120,13 +122,15 @@ class BaseKernel:
 def expansion_covers(kernel: BaseKernel, *labels) -> bool:
     """Whether every label array lies where ``kernel``'s truncated
     feature expansion is accurate (``SquareExponential``: [0, domain]);
-    True for kernels without such a domain. Host-side (numpy)."""
+    True for kernels without such a domain. Host-side (numpy): each
+    label array still on the device is one blocking read
+    (``host_syncs``)."""
     domain = getattr(kernel, "domain", None)
     if domain is None:
         return True
     import numpy as np
     return all(float(np.min(x)) >= 0.0 and float(np.max(x)) <= domain
-               for x in map(np.asarray, labels))
+               for x in map(obs.to_host, labels))
 
 
 def pack_theta(kernel: BaseKernel, theta=None):

@@ -20,6 +20,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = ["Graph", "GraphBatch", "pad_graphs", "batch_from_graphs"]
 
 
@@ -165,4 +167,4 @@ def pad_graphs(graphs: Sequence[Graph], pad_to: int | None = None,
 def batch_from_graphs(graphs: Sequence[Graph], pad_to: int | None = None,
                       multiple_of: int = 8) -> GraphBatch:
     arrs = pad_graphs(graphs, pad_to=pad_to, multiple_of=multiple_of)
-    return GraphBatch(**{k: jnp.asarray(v) for k, v in arrs.items()})
+    return GraphBatch(**{k: obs.to_device(v) for k, v in arrs.items()})

@@ -145,16 +145,22 @@ def weighted_operand_grads(A, E, edge_kernel: BaseKernel,
 def xmv_lowrank(A, E, Ap, Ep, P, edge_kernel: BaseKernel):
     """Beyond-paper MXU 'sandwich' XMV (DESIGN.md §2): two dense matmuls
     per feature rank. FLOPs 2R(n^2 m + n m^2) vs the elementwise path's
-    X n^2 m^2 — asymptotically cheaper AND MXU-eligible."""
-    WA = weighted_operands(A, E, edge_kernel)     # [R, n, n]
-    WAp = weighted_operands(Ap, Ep, edge_kernel)  # [R, m, m]
-    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp, precision=_HIGHEST)
+    X n^2 m^2 — asymptotically cheaper AND MXU-eligible. Its operations
+    carry the named scope ``xmv_lowrank``."""
+    with jax.named_scope("xmv_lowrank"):
+        WA = weighted_operands(A, E, edge_kernel)     # [R, n, n]
+        WAp = weighted_operands(Ap, Ep, edge_kernel)  # [R, m, m]
+        return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp,
+                          precision=_HIGHEST)
 
 
 def xmv_lowrank_precomputed(WA, WAp, P):
     """Low-rank XMV with pre-weighted operands (amortized across the CG
-    iterations of one solve — the weighting is loop-invariant)."""
-    return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp, precision=_HIGHEST)
+    iterations of one solve — the weighting is loop-invariant), in the
+    named scope ``xmv_lowrank``."""
+    with jax.named_scope("xmv_lowrank"):
+        return jnp.einsum("rij,jl,rkl->ik", WA, P, WAp,
+                          precision=_HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("edge_kernel", "method", "chunk"))

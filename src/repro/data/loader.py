@@ -16,6 +16,7 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.graph import Graph, GraphBatch, batch_from_graphs
 
 __all__ = ["BucketedDataset", "bucket_graphs", "pair_blocks",
@@ -61,9 +62,10 @@ class BucketedDataset:
         raise KeyError(idx)
 
     def batch(self, indices: Sequence[int], pad_to: int) -> GraphBatch:
-        return batch_from_graphs([self.graphs[i] for i in indices],
-                                 pad_to=pad_to,
-                                 multiple_of=self.multiple_of)
+        with obs.span("mgk.batch"):
+            return batch_from_graphs([self.graphs[i] for i in indices],
+                                     pad_to=pad_to,
+                                     multiple_of=self.multiple_of)
 
 
 def bucket_graphs(graphs: Sequence[Graph], multiple_of: int = 8,

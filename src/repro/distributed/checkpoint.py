@@ -46,6 +46,8 @@ import numpy as np
 
 import jax
 
+from repro import obs
+
 __all__ = ["ChunkStore", "assemble_blocks", "save_array_checkpoint",
            "load_array_checkpoint"]
 
@@ -249,19 +251,20 @@ class ChunkStore:
         given names and come back verbatim from :meth:`load_block`;
         ``meta`` (JSON-serializable) rides in the manifest record
         (:meth:`block_entry`) — the driver's per-block health channel."""
-        if block_id in self.done_blocks():
-            return False
-        import io
-        buf = io.BytesIO()
-        np.savez(buf, rows=rows, cols=cols, values=values,
-                 iterations=iterations, **extra)
-        data = buf.getvalue()
-        path = self.block_path(block_id)
-        _atomic_write(path, data)
-        self._append({"op": "add", "block": int(block_id),
-                      "crc": zlib.crc32(data), "n_pairs": int(len(rows)),
-                      **(meta or {})})
-        return True
+        with obs.span("mgk.save"):
+            if block_id in self.done_blocks():
+                return False
+            import io
+            buf = io.BytesIO()
+            np.savez(buf, rows=rows, cols=cols, values=values,
+                     iterations=iterations, **extra)
+            data = buf.getvalue()
+            path = self.block_path(block_id)
+            _atomic_write(path, data)
+            self._append({"op": "add", "block": int(block_id),
+                          "crc": zlib.crc32(data),
+                          "n_pairs": int(len(rows)), **(meta or {})})
+            return True
 
     def quarantine_block(self, block_id: int, reason: str) -> None:
         """Retire a block from the done set (journal tombstone) and move
@@ -288,34 +291,35 @@ class ChunkStore:
         truncated chunk; "quarantine" instead journals a tombstone,
         moves the bad file aside, and returns None — the restart path's
         recompute-instead-of-abort mode (DESIGN.md §10.3)."""
-        if on_error not in ("raise", "quarantine"):
-            raise ValueError(f"unknown on_error={on_error!r}")
-        path = self.block_path(block_id)
-        entry = self.block_entry(block_id)
-        err = None
-        data = None
-        if entry is None:
-            err = f"block {block_id} not in manifest"
-        else:
-            try:
-                with open(path, "rb") as f:
-                    data = f.read()
-            except OSError as e:
-                err = f"block {block_id} unreadable: {e}"
-        if err is None:
-            want, got = entry["crc"], zlib.crc32(data)
-            if want != got:
-                kind = "truncated" if len(data) == 0 else "corrupt"
-                err = (f"block {block_id} CRC mismatch ({got} != {want})"
-                       f" — {kind} chunk")
-        if err is not None:
-            if on_error == "quarantine":
-                self.quarantine_block(block_id, err)
-                return None
-            raise IOError(err + "; delete the file (or load with "
-                          "on_error='quarantine') to force recompute")
-        import io
-        return dict(np.load(io.BytesIO(data)))
+        with obs.span("mgk.load"):
+            if on_error not in ("raise", "quarantine"):
+                raise ValueError(f"unknown on_error={on_error!r}")
+            path = self.block_path(block_id)
+            entry = self.block_entry(block_id)
+            err = None
+            data = None
+            if entry is None:
+                err = f"block {block_id} not in manifest"
+            else:
+                try:
+                    with open(path, "rb") as f:
+                        data = f.read()
+                except OSError as e:
+                    err = f"block {block_id} unreadable: {e}"
+            if err is None:
+                want, got = entry["crc"], zlib.crc32(data)
+                if want != got:
+                    kind = "truncated" if len(data) == 0 else "corrupt"
+                    err = (f"block {block_id} CRC mismatch ({got} !="
+                           f" {want}) — {kind} chunk")
+            if err is not None:
+                if on_error == "quarantine":
+                    self.quarantine_block(block_id, err)
+                    return None
+                raise IOError(err + "; delete the file (or load with "
+                              "on_error='quarantine') to force recompute")
+            import io
+            return dict(np.load(io.BytesIO(data)))
 
     def assemble_gram(self, n: int, normalize: bool = False,
                       key: str = "values", strict: bool = True,

@@ -29,6 +29,7 @@ repeatedly failing buckets are deprioritized on replanning
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 from typing import Callable, Iterable
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.base_kernels import BaseKernel, Constant, \
     expansion_covers
 from repro.core.graph import GraphBatch
@@ -102,6 +104,10 @@ class GraphPackCache:
     statistics, stacked per pair batch (:meth:`stacked_factors`) or per
     Gram-tile axis (mirroring :meth:`stacked_axis`). A few O(n²) host
     arrays per graph; evicted and rebuilt with the packs.
+
+    Lookups count as ``pack_cache.hit`` / ``pack_cache.miss`` in the
+    program's counters (:mod:`repro.obs`); a miss builds under the span
+    ``mgk.pack``, stacking and the transfer run under ``mgk.stack``.
     """
 
     def __init__(self, tile: int = 8, edge_kernel=None,
@@ -117,8 +123,6 @@ class GraphPackCache:
         self._factors: "collections.OrderedDict" = \
             collections.OrderedDict()
         self.stats: dict = {}        # (idx, pad) -> octile/nnz/density
-        self.hits = 0
-        self.misses = 0
 
     def _lru_get(self, store, key, build) -> dict:
         """Shared LRU lookup for the pack and factor stores: counts
@@ -126,13 +130,14 @@ class GraphPackCache:
         store at ``max_entries``, builds on miss."""
         hit = store.get(key)
         if hit is not None:
-            self.hits += 1
+            obs.count("pack_cache.hit")
             store.move_to_end(key)
             return hit
-        self.misses += 1
+        obs.count("pack_cache.miss")
         while len(store) >= self.max_entries:
             store.popitem(last=False)
-        entry = build()
+        with obs.span("mgk.pack"):
+            entry = build()
         store[key] = entry
         return entry
 
@@ -168,11 +173,11 @@ class GraphPackCache:
 
         def build():
             f = kron_factor_arrays(
-                np.asarray(batch.adjacency[b]),
-                np.asarray(batch.degrees[b]),
-                np.asarray(batch.edge_labels[b]),
-                np.asarray(batch.vertex_labels[b]),
-                np.asarray(batch.node_mask[b]))
+                obs.to_host(batch.adjacency[b]),
+                obs.to_host(batch.degrees[b]),
+                obs.to_host(batch.edge_labels[b]),
+                obs.to_host(batch.vertex_labels[b]),
+                obs.to_host(batch.node_mask[b]))
             return {name: np.asarray(getattr(f, name))
                     for name in KronFactors._fields}
 
@@ -188,13 +193,14 @@ class GraphPackCache:
         from repro.core.precond import KronFactors
         B = batch.adjacency.shape[0]
         pad_to = batch.adjacency.shape[1]
-        entries = []
-        for b in range(B):
-            idx = int(indices[b]) if b < len(indices) else -1
-            entries.append(self._factor(idx, batch, b, pad_to))
-        return KronFactors(**{
-            name: jnp.asarray(np.stack([e[name] for e in entries]))
-            for name in KronFactors._fields})
+        with obs.span("mgk.stack"):
+            entries = []
+            for b in range(B):
+                idx = int(indices[b]) if b < len(indices) else -1
+                entries.append(self._factor(idx, batch, b, pad_to))
+            return KronFactors(**{
+                name: obs.to_device(np.stack([e[name] for e in entries]))
+                for name in KronFactors._fields})
 
     def density(self, idx: int, pad_to: int) -> float | None:
         """Measured octile occupancy of graph ``idx`` at bucket pad
@@ -225,23 +231,26 @@ class GraphPackCache:
                 f"bucket padded to {pad_to}, not a multiple of"
                 f" tile={self.tile}; pad buckets to a multiple of the"
                 f" tile edge (loader multiple_of)")
-        entries = []
-        for b in range(B):
-            idx = int(indices[b]) if b < len(indices) else -1
-            entries.append(self._pack(idx, np.asarray(batch.adjacency[b]),
-                                      np.asarray(batch.edge_labels[b]),
-                                      pad_to))
-        k_max = max(e["col"].shape[1] for e in entries)
+        with obs.span("mgk.stack"):
+            entries = []
+            for b in range(B):
+                idx = int(indices[b]) if b < len(indices) else -1
+                entries.append(self._pack(
+                    idx, obs.to_host(batch.adjacency[b]),
+                    obs.to_host(batch.edge_labels[b]), pad_to))
+            k_max = max(e["col"].shape[1] for e in entries)
 
-        def stack(field):
-            if entries[0][field] is None:
-                return None
-            if field == "count":
-                return jnp.asarray(np.stack([e[field] for e in entries]))
-            return jnp.asarray(np.stack(
-                [self._pad_k(e[field], k_max) for e in entries]))
+            def stack(field):
+                if entries[0][field] is None:
+                    return None
+                if field == "count":
+                    return obs.to_device(
+                        np.stack([e[field] for e in entries]))
+                return obs.to_device(np.stack(
+                    [self._pad_k(e[field], k_max) for e in entries]))
 
-        return RowPanelPack(**{f: stack(f) for f in RowPanelPack._fields})
+            return RowPanelPack(**{f: stack(f)
+                                   for f in RowPanelPack._fields})
 
     def stacked_axis(self, indices, batch: GraphBatch):
         """PER-AXIS pack for Gram-tile execution (DESIGN.md §8): one
@@ -295,7 +304,7 @@ def pair_shardings(mesh: Mesh) -> tuple:
         n_nodes=ns(b),
     )
     out_shard = MGKResult(values=ns(b), iterations=ns(b), converged=ns(b),
-                          nodal=None, status=ns(b))
+                          nodal=None, matvec_pairs=ns(), status=ns(b))
     return (g1_shard, g2_shard), out_shard
 
 
@@ -442,10 +451,12 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                                pack_dtype=pack_dtype)
 
         def _resolve_block_mode(g1, g2):
-            if mode == "mxu" and sparse_mode == "auto" and \
-                    not expansion_covers(edge_kernel, g1.edge_labels,
-                                         g2.edge_labels):
-                return "elementwise"
+            if mode == "mxu" and sparse_mode == "auto":
+                with obs.span("mgk.label_check"):
+                    covered = expansion_covers(edge_kernel, g1.edge_labels,
+                                               g2.edge_labels)
+                if not covered:
+                    return "elementwise"
             return mode
 
         kron = precond == "kron"
@@ -591,7 +602,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                         guard=guard, **solve_kw, **precond_kw)
         return MGKResult(values=res.values, iterations=res.iterations,
                          converged=res.converged, nodal=None,
-                         status=res.status)
+                         matvec_pairs=res.matvec_pairs, status=res.status)
 
     jstep = jax.jit(step, in_shardings=(g1_s, g2_s), out_shardings=out_s)
 
@@ -609,7 +620,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                         **solve_kw, **precond_kw)
         return MGKResult(values=res.values, iterations=res.iterations,
                          converged=res.converged, nodal=None,
-                         status=res.status)
+                         matvec_pairs=res.matvec_pairs, status=res.status)
 
     dense_step.lower = jstep.lower
     return dense_step
@@ -664,29 +675,39 @@ def solve_pair_block(ds: BucketedDataset, block: PairBlock, step: Callable,
     """Run one PairBlock through the sharded step; returns host arrays.
 
     ``fault``/``spd_margin`` forward to the step's injection seams
-    (only passed when set — gradient steps don't take them)."""
+    (only passed when set — gradient steps don't take them). The
+    results come back in one device-to-host read, with the solve's
+    ``matvec_pairs`` (counted, not returned)."""
     B = block.n_pairs
     kw = {}
     if fault is not None:
         kw["fault"] = fault
     if spd_margin is not None:
         kw["spd_margin"] = spd_margin
-    res = step(*_block_args(ds, block, step, pair_width), **kw)
+    args = _block_args(ds, block, step, pair_width)
+    with obs.span("mgk.dispatch"):
+        res = step(*args, **kw)
     grads = None
     if getattr(step, "with_grad", False):
         res, grads = res
+    with obs.span("mgk.readback"):
+        obs.count("host_syncs")
+        values, iterations, status, matvec_pairs, grads = jax.device_get(
+            (res.values, res.iterations, res.status, res.matvec_pairs,
+             grads))
+    if matvec_pairs is not None:
+        obs.count("matvec_pairs", matvec_pairs)
     out = {
         "rows": np.asarray(block.rows),
         "cols": np.asarray(block.cols),
-        "values": np.asarray(res.values)[:B],
-        "iterations": np.asarray(res.iterations)[:B],
+        "values": values[:B],
+        "iterations": iterations[:B],
     }
-    if res.status is not None:
-        out["status"] = np.asarray(res.status)[:B]
+    if status is not None:
+        out["status"] = status[:B]
     if grads is not None:
         # ∂K/∂θ blocks ride along as extra arrays, one per flat key
-        out.update({f"grad_{k}": np.asarray(v)[:B]
-                    for k, v in grads.items()})
+        out.update({f"grad_{k}": v[:B] for k, v in grads.items()})
     return out
 
 
@@ -720,7 +741,11 @@ class GramDriver:
     production. After a run, ``self.health`` holds retry/escalation
     counters, the quarantined (i, j) list, a per-block recovery trail,
     and the per-bucket count of pairs that hit max_iter without
-    reaching tol (also journaled via ``store.note`` and logged).
+    reaching tol (also journaled via ``store.note`` and logged), and
+    under ``"counters"`` what the program's counters (:mod:`repro.obs`)
+    gained during the run. Each run is a span ``mgk.build``, each block
+    a span ``mgk.block`` (attribute ``block``), each ladder attempt
+    after a block's first a span ``mgk.retry`` (attribute ``rung``).
     """
     ds: BucketedDataset
     mesh: Mesh
@@ -972,17 +997,21 @@ class GramDriver:
             for retry in range(tries):
                 if retry > 0:
                     self.health["retries"] += 1
-                if overrides is None:
-                    out = self._reference_block(block)
-                else:
-                    step = self._build_step(with_grad, overrides)
-                    fault = inj.block_fault(bid, attempt) if inj else None
-                    margin = inj.block_spd_margin(
-                        bid, attempt,
-                        overrides.get("precond", self.precond)) \
-                        if inj else None
-                    out = solve_pair_block(self.ds, block, step, width,
-                                           fault=fault, spd_margin=margin)
+                with obs.span("mgk.retry", rung=rung_idx) if attempt \
+                        else contextlib.nullcontext():
+                    if overrides is None:
+                        out = self._reference_block(block)
+                    else:
+                        step = self._build_step(with_grad, overrides)
+                        fault = inj.block_fault(bid, attempt) \
+                            if inj else None
+                        margin = inj.block_spd_margin(
+                            bid, attempt,
+                            overrides.get("precond", self.precond)) \
+                            if inj else None
+                        out = solve_pair_block(self.ds, block, step, width,
+                                               fault=fault,
+                                               spd_margin=margin)
                 attempt += 1
                 bad = self._bad_pairs(out)
                 if not bad.any():
@@ -1070,6 +1099,14 @@ class GramDriver:
 
     def _run(self, progress, with_grad: bool):
         self.health = self._fresh_health()
+        before = obs.counters()
+        try:
+            with obs.span("mgk.build"):
+                return self._build(progress, with_grad)
+        finally:
+            self.health["counters"] = obs.delta(before)
+
+    def _build(self, progress, with_grad: bool):
         step = self._build_step(with_grad, {})
         self._pack_cache = getattr(step, "pack_cache", None)
         blocks = self.blocks()
@@ -1082,18 +1119,19 @@ class GramDriver:
         n_done = 0
         while pending:
             bid = pending.pop(0)
-            out, meta = self._solve_block_healed(by_id[bid], with_grad,
-                                                 width)
-            if meta:
-                self.health["blocks"][bid] = meta
-            if self.store:
-                self.store.save_block(bid, meta=meta, **out)
-                if self.faults is not None:
-                    # injection seam: may corrupt the chunk on disk
-                    # and/or raise DriverKilled (mid-build crash)
-                    self.faults.after_block_saved(self.store, bid)
-            else:
-                results[bid] = out
+            with obs.span("mgk.block", block=bid):
+                out, meta = self._solve_block_healed(by_id[bid], with_grad,
+                                                     width)
+                if meta:
+                    self.health["blocks"][bid] = meta
+                if self.store:
+                    self.store.save_block(bid, meta=meta, **out)
+                    if self.faults is not None:
+                        # injection seam: may corrupt the chunk on disk
+                        # and/or raise DriverKilled (mid-build crash)
+                        self.faults.after_block_saved(self.store, bid)
+                else:
+                    results[bid] = out
             n_done += 1
             if progress:
                 progress(n_done, len(todo))
@@ -1120,11 +1158,12 @@ class GramDriver:
             missing = [b.block_id for b in blocks
                        if b.block_id not in results]
             for bid in missing:
-                out, meta = self._solve_block_healed(by_id[bid],
-                                                     with_grad, width)
-                if meta:
-                    self.health["blocks"][bid] = meta
-                self.store.save_block(bid, meta=meta, **out)
+                with obs.span("mgk.block", block=bid):
+                    out, meta = self._solve_block_healed(by_id[bid],
+                                                         with_grad, width)
+                    if meta:
+                        self.health["blocks"][bid] = meta
+                    self.store.save_block(bid, meta=meta, **out)
                 results[bid] = out
         if with_grad:
             # a store populated by a plain run() has value-only blocks;
@@ -1143,8 +1182,9 @@ class GramDriver:
                             f" is not part of the current block plan"
                             f" (pairs_per_block changed?) — rerun with the"
                             f" original pairs_per_block or a fresh store")
-                    results[bid], _ = self._solve_block_healed(
-                        by_id[bid], with_grad, width)
+                    with obs.span("mgk.block", block=bid):
+                        results[bid], _ = self._solve_block_healed(
+                            by_id[bid], with_grad, width)
 
         self._nonconvergence_summary(results, by_id)
 
@@ -1160,20 +1200,23 @@ class GramDriver:
             return assemble_blocks(results.values(), n, key,
                                    strict=strict)
 
-        K = assemble("values")
-        grads = None
-        if with_grad:
-            keys = [k for k in next(iter(results.values()))
-                    if k.startswith("grad_")]
-            grads = {k[len("grad_"):]: assemble(k) for k in keys}
-        if self.normalize:
-            d = np.sqrt(np.diag(K))
-            Kn = K / d[:, None] / d[None, :]
-            if grads is not None:
-                grads = {
-                    name: (g / d[:, None] / d[None, :]
-                           - 0.5 * Kn * (np.diag(g) / np.diag(K))[:, None]
-                           - 0.5 * Kn * (np.diag(g) / np.diag(K))[None, :])
-                    for name, g in grads.items()}
-            K = Kn
+        with obs.span("mgk.assemble"):
+            K = assemble("values")
+            grads = None
+            if with_grad:
+                keys = [k for k in next(iter(results.values()))
+                        if k.startswith("grad_")]
+                grads = {k[len("grad_"):]: assemble(k) for k in keys}
+            if self.normalize:
+                d = np.sqrt(np.diag(K))
+                Kn = K / d[:, None] / d[None, :]
+                if grads is not None:
+                    grads = {
+                        name: (g / d[:, None] / d[None, :]
+                               - 0.5 * Kn
+                               * (np.diag(g) / np.diag(K))[:, None]
+                               - 0.5 * Kn
+                               * (np.diag(g) / np.diag(K))[None, :])
+                        for name, g in grads.items()}
+                K = Kn
         return K, grads

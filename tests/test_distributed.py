@@ -8,6 +8,7 @@ import pytest
 import jax
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.core import KroneckerDelta, SquareExponential
 from repro.data import bucket_graphs, make_drugbank_like_dataset, \
     pair_blocks
@@ -140,6 +141,7 @@ def test_sparse_step_caches_packs_per_graph(monkeypatch):
     monkeypatch.setattr(octile_mod, "octile_decompose", counting)
     step = gram_pair_step(_mesh(), VK, EK, method="pallas_sparse")
     assert getattr(step, "wants_indices", False)
+    before = obs.counters()
     outs = [solve_pair_block(ds, b, step, 1) for b in blocks]
     # every (graph, bucket pad) combination decomposed exactly once, plus
     # at most one dummy pack per pad size — far below once-per-block
@@ -147,7 +149,7 @@ def test_sparse_step_caches_packs_per_graph(monkeypatch):
                {(int(i), b.pad_col) for b in blocks for i in b.cols}
     assert calls["n"] <= len(distinct) + len(
         {b.pad_row for b in blocks} | {b.pad_col for b in blocks})
-    assert step.pack_cache.hits > 0
+    assert obs.delta(before).get("pack_cache.hit", 0) > 0
     # and the cached path computes the same values as the dense reference
     from repro.distributed.gram import gram_pair_step as gps
     ref_step = gps(_mesh(), VK, EK, method="lowrank")
@@ -190,9 +192,9 @@ def test_pack_cache_lru_eviction_roundtrip():
     assert len(cache._packs) == 2
     assert (0, 32) not in cache._packs          # evicted...
     assert cache.density(0, 32) is not None     # ...stats persist
-    misses = cache.misses
+    before = obs.counters()
     again = cache.stacked(np.array([0]), one(0))
-    assert cache.misses == misses + 1           # re-packed, not cached
+    assert obs.delta(before).get("pack_cache.miss") == 1   # re-packed
     for a, b in zip(first, again):
         if a is None:
             assert b is None

@@ -218,12 +218,8 @@ def mgk_value_fn(
         (device_weighted_pack, weighted operands) is hoisted out of the
         per-name loop — it already carries every parameter's slice."""
         if sparse:
-            have_w = packs1.values_w is not None and \
-                packs2.values_w is not None
-            # mirror _make_sparse_matvec: "auto" follows pack-time intent
-            mxu = sparse_mode == "mxu" or (sparse_mode == "auto"
-                                           and have_w)
-            if mxu:
+            # mirror _make_sparse_matvec: "auto" runs elementwise
+            if sparse_mode == "mxu":
                 from repro.kernels.ops import device_weighted_pack, \
                     xmv_gram_tile, xmv_row_panel_batched
                 if trust_pack_weights and packs1.values_grad is not None \
@@ -421,14 +417,11 @@ def mgk_adaptive_value_and_grad(
               kron_rank=kron_rank)
     if route.startswith("sparse"):
         from repro.kernels.ops import row_panel_packs_for_batch
-        ek_pack = edge_kernel if route == "sparse_mxu" else None
-        p1 = row_panel_packs_for_batch(g1, tile=tile, edge_kernel=ek_pack)
-        p2 = row_panel_packs_for_batch(g2, tile=tile, edge_kernel=ek_pack)
         fn = mgk_value_fn(
             g1, g2, vertex_kernel, edge_kernel, method="sparse",
-            packs1=p1, packs2=p2,
-            sparse_mode="mxu" if route == "sparse_mxu" else "elementwise",
-            **kw)
+            packs1=row_panel_packs_for_batch(g1, tile=tile),
+            packs2=row_panel_packs_for_batch(g2, tile=tile),
+            sparse_mode="elementwise", **kw)
     else:
         fn = mgk_value_fn(g1, g2, vertex_kernel, edge_kernel,
                           method=route, **kw)

@@ -330,11 +330,9 @@ def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
     if row_panel:
         have_w = packs1.values_w is not None and \
             packs2.values_w is not None
-        # "auto" follows the PACK-TIME intent exactly like _resolve_mode:
-        # packs built without weights run elementwise (exact, theta via
-        # the in-kernel vector) even when the edge kernel could expand —
-        # a theta override must not silently introduce truncation error
-        mxu = sparse_mode == "mxu" or (sparse_mode == "auto" and have_w)
+        # "auto" is the kernels' own rule (_resolve_mode): elementwise
+        # (exact, theta via the in-kernel vector), weighted packs or not
+        mxu = sparse_mode == "mxu"
         if mxu and (theta_e is not None or not have_w):
             packs1 = device_weighted_pack(packs1, edge_kernel,
                                           theta=theta_e)
@@ -472,8 +470,7 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
     =============  ==================  =====================================
     octile dens.   feature expansion   route
     =============  ==================  =====================================
-    < threshold    usable              "sparse_mxu"  (row-panel, MXU)
-    < threshold    none                "sparse_vpu"  (row-panel, VPU)
+    < threshold    any                 "sparse_vpu"  (row-panel, VPU)
     >= threshold   usable              "lowrank"     (dense MXU sandwich)
     >= threshold   none                "pallas"      (dense tiling kernel)
     =============  ==================  =====================================
@@ -481,7 +478,9 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
     "usable" = ``feature_rank()`` is not None, the rank is small against
     ``density * n``, and the labels stay inside the expansion's accuracy
     domain (the SE Taylor truncation) — otherwise exact elementwise
-    paths. Returns (route, tile) with ``tile`` shrunk to the largest of
+    paths. Sparse buckets run the row-panel kernels' elementwise body,
+    the one "auto" picks (``kernels.xmv_block_sparse._resolve_mode``).
+    Returns (route, tile) with ``tile`` shrunk to the largest of
     {tile, 16, 8} dividing the bucket's padded size.
     """
     rank = edge_kernel.feature_rank()
@@ -495,7 +494,7 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
         rank = None
     rank_usable = rank is not None and rank <= max(16, dens * n)
     if dens < density_threshold:
-        return ("sparse_mxu" if rank_usable else "sparse_vpu"), tile
+        return "sparse_vpu", tile
     return ("lowrank" if rank_usable else "pallas"), tile
 
 
@@ -526,14 +525,10 @@ def mgk_adaptive(g1: GraphBatch, g2: GraphBatch,
               spd_margin=spd_margin)
     if route.startswith("sparse"):
         from repro.kernels.ops import row_panel_packs_for_batch
-        ek_pack = edge_kernel if route == "sparse_mxu" else None
         return mgk_pairs_sparse(
-            g1, g2,
-            row_panel_packs_for_batch(g1, tile=tile, edge_kernel=ek_pack),
-            row_panel_packs_for_batch(g2, tile=tile, edge_kernel=ek_pack),
-            vertex_kernel, edge_kernel,
-            sparse_mode="mxu" if route == "sparse_mxu" else "elementwise",
-            **kw)
+            g1, g2, row_panel_packs_for_batch(g1, tile=tile),
+            row_panel_packs_for_batch(g2, tile=tile),
+            vertex_kernel, edge_kernel, sparse_mode="elementwise", **kw)
     return mgk_pairs(g1, g2, vertex_kernel, edge_kernel, method=route,
                      **kw)
 

@@ -41,8 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.core.base_kernels import BaseKernel, Constant, \
-    expansion_covers
+from repro.core.base_kernels import BaseKernel, Constant
 from repro.core.graph import GraphBatch
 from repro.core.mgk import MGKResult, mgk_pairs, mgk_pairs_sparse
 from repro.core.pcg import PCG_BREAKDOWN, PCG_DIVERGENCE, PCG_MAX_ITER, \
@@ -398,8 +397,14 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
     tensors), served from a :class:`GraphPackCache` keyed by dataset
     index so each graph is decomposed once per bucket size instead of
     once per pair block; the whole bucket then solves in one row-panel
-    kernel launch per CG matvec. ``sparse_mode`` "auto" uses the MXU
-    contraction whenever ``edge_kernel`` has a feature expansion;
+    kernel launch per CG matvec. ``sparse_mode`` "auto" runs the
+    elementwise contraction, the one measured faster on the chip at
+    every octile edge and rank a workload runs (DESIGN.md §3.4);
+    "mxu" (the low-rank contraction; needs a feature-expandable edge
+    kernel) and "elementwise" are honoured as given. Only an MXU step
+    builds, stacks and sends the weighted operands. Each block solve
+    counts ``xmv.contraction.mxu`` or ``xmv.contraction.elementwise``
+    (:mod:`repro.obs`).
     ``tile`` sets the octile edge (buckets must pad to a multiple).
     The step accepts optional ``rows``/``cols`` dataset indices (the
     driver passes them; without them the packs are built uncached).
@@ -434,42 +439,29 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 "segment_size is forward-only: the adjoint custom_vjp"
                 " (run_with_grad) solves with lockstep pcg_solve —"
                 " unset segment_size for gradient runs")
-        expand = edge_kernel.feature_rank() is not None and \
-            sparse_mode in ("auto", "mxu")
-        if sparse_mode == "mxu" and not expand:
+        if sparse_mode not in ("auto", "mxu", "elementwise"):
+            raise ValueError(f"unknown sparse_mode {sparse_mode!r}")
+        if sparse_mode == "mxu" and edge_kernel.feature_rank() is None:
             raise ValueError(
                 f"sparse_mode='mxu' needs a feature-expandable edge"
                 f" kernel, got {type(edge_kernel).__name__}")
-        ek_pack = edge_kernel if expand else None
-        mode = "mxu" if expand else "elementwise"
-        # the expansion's accuracy domain (SE Taylor truncation): under
-        # "auto", blocks whose labels leave it run exact elementwise —
-        # same guard as mgk_adaptive; explicit "mxu" is honored as given
-        cache = GraphPackCache(tile=tile, edge_kernel=ek_pack,
-                               max_entries=pack_cache_entries,
-                               with_grad=with_grad,
-                               pack_dtype=pack_dtype)
-
-        def _resolve_block_mode(g1, g2):
-            if mode == "mxu" and sparse_mode == "auto":
-                with obs.span("mgk.label_check"):
-                    covered = expansion_covers(edge_kernel, g1.edge_labels,
-                                               g2.edge_labels)
-                if not covered:
-                    return "elementwise"
-            return mode
+        mode = "mxu" if sparse_mode == "mxu" else "elementwise"
+        # only an MXU step builds, stacks and sends the weighted operands
+        cache = GraphPackCache(
+            tile=tile, edge_kernel=edge_kernel if mode == "mxu" else None,
+            max_entries=pack_cache_entries, with_grad=with_grad,
+            pack_dtype=pack_dtype)
 
         kron = precond == "kron"
 
         def _block_packs(g1, g2, rows, cols):
-            """(packs1, packs2, mode, gram_tile_shape, factors) for one
+            """(packs1, packs2, gram_tile_shape, factors) for one
             block: per-AXIS packs + (Bi, Bj) when the block is a
             rectangle and gram_tile execution is on, else per-pair
             packs + None. ``factors`` are the cached Kronecker
             preconditioner factors — stacked with the SAME granularity
             as the packs (per-axis / per-pair) — or (None, None) under
             Jacobi."""
-            block_mode = _resolve_block_mode(g1, g2)
             axes = _axis_structure(rows, cols) \
                 if gram_tile and rows is not None and cols is not None \
                 else None
@@ -488,18 +480,18 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 # route buckets whose per-step envelope (graph j's whole
                 # pack + the P panel) would crowd VMEM back to the
                 # per-pair kernel, whose P BlockSpec streams instead
-                if gram_tile_vmem_bytes(p1, p2, block_mode == "mxu") \
+                if gram_tile_vmem_bytes(p1, p2, mode == "mxu") \
                         <= _GRAM_TILE_VMEM_BUDGET:
                     facs = (cache.stacked_factors(urows, g1u),
                             cache.stacked_factors(ucols, g2u)) \
                         if kron else (None, None)
-                    return p1, p2, block_mode, (Bi, Bj), facs
+                    return p1, p2, (Bi, Bj), facs
             if rows is None or cols is None:
                 p1 = row_panel_packs_for_batch(g1, tile=tile,
-                                               edge_kernel=ek_pack,
+                                               edge_kernel=cache.edge_kernel,
                                                with_grad=with_grad)
                 p2 = row_panel_packs_for_batch(g2, tile=tile,
-                                               edge_kernel=ek_pack,
+                                               edge_kernel=cache.edge_kernel,
                                                with_grad=with_grad)
                 facs = (None, None)   # uncached: factors derived in-trace
             else:
@@ -508,7 +500,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 facs = (cache.stacked_factors(rows, g1),
                         cache.stacked_factors(cols, g2)) \
                     if kron else (None, None)
-            return p1, p2, block_mode, None, facs
+            return p1, p2, None, facs
 
         if with_grad:
             from repro.core.adjoint import flatten_grads, kernel_theta, \
@@ -516,11 +508,11 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
             theta = kernel_theta(vertex_kernel, edge_kernel)
 
             def grad_sparse_step(g1, g2, rows=None, cols=None):
-                p1, p2, block_mode, gt, facs = _block_packs(g1, g2,
-                                                            rows, cols)
+                p1, p2, gt, facs = _block_packs(g1, g2, rows, cols)
+                obs.count(f"xmv.contraction.{mode}")
                 fn = mgk_value_fn(g1, g2, vertex_kernel, edge_kernel,
                                   method="sparse", packs1=p1, packs2=p2,
-                                  sparse_mode=block_mode,
+                                  sparse_mode=mode,
                                   trust_pack_weights=True, gram_tile=gt,
                                   precond_factors=facs,
                                   **solve_kw, **precond_kw)
@@ -538,10 +530,9 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
             return grad_sparse_step
 
         def _solve_args(g1, g2, rows, cols):
-            p1, p2, block_mode, gt, (f1, f2) = _block_packs(g1, g2,
-                                                            rows, cols)
+            p1, p2, gt, (f1, f2) = _block_packs(g1, g2, rows, cols)
             return ((g1, g2, p1, p2, vertex_kernel, edge_kernel),
-                    dict(sparse_mode=block_mode, gram_tile=gt,
+                    dict(sparse_mode=mode, gram_tile=gt,
                          factors1=f1, factors2=f2, guard=guard,
                          **precond_kw))
 
@@ -549,6 +540,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                         rows=None, cols=None, fault=None,
                         spd_margin=None) -> MGKResult:
             args, kw = _solve_args(g1, g2, rows, cols)
+            obs.count(f"xmv.contraction.{mode}")
             if segment_size is not None:
                 res = mgk_pairs_sparse_segmented(
                     *args, tol=tol, max_iter=max_iter,

@@ -522,18 +522,20 @@ def _xmv_kernel(col1, cnt1, col2, cnt2,   # scalar-prefetch refs (SMEM)
 
 def _resolve_mode(mode: str, packs1: RowPanelPack,
                   packs2: RowPanelPack) -> bool:
-    """Map the mode knob to the mxu flag, validating pack contents."""
-    have_w = packs1.values_w is not None and packs2.values_w is not None
-    if mode == "auto":
-        return have_w
+    """Map the mode knob to the mxu flag, validating pack contents.
+    "auto" runs the elementwise body, even on packs that carry the
+    weighted tiles: on a TPU v5e it took less device time per Gram-tile
+    matvec than the MXU body at every octile edge and rank a workload
+    runs. The MXU's one measured lead, at edge 32 with rank 4, has no
+    workload behind it; "mxu" takes it explicitly (DESIGN.md §3.4)."""
+    if mode in ("auto", "elementwise"):
+        return False
     if mode == "mxu":
-        if not have_w:
+        if packs1.values_w is None or packs2.values_w is None:
             raise ValueError(
                 "mode='mxu' needs packs built with a feature-expandable"
                 " edge kernel (pack_row_panels(..., edge_kernel=...))")
         return True
-    if mode == "elementwise":
-        return False
     raise ValueError(f"unknown row-panel mode {mode!r}")
 
 
@@ -651,7 +653,7 @@ def xmv_row_panel(pack1: RowPanelPack, pack2: RowPanelPack, P, edge_kernel,
     ``P`` (and the result) are tile-major ``[nt, mt, t, t]``
     (:func:`to_tiles`). ``mode``: "elementwise" (VPU, any edge kernel),
     "mxu" (low-rank contraction; needs packs built with the edge kernel),
-    or "auto" (mxu iff both packs carry precomputed weighted tiles).
+    or "auto" (elementwise, the body measured faster on the chip).
 
     With ``diag`` (tile-major like P) the kernel instead returns the
     fused CG operator application ``diag * P - y``. ``theta`` ([P_theta]

@@ -15,9 +15,11 @@ taking a snapshot before and after the work of interest::
 
 ``h2d_bytes`` counts bytes the program puts on the device,
 ``host_syncs`` its blocking device-to-host reads, ``matvec_pairs`` the
-pair-matvecs its PCG solves ran (lockstep pairs included), and
+pair-matvecs its PCG solves ran (lockstep pairs included),
 ``pack_cache.hit`` / ``pack_cache.miss`` the lookups of the Gram
-driver's pack cache. :func:`to_device` and :func:`to_host` move an
+driver's pack cache, and ``xmv.contraction.mxu`` /
+``xmv.contraction.elementwise`` the sparse step's block solves by the
+contraction they ran. :func:`to_device` and :func:`to_host` move an
 array and count it.
 """
 from __future__ import annotations

@@ -161,17 +161,45 @@ def test_sparse_step_caches_packs_per_graph(monkeypatch):
 
 def test_sparse_step_domain_guard_falls_back_to_elementwise():
     """sparse_mode='auto' must not use the Taylor expansion outside its
-    accuracy domain — the block falls back to exact elementwise (the
-    mgk_adaptive guard, applied per pair block)."""
+    accuracy domain: the block runs exact elementwise, as "auto" does
+    for every block, and matches the elementwise reference."""
     from repro.distributed.gram import gram_pair_step, solve_pair_block
     ds = _dataset(6)
     blocks = list(pair_blocks(ds, pairs_per_block=6))
     ek = SquareExponential(1.0, rank=10, domain=0.0)   # always out of domain
     step = gram_pair_step(_mesh(), VK, ek, method="pallas_sparse")
+    before = obs.counters()
     out = solve_pair_block(ds, blocks[0], step, 1)
+    assert obs.delta(before).get("xmv.contraction.elementwise") == 1
+    assert step.pack_cache.edge_kernel is None
     ref_step = gram_pair_step(_mesh(), VK, ek, method="elementwise")
     ref = solve_pair_block(ds, blocks[0], ref_step, 1)
     np.testing.assert_allclose(out["values"], ref["values"], rtol=1e-4)
+
+
+@pytest.mark.parametrize("mode", ["auto", "mxu"])
+def test_sparse_driver_contraction_and_counters(mode):
+    """Under "auto" the Gram-tile driver runs the elementwise
+    contraction: the pack cache holds no weighted operands and every block solve counts
+    ``xmv.contraction.elementwise``. An explicit "mxu" builds the weights
+    and counts ``xmv.contraction.mxu``. Either way the Gram matches the
+    direct dense solve."""
+    from repro.core.reference import mgk_direct
+    ds = _dataset(5)
+    drv = GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
+                     gram_tile=True, tile_shape=(2, 2), sparse_mode=mode,
+                     normalize=False, tol=1e-10)
+    K = drv.run()
+    want = "elementwise" if mode == "auto" else "mxu"
+    contractions = {k: v for k, v in drv.health["counters"].items()
+                    if k.startswith("xmv.contraction.")}
+    assert contractions == {f"xmv.contraction.{want}": len(drv.blocks())}
+    packs = list(drv._pack_cache._packs.values())
+    assert packs and all((p["values_w"] is None) == (mode == "auto")
+                         for p in packs)
+    for i, j in [(0, 0), (0, 3), (2, 4)]:
+        ref = mgk_direct(ds.graphs[i], ds.graphs[j], VK, EK)
+        assert K[i, j] == pytest.approx(ref, rel=1e-4)
 
 
 def test_pack_cache_lru_eviction_roundtrip():
@@ -329,14 +357,16 @@ def test_gram_driver_kron_precond_matches_jacobi():
     assert entry["values_adj"].dtype == jnp.bfloat16
 
 
-def test_gram_driver_kron_grad_matches_jacobi():
+@pytest.mark.parametrize("mode", ["auto", "mxu"])
+def test_gram_driver_kron_grad_matches_jacobi(mode):
     """run_with_grad under precond='kron' (adjoint reuses the cached
     factors via precond_factors/trust_pack_weights) matches Jacobi's
-    gradient Gram blocks."""
+    gradient Gram blocks, in the contraction "auto" picks and in the
+    MXU one."""
     ds = _dataset(5)
     mesh = _mesh()
     base = dict(ds=ds, mesh=mesh, vertex_kernel=VK, edge_kernel=EK,
-                method="pallas_sparse", tol=1e-10)
+                method="pallas_sparse", tol=1e-10, sparse_mode=mode)
     Kj, Gj = GramDriver(**base).run_with_grad()
     Kk, Gk = GramDriver(**base, precond="kron",
                         gram_tile=True,

@@ -83,13 +83,16 @@ def test_grad_blocks_match_finite_differences(setup, gram_and_grads):
         np.testing.assert_allclose(G[key], fd, rtol=2e-3, atol=2e-5)
 
 
-def test_sparse_grad_blocks_match_dense(setup, gram_and_grads):
-    """The pack-cached sparse gradient path (values_w/values_grad baked
-    once per graph, trust_pack_weights) must reproduce the dense-path
-    gradient Gram."""
+@pytest.mark.parametrize("mode", ["auto", "mxu"])
+def test_sparse_grad_blocks_match_dense(setup, gram_and_grads, mode):
+    """The pack-cached sparse gradient path must reproduce the
+    dense-path gradient Gram: in the elementwise contraction "auto"
+    picks (traced theta through the in-kernel vector) and in the MXU one
+    (values_w/values_grad baked once per graph, trust_pack_weights)."""
     _, ds, mesh = setup
     K, G = gram_and_grads
-    Ks, Gs = _driver(ds, mesh, method="pallas_sparse").run_with_grad()
+    Ks, Gs = _driver(ds, mesh, method="pallas_sparse",
+                     sparse_mode=mode).run_with_grad()
     np.testing.assert_allclose(Ks, K, rtol=2e-3, atol=1e-7)
     for key in G:
         np.testing.assert_allclose(Gs[key], G[key], rtol=5e-3, atol=2e-5)

@@ -19,20 +19,24 @@ from repro.distributed.faults import FaultInjector, FaultPlan
 VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=10)
 
+# "gram-tile" names the MXU contraction, "gram-tile-vpu" runs what
+# "auto" picks: the elementwise one
 CASES = {
     "lowrank": dict(method="lowrank", pairs_per_block=3),
     "gram-tile": dict(method="pallas_sparse", gram_tile=True,
-                      tile_shape=(2, 2)),
+                      tile_shape=(2, 2), sparse_mode="mxu"),
+    "gram-tile-vpu": dict(method="pallas_sparse", gram_tile=True,
+                          tile_shape=(2, 2)),
 }
 # spans every block loop opens; the sparse path adds its pack stages
 COMMON = {"mgk.build", "mgk.block", "mgk.batch", "mgk.dispatch",
           "mgk.readback", "mgk.save", "mgk.load", "mgk.assemble"}
 SPANS = {"lowrank": COMMON,
-         "gram-tile": COMMON | {"mgk.label_check", "mgk.stack",
-                                "mgk.pack"}}
+         "gram-tile": COMMON | {"mgk.stack", "mgk.pack"},
+         "gram-tile-vpu": COMMON | {"mgk.stack", "mgk.pack"}}
 # spans of one block's work, each inside that block's mgk.block
 IN_BLOCK = {"mgk.batch", "mgk.dispatch", "mgk.readback", "mgk.save",
-            "mgk.label_check", "mgk.stack", "mgk.pack", "mgk.retry"}
+            "mgk.stack", "mgk.pack", "mgk.retry"}
 
 
 def _dataset():
@@ -158,8 +162,10 @@ def test_lowrank_counts_batches_and_one_read_per_block(builds):
     assert "pack_cache.miss" not in b["spent"]
 
 
-def test_gram_tile_counts_packs_slices_and_label_checks(builds):
-    b = builds["gram-tile"]
+def _check_gram_tile_counts(b, edge_kernel):
+    """Pack-cache lookups, blocking reads and bytes sent of a Gram-tile
+    build, counted from its blocks; ``edge_kernel`` is the pack cache's
+    (None: no weighted operands). Returns the number of blocks."""
     ds, blocks = b["ds"], b["drv"].blocks()
     axes = [(np.unique(blk.rows), np.unique(blk.cols), blk.pad_row)
             for blk in blocks]
@@ -167,11 +173,11 @@ def test_gram_tile_counts_packs_slices_and_label_checks(builds):
     lookups = sum(len(r) + len(c) for r, c, _ in axes)
     assert b["spent"]["pack_cache.miss"] == len(graphs)
     assert b["spent"]["pack_cache.hit"] == lookups - len(graphs)
-    # two slices per graph of an axis, two label arrays, one readback
+    # two slices per graph of an axis, one readback
     assert b["spent"]["host_syncs"] == sum(
-        2 * (len(r) + len(c)) + 3 for r, c, _ in axes)
+        2 * (len(r) + len(c)) + 1 for r, c, _ in axes)
     # both pair batches of each block, then each axis's stacked pack
-    cache = GraphPackCache(tile=8, edge_kernel=EK)
+    cache = GraphPackCache(tile=8, edge_kernel=edge_kernel)
     packs = 0
     for (r, c, pad), blk in zip(axes, blocks):
         for idx in (r, c):
@@ -180,6 +186,25 @@ def test_gram_tile_counts_packs_slices_and_label_checks(builds):
     assert b["spent"]["h2d_bytes"] == packs + sum(
         _batch_bytes(ds, blk.rows, blk.pad_row)
         + _batch_bytes(ds, blk.cols, blk.pad_col) for blk in blocks)
+    return len(blocks)
+
+
+def test_gram_tile_counts_packs_slices_and_label_checks(builds):
+    """The MXU step: weighted packs, and no label check (the step runs
+    the contraction it is given), so no label reads."""
+    b = builds["gram-tile"]
+    n_blocks = _check_gram_tile_counts(b, EK)
+    assert b["spent"]["xmv.contraction.mxu"] == n_blocks
+    assert "xmv.contraction.elementwise" not in b["spent"]
+
+
+def test_gram_tile_elementwise_counts_packs_and_slices(builds):
+    """The step "auto" runs: unweighted packs, the elementwise
+    contraction on every block."""
+    b = builds["gram-tile-vpu"]
+    n_blocks = _check_gram_tile_counts(b, None)
+    assert b["spent"]["xmv.contraction.elementwise"] == n_blocks
+    assert "xmv.contraction.mxu" not in b["spent"]
 
 
 def test_a_retried_block_opens_a_retry_span(tmp_path):

@@ -18,8 +18,8 @@ from repro.core.xmv import xmv_full
 from repro.data import make_drugbank_like_dataset
 from repro.kernels.ops import row_panel_packs_for_batch, \
     stack_row_panel_packs
-from repro.kernels.xmv_block_sparse import from_tiles, \
-    pack_graph_row_panels, to_tiles
+from repro.kernels.xmv_block_sparse import RowPanelPack, _resolve_mode, \
+    from_tiles, pack_graph_row_panels, to_tiles
 from repro.kernels.xmv_block_sparse import xmv_row_panel as _xmv_row_panel
 from repro.kernels.xmv_block_sparse import \
     xmv_row_panel_batched as _xmv_row_panel_batched
@@ -127,6 +127,33 @@ def test_row_panel_elementwise_only_kernel(rng):
     np.testing.assert_allclose(np.asarray(y), ref, **TOL)
     with pytest.raises(ValueError, match="mxu"):
         xmv_row_panel(p1, p2, jnp.asarray(P), ck, mode="mxu")
+
+
+# the octile edges and feature ranks of the chip's crossover
+# (benchmarks/contraction_sweep.py, one TPU v5e; DESIGN.md §3.4), and
+# packs without weighted tiles (rank None)
+@pytest.mark.parametrize("tile,rank", [
+    (8, 4), (8, 9), (8, 12), (8, 24), (16, 4), (16, 9), (16, 12),
+    (16, 24), (32, 4), (32, 9), (32, 12), (32, 24), (8, None),
+    (32, None),
+])
+def test_auto_runs_elementwise_across_the_chip_table(tile, rank):
+    """The kernels' "auto" runs the elementwise body at every measured
+    edge and rank, weighted packs or not; "mxu" runs the MXU body on
+    weighted packs and refuses packs without them."""
+    z = np.zeros((1, 1, tile, tile), np.float32)
+    pack = RowPanelPack(
+        values_adj=z, values_lab=z,
+        values_w=None if rank is None
+        else np.zeros((1, 1, rank, tile, tile), np.float32),
+        col=np.zeros((1, 1), np.int32), count=np.zeros(1, np.int32))
+    assert _resolve_mode("auto", pack, pack) is False
+    assert _resolve_mode("elementwise", pack, pack) is False
+    if rank is None:
+        with pytest.raises(ValueError, match="mode='mxu'"):
+            _resolve_mode("mxu", pack, pack)
+    else:
+        assert _resolve_mode("mxu", pack, pack) is True
 
 
 @pytest.fixture(scope="module")

@@ -104,6 +104,13 @@ class GraphPackCache:
     Gram-tile axis (mirroring :meth:`stacked_axis`). A few O(n²) host
     arrays per graph; evicted and rebuilt with the packs.
 
+    One cache serves several octile edges: packs and their stats are
+    keyed by (dataset index, pad, t), ``tile`` is the edge a call uses
+    when it names none. Gram-tile steps pick t per block (DESIGN.md
+    §3.4) and record in ``edges`` the edge each bucket pair's blocks ran
+    at, which :meth:`GramDriver._block_densities` reads the stats at.
+    The Kronecker factors do not depend on t.
+
     Lookups count as ``pack_cache.hit`` / ``pack_cache.miss`` in the
     program's counters (:mod:`repro.obs`); a miss builds under the span
     ``mgk.pack``, stacking and the transfer run under ``mgk.stack``.
@@ -121,7 +128,8 @@ class GraphPackCache:
         self._packs: "collections.OrderedDict" = collections.OrderedDict()
         self._factors: "collections.OrderedDict" = \
             collections.OrderedDict()
-        self.stats: dict = {}        # (idx, pad) -> octile/nnz/density
+        self.stats: dict = {}        # (idx, pad, t) -> octile/nnz/density
+        self.edges: dict = {}        # (pad_row, pad_col) -> t last run
 
     def _lru_get(self, store, key, build) -> dict:
         """Shared LRU lookup for the pack and factor stores: counts
@@ -140,8 +148,8 @@ class GraphPackCache:
         store[key] = entry
         return entry
 
-    def _pack(self, idx, adjacency, labels, pad_to) -> dict:
-        key = (int(idx), int(pad_to))
+    def _pack(self, idx, adjacency, labels, pad_to, tile) -> dict:
+        key = (int(idx), int(pad_to), int(tile))
         return self._lru_get(self._packs, key,
                              lambda: self._build_pack(key, adjacency,
                                                       labels))
@@ -149,7 +157,7 @@ class GraphPackCache:
     def _build_pack(self, key, adjacency, labels) -> dict:
         from repro.core.octile import octile_decompose
         from repro.kernels.xmv_block_sparse import pack_row_panels
-        oset = octile_decompose(adjacency, labels, tile=self.tile)
+        oset = octile_decompose(adjacency, labels, tile=key[2])
         nt = oset.n_tiles_side
         self.stats[key] = {
             "octiles": int(oset.n_nonempty),
@@ -201,10 +209,12 @@ class GraphPackCache:
                 name: obs.to_device(np.stack([e[name] for e in entries]))
                 for name in KronFactors._fields})
 
-    def density(self, idx: int, pad_to: int) -> float | None:
+    def density(self, idx: int, pad_to: int,
+                tile: int | None = None) -> float | None:
         """Measured octile occupancy of graph ``idx`` at bucket pad
-        ``pad_to`` — None until the graph has been packed once."""
-        s = self.stats.get((int(idx), int(pad_to)))
+        ``pad_to`` and octile edge ``tile`` (default: the cache's) —
+        None until the graph has been packed once at that edge."""
+        s = self.stats.get((int(idx), int(pad_to), int(tile or self.tile)))
         return None if s is None else s["density"]
 
     @staticmethod
@@ -216,19 +226,22 @@ class GraphPackCache:
         pad[1] = (0, k_max - k)
         return np.pad(arr, pad)
 
-    def stacked(self, indices, batch: GraphBatch):
-        """Build the stacked RowPanelPack for one (padded) pair batch.
+    def _host_stacked(self, indices, batch: GraphBatch,
+                     tile: int | None = None):
+        """The stacked RowPanelPack for one (padded) pair batch at octile
+        edge ``tile`` (default: the cache's), as host arrays.
 
         ``indices[b]`` is the dataset index of ``batch`` entry b; entries
         beyond ``len(indices)`` are data-parallel dummy pairs (cached
         under index -1 — their adjacency is all zero)."""
         from repro.kernels.xmv_block_sparse import RowPanelPack
+        t = tile or self.tile
         B = batch.adjacency.shape[0]
         pad_to = batch.adjacency.shape[1]
-        if pad_to % self.tile:
+        if pad_to % t:
             raise ValueError(
                 f"bucket padded to {pad_to}, not a multiple of"
-                f" tile={self.tile}; pad buckets to a multiple of the"
+                f" tile={t}; pad buckets to a multiple of the"
                 f" tile edge (loader multiple_of)")
         with obs.span("mgk.stack"):
             entries = []
@@ -236,36 +249,50 @@ class GraphPackCache:
                 idx = int(indices[b]) if b < len(indices) else -1
                 entries.append(self._pack(
                     idx, obs.to_host(batch.adjacency[b]),
-                    obs.to_host(batch.edge_labels[b]), pad_to))
+                    obs.to_host(batch.edge_labels[b]), pad_to, t))
             k_max = max(e["col"].shape[1] for e in entries)
 
             def stack(field):
                 if entries[0][field] is None:
                     return None
                 if field == "count":
-                    return obs.to_device(
-                        np.stack([e[field] for e in entries]))
-                return obs.to_device(np.stack(
-                    [self._pad_k(e[field], k_max) for e in entries]))
+                    return np.stack([e[field] for e in entries])
+                return np.stack(
+                    [self._pad_k(e[field], k_max) for e in entries])
 
             return RowPanelPack(**{f: stack(f)
                                    for f in RowPanelPack._fields})
 
-    def stacked_axis(self, indices, batch: GraphBatch):
+    @staticmethod
+    def send(pack):
+        """A host-stacked pack on the device, field by field."""
+        with obs.span("mgk.stack"):
+            return type(pack)(*(None if x is None else obs.to_device(x)
+                                for x in pack))
+
+    def stacked(self, indices, batch: GraphBatch, tile: int | None = None):
+        """The stacked RowPanelPack for one (padded) pair batch at
+        octile edge ``tile``, on the device (:meth:`_host_stacked`)."""
+        return self.send(self._host_stacked(indices, batch, tile))
+
+    def stacked_axis(self, indices, batch: GraphBatch,
+                     tile: int | None = None):
         """PER-AXIS pack for Gram-tile execution (DESIGN.md §8): one
         stacked RowPanelPack over the given UNIQUE graphs — the Bi row
-        (or Bj column) axis of an I x J Gram tile. Compared to building
-        :meth:`stacked` per-pair packs for the tile's flattened pair
-        batch, this skips the Bj-fold (resp. Bi-fold) re-stacking and
-        device-transfer duplication entirely: each graph's panels are
-        padded and shipped once per tile, and the Gram-tile kernel
-        reuses them across every partner."""
+        (or Bj column) axis of an I x J Gram tile — as host arrays, so
+        the step can size the kernel's VMEM envelope before
+        :meth:`send` ships it. Compared to building :meth:`stacked`
+        per-pair packs for the tile's flattened pair batch, this skips
+        the Bj-fold (resp. Bi-fold) re-stacking and device-transfer
+        duplication entirely: each graph's panels are padded and
+        shipped once per tile, and the Gram-tile kernel reuses them
+        across every partner."""
         if batch.adjacency.shape[0] != len(indices):
             raise ValueError(
                 f"axis batch size {batch.adjacency.shape[0]} != "
                 f"{len(indices)} axis indices (per-axis packs take the"
                 f" UNIQUE graphs, not the flattened pair batch)")
-        return self.stacked(indices, batch)
+        return self._host_stacked(indices, batch, tile)
 
 
 def pair_shardings(mesh: Mesh) -> tuple:
@@ -312,6 +339,14 @@ def pair_shardings(mesh: Mesh) -> tuple:
 # budget minus headroom for Mosaic's own buffers)
 _GRAM_TILE_VMEM_BUDGET = 12 << 20
 
+# octile edges a Gram-tile block may pack at, largest first: the
+# elementwise body pays a fixed cost per slot pair, and a larger edge
+# runs fewer, fuller slot pairs (DESIGN.md §3.4)
+_GRAM_TILE_EDGES = (32, 16, 8)
+# the octile edge of every other sparse route (per-pair blocks, and
+# Gram-tile blocks that fit VMEM at no edge)
+_DEFAULT_TILE = 8
+
 
 def _axis_structure(rows, cols):
     """(unique_rows, unique_cols) if (rows, cols) is the row-major
@@ -336,13 +371,40 @@ def _axis_structure(rows, cols):
     return urows, ucols
 
 
+def _gram_tile_packs(cache: GraphPackCache, tile: int | None, mxu: bool,
+                     row_axis: tuple, col_axis: tuple) -> tuple | None:
+    """Both axes' packs of a Gram-tile block, on the device, at the
+    octile edge the block runs at; None where no edge fits VMEM (the
+    block then takes the per-pair route at ``cache.tile``).
+
+    ``tile=None`` tries ``_GRAM_TILE_EDGES`` largest first, an integer
+    only itself. An edge is taken where it divides both pads and the
+    kernel's envelope (``gram_tile_vmem_bytes``: graph j's whole pack +
+    the lane-replicated P panel, growing as n·m·t) fits
+    ``_GRAM_TILE_VMEM_BUDGET``. The envelope is sized from the
+    host-stacked packs, so only the chosen edge's packs are sent.
+    ``row_axis`` / ``col_axis`` are (unique dataset indices, their
+    batch)."""
+    from repro.kernels.xmv_block_sparse import gram_tile_vmem_bytes
+    (urows, g1u), (ucols, g2u) = row_axis, col_axis
+    n, m = g1u.adjacency.shape[1], g2u.adjacency.shape[1]
+    for t in _GRAM_TILE_EDGES if tile is None else (tile,):
+        if n % t or m % t:
+            continue
+        p1 = cache.stacked_axis(urows, g1u, t)
+        p2 = cache.stacked_axis(ucols, g2u, t)
+        if gram_tile_vmem_bytes(p1, p2, mxu) <= _GRAM_TILE_VMEM_BUDGET:
+            return cache.send(p1), cache.send(p2)
+    return None
+
+
 def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                    edge_kernel: BaseKernel, *, method: str = "lowrank",
                    tol: float = 1e-8, max_iter: int = 256,
                    fixed_iters: int | None = None,
                    pcg_variant: str = "classic",
                    sparse_mode: str = "auto",
-                   tile: int = 8,
+                   tile: int | None = None,
                    gram_tile: bool = False,
                    segment_size: int | None = None,
                    segment_pad: int = 1,
@@ -403,19 +465,26 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
     "mxu" (the low-rank contraction; needs a feature-expandable edge
     kernel) and "elementwise" are honoured as given. Only an MXU step
     builds, stacks and sends the weighted operands. Each block solve
-    counts ``xmv.contraction.mxu`` or ``xmv.contraction.elementwise``
+    counts ``xmv.contraction.mxu`` or ``xmv.contraction.elementwise``,
+    and ``xmv.octile_edge.<t>`` by the octile edge it ran at
     (:mod:`repro.obs`).
-    ``tile`` sets the octile edge (buckets must pad to a multiple).
-    The step accepts optional ``rows``/``cols`` dataset indices (the
-    driver passes them; without them the packs are built uncached).
+    ``tile`` sets the octile edge (buckets must pad to a multiple);
+    ``None`` picks it: Gram-tile blocks as below, every other route at
+    t = 8. The step accepts optional ``rows``/``cols`` dataset indices
+    (the driver passes them; without them the packs are built uncached).
 
     ``gram_tile=True`` (sparse only): blocks whose (rows, cols) form a
     rectangle (``data.gram_tile_blocks``) solve in GRAM-TILE execution
     (DESIGN.md §8) — ONE row-panel pack per axis from
     :meth:`GraphPackCache.stacked_axis` (no per-pair restacking) and one
     ``xmv_gram_tile`` launch per matvec, reusing each row graph's
-    panels across all its column partners. Non-rectangular blocks fall
-    back to the per-pair path transparently.
+    panels across all its column partners. Under ``tile=None`` such a
+    block packs at the largest t in (32, 16, 8) that divides both
+    buckets' pads and whose kernel envelope (``gram_tile_vmem_bytes``)
+    fits the VMEM budget: t depends only on the pads and the packs'
+    k_max (DESIGN.md §3.4). A block that fits at no edge, and every
+    non-rectangular block, falls back to the per-pair path at t = 8
+    (at ``tile`` where it is given) transparently.
 
     ``segment_size`` (sparse, forward only): solve with
     convergence-segmented PCG — converged pairs RETIRE between segments
@@ -448,11 +517,21 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
         mode = "mxu" if sparse_mode == "mxu" else "elementwise"
         # only an MXU step builds, stacks and sends the weighted operands
         cache = GraphPackCache(
-            tile=tile, edge_kernel=edge_kernel if mode == "mxu" else None,
+            tile=_DEFAULT_TILE if tile is None else tile,
+            edge_kernel=edge_kernel if mode == "mxu" else None,
             max_entries=pack_cache_entries, with_grad=with_grad,
             pack_dtype=pack_dtype)
 
         kron = precond == "kron"
+
+        def _note_block(g1, g2, packs):
+            """Once per block solve: count the contraction and the octile
+            edge it runs, and keep the edge for the scheduler's reads of
+            the pack stats (``GramDriver._block_densities``)."""
+            obs.count(f"xmv.contraction.{mode}")
+            obs.count(f"xmv.octile_edge.{packs.tile}")
+            cache.edges[(g1.adjacency.shape[1], g2.adjacency.shape[1])] = \
+                packs.tile
 
         def _block_packs(g1, g2, rows, cols):
             """(packs1, packs2, gram_tile_shape, factors) for one
@@ -466,8 +545,6 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 if gram_tile and rows is not None and cols is not None \
                 else None
             if axes is not None:
-                from repro.kernels.xmv_block_sparse import \
-                    gram_tile_vmem_bytes
                 urows, ucols = axes
                 Bi, Bj = len(urows), len(ucols)
                 # the flattened pair batch is urows x ucols row-major:
@@ -475,22 +552,18 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 # column graphs are the first Bj entries
                 g1u = jax.tree.map(lambda x: x[::Bj], g1)
                 g2u = jax.tree.map(lambda x: x[:Bj], g2)
-                p1 = cache.stacked_axis(urows, g1u)
-                p2 = cache.stacked_axis(ucols, g2u)
-                # route buckets whose per-step envelope (graph j's whole
-                # pack + the P panel) would crowd VMEM back to the
-                # per-pair kernel, whose P BlockSpec streams instead
-                if gram_tile_vmem_bytes(p1, p2, mode == "mxu") \
-                        <= _GRAM_TILE_VMEM_BUDGET:
+                packs = _gram_tile_packs(cache, tile, mode == "mxu",
+                                         (urows, g1u), (ucols, g2u))
+                if packs is not None:
                     facs = (cache.stacked_factors(urows, g1u),
                             cache.stacked_factors(ucols, g2u)) \
                         if kron else (None, None)
-                    return p1, p2, (Bi, Bj), facs
+                    return (*packs, (Bi, Bj), facs)
             if rows is None or cols is None:
-                p1 = row_panel_packs_for_batch(g1, tile=tile,
+                p1 = row_panel_packs_for_batch(g1, tile=cache.tile,
                                                edge_kernel=cache.edge_kernel,
                                                with_grad=with_grad)
-                p2 = row_panel_packs_for_batch(g2, tile=tile,
+                p2 = row_panel_packs_for_batch(g2, tile=cache.tile,
                                                edge_kernel=cache.edge_kernel,
                                                with_grad=with_grad)
                 facs = (None, None)   # uncached: factors derived in-trace
@@ -509,7 +582,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
 
             def grad_sparse_step(g1, g2, rows=None, cols=None):
                 p1, p2, gt, facs = _block_packs(g1, g2, rows, cols)
-                obs.count(f"xmv.contraction.{mode}")
+                _note_block(g1, g2, p1)
                 fn = mgk_value_fn(g1, g2, vertex_kernel, edge_kernel,
                                   method="sparse", packs1=p1, packs2=p2,
                                   sparse_mode=mode,
@@ -540,7 +613,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                         rows=None, cols=None, fault=None,
                         spd_margin=None) -> MGKResult:
             args, kw = _solve_args(g1, g2, rows, cols)
-            obs.count(f"xmv.contraction.{mode}")
+            _note_block(*args[:3])          # g1, g2 and the row packs
             if segment_size is not None:
                 res = mgk_pairs_sparse_segmented(
                     *args, tol=tol, max_iter=max_iter,
@@ -750,7 +823,7 @@ class GramDriver:
     fixed_iters: int | None = None
     pcg_variant: str = "classic"
     sparse_mode: str = "auto"     # pallas_sparse: "auto" | "mxu" | ...
-    tile: int = 8                 # octile edge for the sparse path
+    tile: int | None = None       # octile edge; None picks (§3.4)
     pairs_per_block: int = 64
     gram_tile: bool = False       # Gram-tile execution (sparse only)
     tile_shape: tuple[int, int] = (8, 8)   # unique graphs per tile axis
@@ -822,17 +895,19 @@ class GramDriver:
 
     def _block_densities(self, blocks) -> dict[int, float] | None:
         """Measured per-block octile occupancy from the pack cache's
-        stats (scheduler satellite): the product system touches
-        d_row * d_col of the tile products, and estimate_cost squares
-        its density knob, so the block estimate is sqrt(d_r * d_c)."""
+        stats (scheduler satellite), at the octile edge the block's
+        bucket pair ran at: the product system touches d_row * d_col of
+        the tile products, and estimate_cost squares its density knob,
+        so the block estimate is sqrt(d_r * d_c)."""
         cache = self._pack_cache
         if cache is None or not cache.stats:
             return None
         out = {}
         for b in blocks:
-            dr = [cache.density(int(i), b.pad_row)
+            t = cache.edges.get((b.pad_row, b.pad_col), cache.tile)
+            dr = [cache.density(int(i), b.pad_row, t)
                   for i in set(b.rows.tolist())]
-            dc = [cache.density(int(i), b.pad_col)
+            dc = [cache.density(int(i), b.pad_col, t)
                   for i in set(b.cols.tolist())]
             dr = [d for d in dr if d is not None]
             dc = [d for d in dc if d is not None]

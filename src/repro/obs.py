@@ -17,10 +17,11 @@ taking a snapshot before and after the work of interest::
 ``host_syncs`` its blocking device-to-host reads, ``matvec_pairs`` the
 pair-matvecs its PCG solves ran (lockstep pairs included),
 ``pack_cache.hit`` / ``pack_cache.miss`` the lookups of the Gram
-driver's pack cache, and ``xmv.contraction.mxu`` /
+driver's pack cache, ``xmv.contraction.mxu`` /
 ``xmv.contraction.elementwise`` the sparse step's block solves by the
-contraction they ran. :func:`to_device` and :func:`to_host` move an
-array and count it.
+contraction they ran, and ``xmv.octile_edge.<t>`` the same solves by
+the octile edge t they ran at. :func:`to_device` and :func:`to_host`
+move an array and count it.
 """
 from __future__ import annotations
 

@@ -218,7 +218,7 @@ def test_pack_cache_lru_eviction_roundtrip():
     for b in (1, 2, 3):          # push graph 0 out of the LRU window
         cache.stacked(np.array([b]), one(b))
     assert len(cache._packs) == 2
-    assert (0, 32) not in cache._packs          # evicted...
+    assert (0, 32, 8) not in cache._packs       # evicted...
     assert cache.density(0, 32) is not None     # ...stats persist
     before = obs.counters()
     again = cache.stacked(np.array([0]), one(0))
@@ -267,6 +267,104 @@ def test_gram_tile_driver_matches_per_pair_driver():
         gt = GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
                         gram_tile=True, tile_shape=(3, 3), **kw).run()
         np.testing.assert_allclose(gt, ref, rtol=1e-4, atol=1e-6)
+
+
+def _nws_axis(n_nodes, indices):
+    """(indices, their batch) of NWS graphs with ``n_nodes`` nodes: one
+    axis of a Gram-tile block."""
+    from repro.core.graph import batch_from_graphs
+    from repro.data import make_synthetic_dataset
+    gs = make_synthetic_dataset("nws", n_graphs=len(indices),
+                                n_nodes=n_nodes, seed=n_nodes)
+    return np.asarray(indices), batch_from_graphs(gs, pad_to=n_nodes)
+
+
+@pytest.mark.parametrize("pads,tile,want", [
+    ((96, 96), None, 32),
+    ((48, 48), None, 16),
+    ((24, 24), None, 8),
+    ((64, 232), None, 8),     # 8 is the largest edge dividing both
+    ((256, 256), None, 16),   # the t = 32 envelope is over budget
+    ((512, 512), None, None),  # no edge fits: the per-pair route at 8
+    ((96, 96), 8, 8),
+    ((96, 96), 16, 16),
+])
+def test_gram_tile_octile_edge(pads, tile, want):
+    """The edge a Gram-tile block packs at: under ``tile=None`` the
+    largest of (32, 16, 8) that divides both pads and whose kernel
+    envelope fits the VMEM budget; an explicit ``tile`` as given.
+    Packing only, no kernel runs."""
+    from repro.distributed.gram import GraphPackCache, _gram_tile_packs, \
+        _GRAM_TILE_VMEM_BUDGET
+    from repro.kernels.xmv_block_sparse import gram_tile_vmem_bytes
+    cache = GraphPackCache(tile=8 if tile is None else tile)
+    rows, cols = _nws_axis(pads[0], [0, 1]), _nws_axis(pads[1], [2, 3])
+    packs = _gram_tile_packs(cache, tile, False, rows, cols)
+    if want is None:
+        assert packs is None and cache.tile == 8
+        return
+    p1, p2 = packs
+    assert (p1.tile, p2.tile) == (want, want)
+    assert (p1.n_tile_rows * want, p2.n_tile_rows * want) == pads
+    assert gram_tile_vmem_bytes(p1, p2, False) <= _GRAM_TILE_VMEM_BUDGET
+    # the stats of every edge tried are kept under that edge
+    for t in (32, 16, 8):
+        tried = (tile is None and t >= want and not pads[0] % t
+                 and not pads[1] % t) or t == tile
+        assert (cache.density(0, pads[0], t) is not None) == tried
+
+
+@pytest.fixture(scope="module")
+def nws32():
+    """Four 32-node NWS graphs, their Gram at the picked edge (2 x 2
+    Gram tiles) and the normalised direct-solve reference."""
+    from repro.core.reference import mgk_direct
+    from repro.data import make_synthetic_dataset
+    ds = bucket_graphs(make_synthetic_dataset("nws", n_graphs=4,
+                                              n_nodes=32, seed=3),
+                       max_buckets=1)
+    n = len(ds)
+    ref = np.array([[mgk_direct(ds.graphs[i], ds.graphs[j], VK, EK)
+                     for j in range(n)] for i in range(n)])
+    d = np.sqrt(np.diag(ref))
+    drv = GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
+                     gram_tile=True, tile_shape=(2, 2), tol=1e-10)
+    return ds, drv.run(), ref / d[:, None] / d[None, :], drv
+
+
+def test_picked_edge_gram_matches_direct(nws32):
+    """The step picks t = 32 on the 32-node bucket, and its normalised
+    Gram matches the direct solve."""
+    _, K, ref, drv = nws32
+    assert drv.health["counters"]["xmv.octile_edge.32"] == \
+        len(drv.blocks())
+    np.testing.assert_allclose(K, ref, rtol=1e-4)
+
+
+@pytest.mark.parametrize("tile", [8, 16, 32])
+def test_gram_is_the_same_at_every_octile_edge(nws32, tile):
+    """A pinned edge gives the picked edge's normalised Gram: t changes
+    only the summation order."""
+    ds, K, _, _ = nws32
+    Kt = GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
+                    gram_tile=True, tile_shape=(2, 2), tol=1e-10,
+                    tile=tile).run()
+    np.testing.assert_allclose(Kt, K, rtol=0, atol=1e-6)
+
+
+def test_gradient_gram_at_the_picked_edge(nws32):
+    """run_with_grad at the picked edge (t = 32) matches the gradient
+    Gram pinned at t = 8."""
+    ds = nws32[0]
+    base = dict(method="pallas_sparse", gram_tile=True,
+                tile_shape=(2, 2), tol=1e-10)
+    K, G = GramDriver(ds, _mesh(), VK, EK, **base).run_with_grad()
+    K8, G8 = GramDriver(ds, _mesh(), VK, EK, tile=8,
+                        **base).run_with_grad()
+    np.testing.assert_allclose(K, K8, rtol=0, atol=1e-6)
+    assert sorted(G) == sorted(G8)
+    for key in G8:
+        np.testing.assert_allclose(G[key], G8[key], rtol=1e-4, atol=1e-6)
 
 
 def test_gram_tile_blocks_cover_all_pairs():

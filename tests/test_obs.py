@@ -20,20 +20,25 @@ VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=10)
 
 # "gram-tile" names the MXU contraction, "gram-tile-vpu" runs what
-# "auto" picks: the elementwise one
+# "auto" picks: the elementwise one; both pin the octile edge at 8.
+# "gram-tile-edge" leaves the edge to the step: t = 16 on the 16-node
+# bucket
 CASES = {
     "lowrank": dict(method="lowrank", pairs_per_block=3),
     "gram-tile": dict(method="pallas_sparse", gram_tile=True,
-                      tile_shape=(2, 2), sparse_mode="mxu"),
+                      tile_shape=(2, 2), sparse_mode="mxu", tile=8),
     "gram-tile-vpu": dict(method="pallas_sparse", gram_tile=True,
-                          tile_shape=(2, 2)),
+                          tile_shape=(2, 2), tile=8),
+    "gram-tile-edge": dict(method="pallas_sparse", gram_tile=True,
+                           tile_shape=(2, 2)),
 }
 # spans every block loop opens; the sparse path adds its pack stages
 COMMON = {"mgk.build", "mgk.block", "mgk.batch", "mgk.dispatch",
           "mgk.readback", "mgk.save", "mgk.load", "mgk.assemble"}
 SPANS = {"lowrank": COMMON,
          "gram-tile": COMMON | {"mgk.stack", "mgk.pack"},
-         "gram-tile-vpu": COMMON | {"mgk.stack", "mgk.pack"}}
+         "gram-tile-vpu": COMMON | {"mgk.stack", "mgk.pack"},
+         "gram-tile-edge": COMMON | {"mgk.stack", "mgk.pack"}}
 # spans of one block's work, each inside that block's mgk.block
 IN_BLOCK = {"mgk.batch", "mgk.dispatch", "mgk.readback", "mgk.save",
             "mgk.stack", "mgk.pack", "mgk.retry"}
@@ -162,10 +167,11 @@ def test_lowrank_counts_batches_and_one_read_per_block(builds):
     assert "pack_cache.miss" not in b["spent"]
 
 
-def _check_gram_tile_counts(b, edge_kernel):
+def _check_gram_tile_counts(b, edge_kernel, tile=8):
     """Pack-cache lookups, blocking reads and bytes sent of a Gram-tile
     build, counted from its blocks; ``edge_kernel`` is the pack cache's
-    (None: no weighted operands). Returns the number of blocks."""
+    (None: no weighted operands), ``tile`` the octile edge every block
+    ran at. Returns the number of blocks."""
     ds, blocks = b["ds"], b["drv"].blocks()
     axes = [(np.unique(blk.rows), np.unique(blk.cols), blk.pad_row)
             for blk in blocks]
@@ -177,7 +183,7 @@ def _check_gram_tile_counts(b, edge_kernel):
     assert b["spent"]["host_syncs"] == sum(
         2 * (len(r) + len(c)) + 1 for r, c, _ in axes)
     # both pair batches of each block, then each axis's stacked pack
-    cache = GraphPackCache(tile=8, edge_kernel=edge_kernel)
+    cache = GraphPackCache(tile=tile, edge_kernel=edge_kernel)
     packs = 0
     for (r, c, pad), blk in zip(axes, blocks):
         for idx in (r, c):
@@ -205,6 +211,36 @@ def test_gram_tile_elementwise_counts_packs_and_slices(builds):
     n_blocks = _check_gram_tile_counts(b, None)
     assert b["spent"]["xmv.contraction.elementwise"] == n_blocks
     assert "xmv.contraction.mxu" not in b["spent"]
+
+
+def test_octile_edge_counts_once_per_block_solve(builds):
+    """Every block solve counts the octile edge it ran at, and the count
+    reaches ``health["counters"]``: t = 8 where the edge is pinned, the
+    step's pick (t = 16 on this 16-node bucket) where it is not, with
+    that edge's packs and bytes."""
+    for case in ("gram-tile", "gram-tile-vpu"):
+        b = builds[case]
+        edges = {k: v for k, v in b["drv"].health["counters"].items()
+                 if k.startswith("xmv.octile_edge.")}
+        assert edges == {"xmv.octile_edge.8": len(b["drv"].blocks())}
+    b = builds["gram-tile-edge"]
+    n_blocks = _check_gram_tile_counts(b, None, tile=16)
+    edges = {k: v for k, v in b["drv"].health["counters"].items()
+             if k.startswith("xmv.octile_edge.")}
+    assert edges == {"xmv.octile_edge.16": n_blocks}
+    assert b["drv"]._pack_cache.edges == {(16, 16): 16}
+
+
+def test_gradient_build_counts_its_octile_edge(tmp_path):
+    """The gradient Gram-tile build takes the same edge as the forward
+    one and counts it once per block solve."""
+    ds = _dataset()
+    drv = GramDriver(ds, _mesh(), VK, EK, store=None,
+                     **CASES["gram-tile-edge"])
+    drv.run_with_grad()
+    edges = {k: v for k, v in drv.health["counters"].items()
+             if k.startswith("xmv.octile_edge.")}
+    assert edges == {"xmv.octile_edge.16": len(drv.blocks())}
 
 
 def test_a_retried_block_opens_a_retry_span(tmp_path):

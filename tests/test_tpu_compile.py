@@ -26,7 +26,6 @@ from repro.kernels.xmv_dense import DENSE_TILE, xmv_dense_batched
 EK = SquareExponential(1.0, rank=12)
 T = 8              # octile edge
 N = 96             # the paper's synthetic graphs: 96 nodes
-K_MAX = 12         # every tile of a row occupied: the worst case
 
 
 @pytest.fixture(scope="module")
@@ -45,14 +44,15 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _packs(sharding, B, n, rank):
-    nt = n // T
+def _packs(sharding, B, n, rank, t=T):
+    nt = n // t
+    k_max = nt        # every tile of a row occupied: the worst case
     return RowPanelPack(
-        values_adj=_spec(sharding, (B, nt, K_MAX, T, T)),
-        values_lab=_spec(sharding, (B, nt, K_MAX, T, T)),
+        values_adj=_spec(sharding, (B, nt, k_max, t, t)),
+        values_lab=_spec(sharding, (B, nt, k_max, t, t)),
         values_w=None if rank is None else _spec(
-            sharding, (B, nt, K_MAX, rank, T, T)),
-        col=_spec(sharding, (B, nt, K_MAX), jnp.int32),
+            sharding, (B, nt, k_max, rank, t, t)),
+        col=_spec(sharding, (B, nt, k_max), jnp.int32),
         count=_spec(sharding, (B, nt), jnp.int32))
 
 
@@ -80,6 +80,20 @@ def test_gram_tile_compiles(one_chip, mode):
     nt = N // T
     packs = _packs(one_chip, 8, N, 12 if mode == "mxu" else None)
     P = _spec(one_chip, (8, 8, nt, nt, T, T))
+    _assert_kernel_compiles(
+        lambda p1, p2, P, d: xmv_gram_tile(
+            p1, p2, P, EK, diag=d, mode=mode, interpret=False),
+        packs, packs, P, P)
+
+
+@pytest.mark.parametrize("mode", ["mxu", "elementwise"])
+def test_gram_tile_compiles_at_the_picked_edge(one_chip, mode):
+    """The same 8 x 8 Gram tile at t = 32, the octile edge the Gram
+    driver picks for 96-node buckets (``[32, 1024]`` accumulators)."""
+    t = 32
+    nt = N // t
+    packs = _packs(one_chip, 8, N, 12 if mode == "mxu" else None, t)
+    P = _spec(one_chip, (8, 8, nt, nt, t, t))
     _assert_kernel_compiles(
         lambda p1, p2, P, d: xmv_gram_tile(
             p1, p2, P, EK, diag=d, mode=mode, interpret=False),

@@ -11,8 +11,8 @@ import jax.numpy as jnp
 
 from repro.core.base_kernels import SquareExponential
 from repro.core.octile import octile_decompose
-from repro.kernels.xmv_block_sparse import pack_graph, xmv_block_sparse
-from repro.kernels.xmv_block_sparse import to_tiles
+from repro.kernels.xmv_block_sparse import pack_graph_row_panels, \
+    to_tiles, xmv_row_panel
 from repro.kernels.xmv_dense import DENSE_TILE, xmv_dense
 from .common import row, time_fn
 
@@ -44,9 +44,9 @@ def run(n: int = 64, occupancies=(2, 8, 16, 32, 56)) -> list[str]:
         Aj, Ej = jnp.asarray(A), jnp.asarray(E)
         us_d = time_fn(lambda a, e, p: xmv_dense(a, e, a, e, p, EK),
                        Aj, Ej, to_tiles(P, DENSE_TILE), iters=3)
-        p1 = pack_graph(A, E)
-        us_s = time_fn(lambda pk, p: xmv_block_sparse(pk, pk, p, EK),
-                       p1, P, iters=3)
+        p1 = pack_graph_row_panels(A, E)
+        us_s = time_fn(lambda pk, p: xmv_row_panel(pk, pk, p, EK),
+                       p1, to_tiles(P, p1.tile), iters=3)
         oset = octile_decompose(A, E)
         frac = oset.n_nonempty / max((n // 8) ** 2, 1)
         work_ratio = frac ** 2      # tile-pair products touched

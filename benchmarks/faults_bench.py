@@ -80,12 +80,11 @@ def _guard_overhead(report: dict, B: int, seed: int, fixed_iters: int,
     pad += (-pad) % 8
     g1 = batch_from_graphs(gs[:B], pad_to=pad)
     g2 = batch_from_graphs(gs[B:2 * B], pad_to=pad)
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
+    p1 = row_panel_packs_for_batch(g1)
+    p2 = row_panel_packs_for_batch(g2)
 
     def solve(guard):
         return mgk_pairs_sparse(g1, g2, p1, p2, VK, EK,
-                                sparse_mode="mxu",
                                 fixed_iters=fixed_iters,
                                 guard=guard)
 

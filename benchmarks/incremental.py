@@ -16,7 +16,7 @@ from repro.core import KroneckerDelta, SquareExponential, \
     batch_from_graphs, mgk_pairs, pbr_order
 from repro.core.mgk import mgk_pairs_sparse
 from repro.data import make_drugbank_like_dataset, make_synthetic_dataset
-from repro.kernels.ops import packs_for_batch
+from repro.kernels.ops import row_panel_packs_for_batch
 from .common import row, time_fn
 
 VK = KroneckerDelta(0.5, n_labels=8)
@@ -52,7 +52,8 @@ def run(datasets=("nws", "ba", "drugbank_like")) -> list[str]:
         base = us
         out.append(row(f"ladder_{ds}_dense", us, "speedup=1.00x"))
 
-        packs_a, packs_b = packs_for_batch(A), packs_for_batch(B)
+        packs_a = row_panel_packs_for_batch(A)
+        packs_b = row_panel_packs_for_batch(B)
         us = time_fn(lambda a, b, pa, pb: mgk_pairs_sparse(
             a, b, pa, pb, VK, EK, tol=1e-8).values,
             A, B, packs_a, packs_b, iters=3)
@@ -63,7 +64,8 @@ def run(datasets=("nws", "ba", "drugbank_like")) -> list[str]:
         gb_r = [g.permuted(pbr_order(g.adjacency)) for g in gb]
         Ar = batch_from_graphs(ga_r, pad_to=pad)
         Br = batch_from_graphs(gb_r, pad_to=pad)
-        pa_r, pb_r = packs_for_batch(Ar), packs_for_batch(Br)
+        pa_r = row_panel_packs_for_batch(Ar)
+        pb_r = row_panel_packs_for_batch(Br)
         us = time_fn(lambda a, b, pa, pb: mgk_pairs_sparse(
             a, b, pa, pb, VK, EK, tol=1e-8).values,
             Ar, Br, pa_r, pb_r, iters=3)

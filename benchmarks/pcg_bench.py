@@ -88,10 +88,8 @@ def _pack_bytes(pack) -> int:
     """HBM bytes of the value buffers a matvec streams (indices/counts
     excluded — they are SMEM scalar-prefetch traffic)."""
     total = 0
-    for field in ("values_adj", "values_lab", "values_w", "values_grad"):
-        arr = getattr(pack, field)
-        if arr is not None:
-            total += arr.nbytes
+    for field in ("values_adj", "values_lab"):
+        total += getattr(pack, field).nbytes
     return total
 
 
@@ -101,12 +99,11 @@ def run(out_path: str = "BENCH_pcg.json", B: int = 4, n: int = 32,
 
     for name, kind in BUCKETS:
         g1, g2 = _bucket(B, n, kind, seed)
-        p1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-        p2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
+        p1 = row_panel_packs_for_batch(g1)
+        p2 = row_panel_packs_for_batch(g2)
 
         def solve(precond):
-            return mgk_pairs_sparse(g1, g2, p1, p2, VK, EK,
-                                    sparse_mode="mxu", tol=tol,
+            return mgk_pairs_sparse(g1, g2, p1, p2, VK, EK, tol=tol,
                                     precond=precond)
 
         rj, rk = solve("jacobi"), solve("kron")
@@ -149,18 +146,16 @@ def run(out_path: str = "BENCH_pcg.json", B: int = 4, n: int = 32,
 
     # bf16 pack streaming: bytes per matvec + measured parity
     g1, g2 = _bucket(B, n, BUCKETS[1][1], seed)
-    pf1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-    pf2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
-    pb1 = row_panel_packs_for_batch(g1, edge_kernel=EK,
-                                    pack_dtype=jnp.bfloat16)
-    pb2 = row_panel_packs_for_batch(g2, edge_kernel=EK,
-                                    pack_dtype=jnp.bfloat16)
+    pf1 = row_panel_packs_for_batch(g1)
+    pf2 = row_panel_packs_for_batch(g2)
+    pb1 = row_panel_packs_for_batch(g1, pack_dtype=jnp.bfloat16)
+    pb2 = row_panel_packs_for_batch(g2, pack_dtype=jnp.bfloat16)
     rng = np.random.default_rng(seed)
     nn = g1.adjacency.shape[1]
     P = to_tiles(jnp.asarray(rng.random((B, nn, nn)).astype(np.float32)),
                  pf1.tile)
-    yf = xmv_row_panel_batched(pf1, pf2, P, EK, mode="mxu")
-    yb = xmv_row_panel_batched(pb1, pb2, P, EK, mode="mxu")
+    yf = xmv_row_panel_batched(pf1, pf2, P, EK)
+    yb = xmv_row_panel_batched(pb1, pb2, P, EK)
     rel = float(np.max(np.abs(np.asarray(yf - yb)))
                 / np.max(np.abs(np.asarray(yf))))
     bytes_f32 = _pack_bytes(pf1) + _pack_bytes(pf2)

@@ -1,18 +1,11 @@
-"""PR 1/2/4 perf tracking: the CG hot-path before/after comparison.
+"""CG hot-path timings on the CPU/interpret harness.
 
 Emits ``BENCH_xmv.json`` with
 
-* per-matvec wall time of the block-sparse bucket XMV across the three
-  kernel generations at several bucket sizes B: legacy loop-of-launches
-  (one ``pallas_call`` + jit dispatch per pair), the PR-1 batched
-  unrolled grid (one launch, a grid step per (slot, slot') pair), and
-  the PR-2 row-panel kernel (one launch, one grid step per output
-  block, in-kernel slot reduction over VMEM-staged tile rows) in both
-  its elementwise and MXU-contraction modes;
-* the same arms swept over octile edge t in {8, 16, 32} (the t^4 VPU
-  broadcast vs rank-batched MXU matmul scaling; on this CPU harness the
-  MXU mode's matmuls only pull ahead of the elementwise tensor at t=32,
-  where 2*R*t^3 < t^4 — on real MXU hardware the crossover is earlier);
+* per-matvec wall time of the block-sparse bucket XMV — the row-panel
+  kernel (one launch, one grid step per output block, in-kernel slot
+  reduction over VMEM-staged tile rows) — at several bucket sizes B;
+* the same swept over octile edge t in {8, 16, 32};
 * fused diagonal epilogue vs the two-step ``diag*p - y`` reference on
   the dense batched path;
 * classic vs pipelined PCG on the same product systems: wall time per
@@ -21,8 +14,7 @@ Emits ``BENCH_xmv.json`` with
   overhead), and the per-pair iteration counts (must agree within ±1).
 
 Numbers here come from the CPU/interpret harness — the absolute times
-are not TPU times, but the *launch/grid-step count* effects the batched
-grid and the row-panel kernel remove are exactly what they measure.
+are not TPU times.
 
 On the pipelined-PCG column: PR 1 recorded pipelined ~27% slower per
 solve than classic here despite identical iteration counts. That is an
@@ -49,10 +41,9 @@ from repro.core.base_kernels import KroneckerDelta, SquareExponential
 from repro.core.graph import batch_from_graphs
 from repro.core.mgk import mgk_pairs_sparse, mgk_pairs_sparse_segmented
 from repro.data import make_drugbank_like_dataset
-from repro.kernels.ops import packs_for_batch, row_panel_packs_for_batch, \
-    xmv_block_sparse_unrolled
-from repro.kernels.xmv_block_sparse import xmv_block_sparse_batched, \
-    xmv_gram_tile, xmv_row_panel_batched
+from repro.kernels.ops import row_panel_packs_for_batch
+from repro.kernels.xmv_block_sparse import xmv_gram_tile, \
+    xmv_row_panel_batched
 from repro.kernels.xmv_block_sparse import to_tiles
 from repro.kernels.xmv_dense import DENSE_TILE, xmv_dense_batched
 from .common import row, time_fn
@@ -93,29 +84,13 @@ def _bucket(B: int, pad_to: int, seed: int = 7):
     return g1, g2
 
 
-def _sparse_arms(g1, g2, P, iters, tile: int = 8, with_unrolled=True):
-    """Time every block-sparse kernel generation on one bucket."""
-    p1 = packs_for_batch(g1, tile=tile)
-    p2 = packs_for_batch(g2, tile=tile)
+def _row_panel_us(g1, g2, P, iters, tile: int = 8) -> float:
+    """Time the whole-bucket row-panel matvec on one bucket."""
     r1 = row_panel_packs_for_batch(g1, tile=tile)
     r2 = row_panel_packs_for_batch(g2, tile=tile)
-    r1w = row_panel_packs_for_batch(g1, tile=tile, edge_kernel=EK)
-    r2w = row_panel_packs_for_batch(g2, tile=tile, edge_kernel=EK)
-    out = {}
-    if with_unrolled:
-        out["us_per_matvec_unrolled"] = time_fn(
-            lambda P: xmv_block_sparse_unrolled(p1, p2, P, EK),
-            P, iters=iters)
-    out["us_per_matvec_batched"] = time_fn(
-        lambda P: xmv_block_sparse_batched(p1, p2, P, EK), P, iters=iters)
     Pt = to_tiles(P, tile)     # the row-panel kernels' tile-major order
-    out["us_per_matvec_row_panel"] = time_fn(
-        lambda P: xmv_row_panel_batched(r1, r2, P, EK, mode="elementwise"),
-        Pt, iters=iters)
-    out["us_per_matvec_row_panel_mxu"] = time_fn(
-        lambda P: xmv_row_panel_batched(r1w, r2w, P, EK, mode="mxu"),
-        Pt, iters=iters)
-    return out
+    return time_fn(lambda P: xmv_row_panel_batched(r1, r2, P, EK),
+                   Pt, iters=iters)
 
 
 def run(out_path: str = "BENCH_xmv.json", sizes=(2, 8, 16),
@@ -129,46 +104,22 @@ def run(out_path: str = "BENCH_xmv.json", sizes=(2, 8, 16),
         g1, g2 = _bucket(B, pad_to)
         n = g1.adjacency.shape[1]
         P = jnp.asarray(rng.random((B, n, n)).astype(np.float32))
-        arms = _sparse_arms(g1, g2, P, iters)
-        batched = arms["us_per_matvec_batched"]
-        entry = {"B": B, "n": n, "tile": 8, **arms,
-                 "speedup": arms["us_per_matvec_unrolled"]
-                 / max(batched, 1e-9),
-                 "speedup_row_panel_vs_batched": batched
-                 / max(arms["us_per_matvec_row_panel"], 1e-9),
-                 "speedup_row_panel_mxu_vs_batched": batched
-                 / max(arms["us_per_matvec_row_panel_mxu"], 1e-9)}
-        report["matvec_block_sparse"].append(entry)
-        row(f"xmv_sparse_unrolled_B{B}", arms["us_per_matvec_unrolled"],
-            "loop-of-launches")
-        row(f"xmv_sparse_batched_B{B}", batched,
-            f"one-launch-speedup={entry['speedup']:.2f}x")
-        row(f"xmv_sparse_row_panel_B{B}", arms["us_per_matvec_row_panel"],
-            f"vs-batched={entry['speedup_row_panel_vs_batched']:.2f}x")
-        row(f"xmv_sparse_row_panel_mxu_B{B}",
-            arms["us_per_matvec_row_panel_mxu"],
-            f"vs-batched={entry['speedup_row_panel_mxu_vs_batched']:.2f}x")
+        us = _row_panel_us(g1, g2, P, iters)
+        report["matvec_block_sparse"].append(
+            {"B": B, "n": n, "tile": 8, "us_per_matvec_row_panel": us})
+        row(f"xmv_sparse_row_panel_B{B}", us, "one-launch")
 
-    # octile-edge sweep: the t^4 VPU tensor vs rank-batched MXU matmuls
+    # octile-edge sweep
     for t in tiles:
         if tile_pad_to % t:
             continue
         g1, g2 = _bucket(tile_B, tile_pad_to)
         n = g1.adjacency.shape[1]
         P = jnp.asarray(rng.random((tile_B, n, n)).astype(np.float32))
-        arms = _sparse_arms(g1, g2, P, iters, tile=t, with_unrolled=False)
-        batched = arms["us_per_matvec_batched"]
-        entry = {"B": tile_B, "n": n, "tile": t, **arms,
-                 "speedup_row_panel_vs_batched": batched
-                 / max(arms["us_per_matvec_row_panel"], 1e-9),
-                 "speedup_row_panel_mxu_vs_batched": batched
-                 / max(arms["us_per_matvec_row_panel_mxu"], 1e-9)}
-        report["matvec_tile_sweep"].append(entry)
-        row(f"xmv_sparse_row_panel_t{t}", arms["us_per_matvec_row_panel"],
-            f"vs-batched={entry['speedup_row_panel_vs_batched']:.2f}x")
-        row(f"xmv_sparse_row_panel_mxu_t{t}",
-            arms["us_per_matvec_row_panel_mxu"],
-            f"vs-batched={entry['speedup_row_panel_mxu_vs_batched']:.2f}x")
+        us = _row_panel_us(g1, g2, P, iters, tile=t)
+        report["matvec_tile_sweep"].append(
+            {"B": tile_B, "n": n, "tile": t, "us_per_matvec_row_panel": us})
+        row(f"xmv_sparse_row_panel_t{t}", us, f"tile={t}")
 
     # fused diagonal epilogue vs separate XLA op (dense path, largest B)
     B = sizes[-1]
@@ -197,9 +148,9 @@ def run(out_path: str = "BENCH_xmv.json", sizes=(2, 8, 16),
     row(f"xmv_dense_fused_B{B}", us_fused, "in-kernel-epilogue")
 
     # classic vs pipelined PCG on the real sparse product systems (the
-    # production row-panel MXU matvec)
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
+    # production row-panel matvec)
+    p1 = row_panel_packs_for_batch(g1)
+    p2 = row_panel_packs_for_batch(g2)
     pcg: dict = {}
     k_lo, k_hi = 5, 15
     for variant in ("classic", "pipelined"):
@@ -249,15 +200,15 @@ def _gram_batches(Bi: int, Bj: int, pad_to: int, seed: int = 7):
 def run_gram(out_path: str = "BENCH_gram.json",
              shapes=((2, 2), (4, 4), (8, 8)), pad_to: int = 32,
              iters: int = 5, segment_size: int = 4) -> dict:
-    """PR 4: Gram-tile hot path vs stacked per-pair row-panel, plus
+    """Gram-tile hot path vs stacked per-pair row-panel, plus
     convergence-segmented PCG vs masked lockstep.
 
     Per I x J Gram-tile shape:
 
     * per-matvec wall time of ``xmv_gram_tile`` (ONE pack per axis,
       (Bi, nt, Bj) grid, in-kernel output-column loop) against
-      ``xmv_row_panel_batched`` over per-pair stacked packs (the PR-2
-      production kernel) — both modes. On this interpret harness the
+      ``xmv_row_panel_batched`` over per-pair stacked packs. On this
+      interpret harness the
       win is the mt-fold grid-step reduction; on hardware it is that
       plus each row graph's panels fetched once per tile row instead of
       once per (pair, tile row).
@@ -278,46 +229,27 @@ def run_gram(out_path: str = "BENCH_gram.json",
         # per-axis packs (Bi + Bj) vs per-pair stacked packs (Bi*Bj)
         a1 = row_panel_packs_for_batch(g1u)
         a2 = row_panel_packs_for_batch(g2u)
-        a1w = row_panel_packs_for_batch(g1u, edge_kernel=EK)
-        a2w = row_panel_packs_for_batch(g2u, edge_kernel=EK)
         p1 = row_panel_packs_for_batch(g1f)
         p2 = row_panel_packs_for_batch(g2f)
-        p1w = row_panel_packs_for_batch(g1f, edge_kernel=EK)
-        p2w = row_panel_packs_for_batch(g2f, edge_kernel=EK)
         entry = {"Bi": Bi, "Bj": Bj, "n": n, "tile": 8}
         entry["us_per_matvec_per_pair"] = time_fn(
-            lambda P: xmv_row_panel_batched(p1, p2, P, EK,
-                                            mode="elementwise"),
+            lambda P: xmv_row_panel_batched(p1, p2, P, EK),
             Pf, iters=iters)
         entry["us_per_matvec_gram_tile"] = time_fn(
-            lambda P: xmv_gram_tile(a1, a2, P, EK, mode="elementwise"),
-            P4, iters=iters)
-        entry["us_per_matvec_per_pair_mxu"] = time_fn(
-            lambda P: xmv_row_panel_batched(p1w, p2w, P, EK, mode="mxu"),
-            Pf, iters=iters)
-        entry["us_per_matvec_gram_tile_mxu"] = time_fn(
-            lambda P: xmv_gram_tile(a1w, a2w, P, EK, mode="mxu"),
-            P4, iters=iters)
+            lambda P: xmv_gram_tile(a1, a2, P, EK), P4, iters=iters)
         entry["speedup_gram_tile_vs_per_pair"] = \
             entry["us_per_matvec_per_pair"] / max(
                 entry["us_per_matvec_gram_tile"], 1e-9)
-        entry["speedup_gram_tile_vs_per_pair_mxu"] = \
-            entry["us_per_matvec_per_pair_mxu"] / max(
-                entry["us_per_matvec_gram_tile_mxu"], 1e-9)
         report["gram_tile"].append(entry)
         row(f"xmv_gram_tile_{Bi}x{Bj}", entry["us_per_matvec_gram_tile"],
             f"vs-per-pair={entry['speedup_gram_tile_vs_per_pair']:.2f}x")
-        row(f"xmv_gram_tile_mxu_{Bi}x{Bj}",
-            entry["us_per_matvec_gram_tile_mxu"],
-            f"vs-per-pair="
-            f"{entry['speedup_gram_tile_vs_per_pair_mxu']:.2f}x")
 
         # segmented PCG vs masked lockstep on the same Gram tile (a
         # mixed-convergence bucket: iteration counts vary per pair)
-        lock = mgk_pairs_sparse(g1f, g2f, a1w, a2w, VK, EK, tol=1e-10,
+        lock = mgk_pairs_sparse(g1f, g2f, a1, a2, VK, EK, tol=1e-10,
                                 gram_tile=(Bi, Bj))
         seg = mgk_pairs_sparse_segmented(
-            g1f, g2f, a1w, a2w, VK, EK, tol=1e-10,
+            g1f, g2f, a1, a2, VK, EK, tol=1e-10,
             segment_size=segment_size, gram_tile=(Bi, Bj))
         its = np.asarray(lock.iterations)
         seg_entry = {

@@ -9,10 +9,9 @@ chip (not interpreted), each checked against the numpy reference.
 
 Phases (one chip):
 
-* ``nws-mxu`` / ``nws-vpu``: the paper's synthetic NWS set (160 graphs x
-  96 nodes) as one whole Gram, ``method="pallas_sparse"`` with Gram-tile
-  execution on 8x8 tiles, ``sparse_mode="mxu"`` (MXU contraction) and
-  ``"elementwise"`` (VPU, what ``"auto"`` runs);
+* ``nws-gram-tile``: the paper's synthetic NWS set (160 graphs x 96
+  nodes) as one whole Gram, ``method="pallas_sparse"`` with Gram-tile
+  execution on 8x8 tiles;
 * ``drugbank-dense``: the DrugBank-shaped set, size-bucketed, through
   ``method="pallas"`` (the dense kernel) on every bucket.
 
@@ -205,11 +204,9 @@ def run_one_chip(small: bool, on_tpu: bool, failures: list) -> None:
                                             method="lowrank")
     emit("nws-lowrank", smoke_compile_s=f"{csecs:.1f}",
          smoke_build_s=f"{secs:.1f}", retries=drv.health["retries"])
-    for name, mode in (("nws-mxu", "mxu"), ("nws-vpu", "elementwise")):
-        out = timed_build(name, nws, mesh, method="pallas_sparse",
-                          gram_tile=True, tile_shape=(8, 8),
-                          sparse_mode=mode)
-        builds.append((name, *out, nws_pairs, nws_ref, K_lr))
+    out = timed_build("nws-gram-tile", nws, mesh, method="pallas_sparse",
+                      gram_tile=True, tile_shape=(8, 8))
+    builds.append(("nws-gram-tile", *out, nws_pairs, nws_ref, K_lr))
 
     # (b) DrugBank-shaped, size-bucketed, the dense kernel on every bucket
     emit("drugbank", graphs=len(db),

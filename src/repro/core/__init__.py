@@ -21,8 +21,7 @@ from .mgk import MGKResult, ProductSystem, adaptive_route, \
     build_product_system, mgk_adaptive, mgk_pairs, mgk_pairs_sparse, \
     mgk_pairs_sparse_segmented, mgk_single
 from .octile import (OctileSet, count_nonempty_tiles, expand_octiles,
-                     feature_operands, octile_decompose,
-                     tile_occupancy_histogram)
+                     octile_decompose, tile_occupancy_histogram)
 from .pcg import PCGResult, adjoint_solve, pcg_solve, \
     pcg_solve_segmented
 from .precond import (KronFactors, kron_apply, kron_apply_gram,
@@ -39,7 +38,7 @@ __all__ = [
     "mgk_pairs_sparse_segmented", "mgk_adaptive",
     "adaptive_route", "OctileSet", "count_nonempty_tiles",
     "expand_octiles", "octile_decompose", "tile_occupancy_histogram",
-    "feature_operands", "PCGResult", "pcg_solve", "pcg_solve_segmented", "adjoint_solve",
+    "PCGResult", "pcg_solve", "pcg_solve_segmented", "adjoint_solve",
     "KronFactors", "kron_factors", "kron_factor_arrays", "kron_scalars",
     "kron_apply", "kron_apply_gram", "take_kron_factors",
     "stack_kron_factors",

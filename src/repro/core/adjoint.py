@@ -27,11 +27,7 @@ The parameter contractions never materialize ∂A:
 * edge params enter through the off-diagonal ``A_x ∘ E_x``, whose
   θ-derivative has A's sparsity: ``λᵀ (∂A_x∘E_x) x`` is ONE raw XMV of
   x with kappa replaced by ∂kappa/∂θ (``ParamDerivative``) — the same
-  dispatch backend as the forward solve — followed by a dot with λ. On
-  the row-panel MXU path the derivative kernel
-  ``∂kappa = Σ_r (∂f_r f'_r + f_r ∂f'_r)`` is a rank-2R bilinear form,
-  so the contraction runs the UNCHANGED MXU kernel with slot operands
-  ``[wg ; w]`` vs ``[w' ; wg']`` (the ``values_grad`` companions).
+  dispatch backend as the forward solve — followed by a dot with λ.
 
 Cost: gradients w.r.t. ALL hyperparameters ≈ one extra PCG solve per
 pair (the acceptance contract: exactly two solves in the jaxpr — tested
@@ -105,13 +101,11 @@ def mgk_value_fn(
     method: str = "lowrank",
     packs1=None,
     packs2=None,
-    sparse_mode: str = "auto",
     chunk: int = 8,
     tol: float = 1e-10,
     max_iter: int = 512,
     fixed_iters: int | None = None,
     pcg_variant: str = "classic",
-    trust_pack_weights: bool = False,
     gram_tile: tuple[int, int] | None = None,
     precond: str = "jacobi",
     kron_rank: int = 2,
@@ -122,19 +116,13 @@ def mgk_value_fn(
 
     ``method``: any dense backend of :func:`~repro.core.mgk.mgk_pairs`
     ("full" / "elementwise" / "lowrank" / "pallas") or "sparse" with
-    stacked row-panel ``packs1``/``packs2`` (+ ``sparse_mode``, as in
-    :func:`~repro.core.mgk.mgk_pairs_sparse`; the legacy TilePack packs
-    carry no in-kernel theta path and are not supported here).
+    stacked row-panel ``packs1``/``packs2`` (as in
+    :func:`~repro.core.mgk.mgk_pairs_sparse`; theta's edge values reach
+    the kernel as its in-kernel theta vector).
     ``gram_tile=(Bi, Bj)``: the packs are PER-AXIS and both the forward
     and adjoint solves — plus the edge-gradient contraction — run on the
     single-launch Gram-tile kernel (g1/g2 stay the row-major
     pair-flattened batches, as in ``mgk_pairs_sparse``).
-
-    ``trust_pack_weights``: use the packs' host-precomputed ``values_w``
-    / ``values_grad`` buffers instead of re-deriving them on device from
-    ``theta`` — valid ONLY when theta's edge values equal the pack-time
-    kernel parameters (the Gram driver's fixed-θ evaluation; it is what
-    makes the pack cache shared between forward and adjoint solves).
 
     ``precond="kron"``: BOTH the forward and the adjoint solve run with
     the Kronecker-factored approximate inverse (DESIGN.md §9). The
@@ -153,13 +141,9 @@ def mgk_value_fn(
     forward + adjoint solve pair.
     """
     sparse = method in ("sparse", "pallas_sparse")
-    if sparse:
-        from repro.kernels.ops import RowPanelPack
-        if not isinstance(packs1, RowPanelPack) or \
-                not isinstance(packs2, RowPanelPack):
-            raise ValueError(
-                "method='sparse' needs stacked RowPanelPack packs1/packs2"
-                " (legacy TilePacks have no differentiable path)")
+    if sparse and (packs1 is None or packs2 is None):
+        raise ValueError("method='sparse' needs stacked RowPanelPack"
+                         " packs1/packs2")
     B, n = g1.adjacency.shape[0], g1.adjacency.shape[1]
     m = g2.adjacency.shape[1]
     # Pallas backends solve (forward AND adjoint) in the kernels'
@@ -187,13 +171,12 @@ def mgk_value_fn(
 
     def _build_mv(theta, sys_):
         _, te, _ = _parts(theta)
-        te_mv = None if trust_pack_weights else te
         if sparse:
             return _make_sparse_matvec(sys_, packs1, packs2, edge_kernel,
-                                       sparse_mode, (B, n, m),
-                                       theta_e=te_mv, gram_tile=gram_tile)
+                                       (B, n, m), theta_e=te,
+                                       gram_tile=gram_tile)
         return _make_matvec(g1, g2, sys_, edge_kernel, method, chunk,
-                            theta_e=te_mv)
+                            theta_e=te)
 
     def _system(theta):
         tv, _, q = _parts(theta)
@@ -214,53 +197,13 @@ def mgk_value_fn(
         """{name: raw XMV of x with kappa -> ∂kappa/∂θ_name} for ALL
         edge parameters: the sparsity-preserving half of λᵀ (∂A/∂θ) x,
         [B, n*m] per name (x and the result in the solve's vector
-        order). Parameter-independent operand derivation
-        (device_weighted_pack, weighted operands) is hoisted out of the
-        per-name loop — it already carries every parameter's slice."""
+        order). Parameter-independent operand derivation (the
+        lowrank weighted operands) is hoisted out of the per-name loop —
+        it already carries every parameter's slice."""
         if sparse:
-            # mirror _make_sparse_matvec: "auto" runs elementwise
-            if sparse_mode == "mxu":
-                from repro.kernels.ops import device_weighted_pack, \
-                    xmv_gram_tile, xmv_row_panel_batched
-                if trust_pack_weights and packs1.values_grad is not None \
-                        and packs2.values_grad is not None:
-                    p1, p2 = packs1, packs2
-                else:
-                    p1 = device_weighted_pack(packs1, edge_kernel,
-                                              theta=te, with_grad=True)
-                    p2 = device_weighted_pack(packs2, edge_kernel,
-                                              theta=te, with_grad=True)
-                out = {}
-                for name in names:
-                    pi = edge_kernel.param_names().index(name)
-                    wg1 = jnp.take(p1.values_grad, pi, axis=-4)
-                    wg2 = jnp.take(p2.values_grad, pi, axis=-4)
-                    # rank-2R bilinear form: [wg ; w] vs [w' ; wg']
-                    # computes Σ_r (wg_r P w'_rᵀ + w_r P wg'_rᵀ) in the
-                    # SAME kernel
-                    c1 = p1._replace(
-                        values_w=jnp.concatenate([wg1, p1.values_w],
-                                                 axis=-3),
-                        values_grad=None)
-                    c2 = p2._replace(
-                        values_w=jnp.concatenate([p2.values_w, wg2],
-                                                 axis=-3),
-                        values_grad=None)
-                    tiles = (n // t, m // t, t, t)
-                    if gram_tile is not None:
-                        y = xmv_gram_tile(
-                            c1, c2, x.reshape(tuple(gram_tile) + tiles),
-                            edge_kernel, mode="mxu")
-                    else:
-                        y = xmv_row_panel_batched(
-                            c1, c2, x.reshape((B,) + tiles), edge_kernel,
-                            mode="mxu")
-                    out[name] = y.reshape(B, -1)
-                return out
             return {name: _make_sparse_matvec(
                 None, packs1, packs2, ParamDerivative(edge_kernel, name),
-                "elementwise", (B, n, m), theta_e=te, raw=True,
-                gram_tile=gram_tile)(x)
+                (B, n, m), theta_e=te, raw=True, gram_tile=gram_tile)(x)
                 for name in names}
         if method == "lowrank":
             wo = lambda a, e: weighted_operands(a, e, edge_kernel,  # noqa
@@ -420,8 +363,7 @@ def mgk_adaptive_value_and_grad(
         fn = mgk_value_fn(
             g1, g2, vertex_kernel, edge_kernel, method="sparse",
             packs1=row_panel_packs_for_batch(g1, tile=tile),
-            packs2=row_panel_packs_for_batch(g2, tile=tile),
-            sparse_mode="elementwise", **kw)
+            packs2=row_panel_packs_for_batch(g2, tile=tile), **kw)
     else:
         fn = mgk_value_fn(g1, g2, vertex_kernel, edge_kernel,
                           method=route, **kw)

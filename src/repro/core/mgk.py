@@ -8,11 +8,9 @@ computed with the batched PCG of core/pcg.py and one of the XMV backends:
            "elementwise"  paper-faithful streaming XMV (jnp)
            "lowrank"      beyond-paper MXU sandwich (feature expansion)
            "pallas"       Pallas TPU tiling&blocking kernel
-           "pallas_sparse" Pallas block-sparse octile kernel; row-panel
-                          packs select the VMEM-staged row-panel kernel
-                          whose in-kernel slot reduction runs either
-                          elementwise (VPU) or as the MXU low-rank
-                          contraction (``sparse_mode``)
+           "pallas_sparse" Pallas block-sparse octile kernel: the
+                          VMEM-staged row-panel kernel, whose in-kernel
+                          slot reduction runs elementwise on the VPU
            "adaptive"     density-based host dispatch (paper Sec. IV-B)
 
 Batched over pairs: both operands are GraphBatch pytrees of equal batch
@@ -94,8 +92,7 @@ def kernel_tile(method: str, packs1=None) -> int | None:
     if method == "pallas":
         from repro.kernels.xmv_dense import DENSE_TILE
         return DENSE_TILE
-    from repro.kernels.xmv_block_sparse import RowPanelPack
-    if method == "sparse" and isinstance(packs1, RowPanelPack):
+    if method == "sparse":
         return packs1.tile
     return None
 
@@ -204,7 +201,7 @@ def _make_matvec(g1: GraphBatch, g2: GraphBatch, sys_: ProductSystem,
             # launch IS the whole operator application (DESIGN.md §3)
             out = xmv_row_panel_batched(pk1, pk2, p_vec.reshape(shape),
                                         edge_kernel, diag=diag_t,
-                                        mode="elementwise", theta=tvec)
+                                        theta=tvec)
             return out.reshape(B, -1)
         return matvec
 
@@ -291,12 +288,12 @@ def _make_precond_apply(precond: str, g1: GraphBatch, g2: GraphBatch,
 
 
 def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
-                        edge_kernel: BaseKernel, sparse_mode: str,
+                        edge_kernel: BaseKernel,
                         shape: tuple[int, int, int],
                         theta_e=None, raw: bool = False,
                         gram_tile: tuple[int, int] | None = None):
-    """Block-sparse analogue of :func:`_make_matvec` over stacked packs
-    (RowPanelPack -> row-panel kernel, TilePack -> legacy batched grid).
+    """Block-sparse analogue of :func:`_make_matvec` over stacked
+    row-panel packs.
 
     With ``gram_tile=(Bi, Bj)`` the packs are PER-AXIS instead of
     per-pair — ``packs1`` holds the Bi row graphs, ``packs2`` the Bj
@@ -305,53 +302,16 @@ def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
     DESIGN.md §8). The [B, n*m] vector contract is unchanged, so the
     PCG solvers and the adjoint path dispatch to it unmodified.
 
-    With ``theta_e``, traced edge hyperparameters reach the kernels two
-    ways (DESIGN.md §7): the elementwise mode takes a packed theta
-    vector straight into the Pallas kernel; the MXU mode re-derives the
-    weighted operands ``values_w`` on device from the pack's structural
-    fields (``device_weighted_pack``) — unless the pack already carries
-    weights and ``theta_e`` is None, in which case the pack-time host
-    precompute is trusted as-is.
-
-    Row-panel packs work in the tile-major order of :func:`kernel_tile`
-    (``sys_`` and the vectors already permuted); legacy TilePacks stay
-    node-major."""
-    from repro.kernels.ops import RowPanelPack, device_weighted_pack, \
-        xmv_block_sparse_batched, xmv_gram_tile, xmv_row_panel_batched
+    With ``theta_e``, traced edge hyperparameters reach the kernel as a
+    packed theta vector in SMEM (DESIGN.md §7). The packs work in the
+    tile-major order of :func:`kernel_tile` (``sys_`` and the vectors
+    already permuted)."""
+    from repro.kernels.ops import xmv_gram_tile, xmv_row_panel_batched
     from .base_kernels import pack_theta
 
     B, n, m = shape
     diag = None if raw else sys_.dx / sys_.vx
-    row_panel = isinstance(packs1, RowPanelPack)
-    if gram_tile is not None and not row_panel:
-        raise ValueError("gram_tile needs RowPanelPack per-axis packs"
-                         " (legacy TilePacks have no Gram-tile kernel)")
-    tvec = None
-    if row_panel:
-        have_w = packs1.values_w is not None and \
-            packs2.values_w is not None
-        # "auto" is the kernels' own rule (_resolve_mode): elementwise
-        # (exact, theta via the in-kernel vector), weighted packs or not
-        mxu = sparse_mode == "mxu"
-        if mxu and (theta_e is not None or not have_w):
-            packs1 = device_weighted_pack(packs1, edge_kernel,
-                                          theta=theta_e)
-            packs2 = device_weighted_pack(packs2, edge_kernel,
-                                          theta=theta_e)
-        if not mxu and theta_e is not None:
-            tvec = pack_theta(edge_kernel, theta_e)
-        mode = "mxu" if mxu else "elementwise"
-
-    if not row_panel:
-        diag_nm = None if raw else diag.reshape(B, n, m)
-
-        def matvec(p_vec):
-            out = xmv_block_sparse_batched(packs1, packs2,
-                                           p_vec.reshape(B, n, m),
-                                           edge_kernel, diag=diag_nm)
-            return out.reshape(B, -1)
-        return matvec
-
+    tvec = None if theta_e is None else pack_theta(edge_kernel, theta_e)
     t = packs1.tile
     if gram_tile is not None:
         Bi, Bj = gram_tile
@@ -368,7 +328,7 @@ def _make_sparse_matvec(sys_: ProductSystem, packs1, packs2,
         # full operator application); raw mode (diag None) emits +y, the
         # pure XMV the adjoint contraction needs
         out = xmv(packs1, packs2, p_vec.reshape(shape), edge_kernel,
-                  diag=diag_t, mode=mode, theta=tvec)
+                  diag=diag_t, theta=tvec)
         return out.reshape(B, -1)
     return matvec
 
@@ -479,7 +439,7 @@ def adaptive_route(g1: GraphBatch, g2: GraphBatch,
     ``density * n``, and the labels stay inside the expansion's accuracy
     domain (the SE Taylor truncation) — otherwise exact elementwise
     paths. Sparse buckets run the row-panel kernels' elementwise body,
-    the one "auto" picks (``kernels.xmv_block_sparse._resolve_mode``).
+    the only one they have.
     Returns (route, tile) with ``tile`` shrunk to the largest of
     {tile, 16, 8} dividing the bucket's padded size.
     """
@@ -528,7 +488,7 @@ def mgk_adaptive(g1: GraphBatch, g2: GraphBatch,
         return mgk_pairs_sparse(
             g1, g2, row_panel_packs_for_batch(g1, tile=tile),
             row_panel_packs_for_batch(g2, tile=tile),
-            vertex_kernel, edge_kernel, sparse_mode="elementwise", **kw)
+            vertex_kernel, edge_kernel, **kw)
     return mgk_pairs(g1, g2, vertex_kernel, edge_kernel, method=route,
                      **kw)
 
@@ -537,17 +497,16 @@ def mgk_adaptive(g1: GraphBatch, g2: GraphBatch,
     jax.jit,
     static_argnames=("vertex_kernel", "edge_kernel", "max_iter",
                      "return_nodal", "fixed_iters", "pcg_variant",
-                     "sparse_mode", "gram_tile", "precond", "kron_rank",
-                     "guard", "fault"))
+                     "gram_tile", "precond", "kron_rank", "guard",
+                     "fault"))
 def mgk_pairs_sparse(
     g1: GraphBatch,
     g2: GraphBatch,
-    packs1,                      # stacked RowPanelPack or legacy TilePack
+    packs1,                      # stacked (or per-axis) RowPanelPack
     packs2,
     vertex_kernel: BaseKernel = Constant(1.0),
     edge_kernel: BaseKernel = Constant(1.0),
     *,
-    sparse_mode: str = "auto",
     tol: float = 1e-10,
     max_iter: int = 512,
     return_nodal: bool = False,
@@ -564,18 +523,16 @@ def mgk_pairs_sparse(
 ) -> MGKResult:
     """Block-sparse-octile variant of mgk_pairs (paper Sec. IV).
 
-    The packs are host-preprocessed (``row_panel_packs_for_batch`` /
-    ``packs_for_batch`` after reordering) — the quadratic CG work then
-    touches only non-empty octiles. GraphBatch still supplies the
+    The packs are host-preprocessed (``row_panel_packs_for_batch``
+    after reordering) — the quadratic CG work then touches only
+    non-empty octiles. GraphBatch still supplies the
     diagonal/probability vectors (cheap, O(n+m)).
 
     Stacked :class:`~repro.kernels.xmv_block_sparse.RowPanelPack` inputs
     run the row-panel kernel (VMEM tile-row reuse, in-kernel slot
-    reduction; ``sparse_mode`` picks "elementwise" / "mxu" / "auto");
-    stacked legacy TilePacks run the unrolled-grid baseline. Either way
-    the whole bucket's matvec is ONE ``pallas_call`` with the diagonal
-    epilogue fused in-kernel (DESIGN.md §3); shares mgk_pairs'
-    ``fixed_iters``/``pcg_variant`` contract.
+    reduction): the whole bucket's matvec is ONE ``pallas_call`` with
+    the diagonal epilogue fused in-kernel (DESIGN.md §3); shares
+    mgk_pairs' ``fixed_iters``/``pcg_variant`` contract.
 
     ``gram_tile=(Bi, Bj)`` switches to Gram-tile execution (DESIGN.md
     §8): ``packs1``/``packs2`` are then PER-AXIS row-panel packs (Bi row
@@ -595,8 +552,7 @@ def mgk_pairs_sparse(
                              n, m, t)
     diag = sys_.dx / sys_.vx
     matvec = _make_sparse_matvec(sys_, packs1, packs2, edge_kernel,
-                                 sparse_mode, (B, n, m),
-                                 gram_tile=gram_tile)
+                                 (B, n, m), gram_tile=gram_tile)
     papply = _tile_major_precond(
         _make_precond_apply(precond, g1, g2, vertex_kernel, edge_kernel,
                             (B, n, m), gram_tile=gram_tile,
@@ -623,7 +579,6 @@ def mgk_pairs_sparse_segmented(
     vertex_kernel: BaseKernel = Constant(1.0),
     edge_kernel: BaseKernel = Constant(1.0),
     *,
-    sparse_mode: str = "auto",
     tol: float = 1e-10,
     max_iter: int = 512,
     segment_size: int = 32,
@@ -670,8 +625,7 @@ def mgk_pairs_sparse_segmented(
                              n, m, t)
     diag = sys_.dx / sys_.vx
     matvec = _make_sparse_matvec(sys_, packs1, packs2, edge_kernel,
-                                 sparse_mode, (B, n, m),
-                                 gram_tile=gram_tile)
+                                 (B, n, m), gram_tile=gram_tile)
     kron = precond == "kron"
     if kron:
         # materialized HERE (not just inside the apply closure) because
@@ -702,7 +656,7 @@ def mgk_pairs_sparse_segmented(
             p1 = take_row_panel_pack(packs1, idx)
             p2 = take_row_panel_pack(packs2, idx)
         sub_mv = _make_sparse_matvec(sub_sys, p1, p2, edge_kernel,
-                                     sparse_mode, (len(lanes), n, m))
+                                     (len(lanes), n, m))
         if not kron:
             return sub_mv
         # the preconditioner factors remap through the survivor gather

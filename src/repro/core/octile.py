@@ -36,7 +36,6 @@ __all__ = [
     "expand_octiles",
     "bitmap_popcounts",
     "bitmap_words",
-    "feature_operands",
 ]
 
 TILE = 8  # the paper's octile edge length (default, not a constraint)
@@ -193,37 +192,6 @@ def tile_occupancy_histogram(adjacency: np.ndarray,
     counts = (a4 != 0).sum(axis=(2, 3)).ravel()
     counts = counts[counts > 0]
     return np.bincount(counts, minlength=tile * tile + 1)
-
-
-def feature_operands(values_adj, values_lab, edge_kernel, theta=None,
-                     with_grad: bool = False):
-    """Weighted MXU operands from packed tile values: ``w_r = a ∘ f_r(e)``
-    and (``with_grad``) their per-parameter derivatives
-    ``wg_{p,r} = a ∘ ∂f_r(e)/∂θ_p``.
-
-    Shape contract: ``[..., t, t]`` tile stacks in, ``([..., R, t, t]``,
-    ``[..., P, R, t, t] | None)`` out, P ordered by
-    ``edge_kernel.param_names()``. Pure jnp on whatever arrays come in —
-    the ONE implementation shared by host-side packing
-    (``kernels.xmv_block_sparse.pack_row_panels``, numpy in / numpy out
-    via ``np.asarray``) and the on-device repack of the differentiable
-    path (``device_weighted_pack``), where ``theta`` carries traced
-    hyperparameters and the result feeds the unchanged MXU kernel
-    (DESIGN.md §7)."""
-    import jax.numpy as jnp
-    phi = edge_kernel.features_theta(values_lab, theta)  # [..., t, t, R]
-    if phi is None:
-        raise ValueError(
-            f"{type(edge_kernel).__name__} has no feature expansion")
-    w = jnp.moveaxis(jnp.asarray(values_adj)[..., None] * phi, -1, -3)
-    wg = None
-    if with_grad and edge_kernel.param_names():
-        dphi = edge_kernel.dfeatures(values_lab, theta)
-        stacks = [jnp.moveaxis(jnp.asarray(values_adj)[..., None] * d,
-                               -1, -3)
-                  for d in (dphi[n] for n in edge_kernel.param_names())]
-        wg = jnp.stack(stacks, axis=-4)
-    return w, wg
 
 
 def expand_octiles(oset: OctileSet) -> tuple[np.ndarray, np.ndarray]:

@@ -77,14 +77,13 @@ class GraphPackCache:
     slot count; per-block stacking is then a cheap pad-and-stack to the
     block's shared k_max.
 
-    ``edge_kernel`` (feature-expandable) additionally precomputes the MXU
-    contraction operands into the cached packs. ``max_entries`` bounds
-    host memory with LRU eviction (configurable through
-    ``GramDriver.pack_cache_entries``) — the scheduler emits blocks
-    bucket-contiguously, so even a bound far below the dataset size keeps
-    the reuse (a graph's blocks are temporally close). An evicted graph
-    is simply re-decomposed on its next miss; the round trip is
-    bit-identical (the pack is a pure function of the graph arrays).
+    ``max_entries`` bounds host memory with LRU eviction (configurable
+    through ``GramDriver.pack_cache_entries``) — the scheduler emits
+    blocks bucket-contiguously, so even a bound far below the dataset
+    size keeps the reuse (a graph's blocks are temporally close). An
+    evicted graph is simply re-decomposed on its next miss; the round
+    trip is bit-identical (the pack is a pure function of the graph
+    arrays).
 
     Pack-time STATISTICS (octile count, nnz, occupancy density) persist
     in ``self.stats`` even after the pack itself is evicted — they are a
@@ -93,9 +92,9 @@ class GraphPackCache:
     uniform-density assumption with measured sparsity.
 
     ``pack_dtype`` stores the pack value buffers (``values_adj`` /
-    ``values_lab`` / ``values_w`` / ``values_grad``) in a narrower
-    dtype — ``jnp.bfloat16`` halves HBM bytes per matvec while the
-    kernels keep f32 accumulators (DESIGN.md §9.4).
+    ``values_lab``) in a narrower dtype — ``jnp.bfloat16`` halves HBM
+    bytes per matvec while the kernels keep f32 accumulators (DESIGN.md
+    §9.4).
 
     Kronecker-preconditioner FACTORS (``core/precond.py``) are cached
     alongside the packs, keyed by the same (dataset index, pad):
@@ -116,14 +115,11 @@ class GraphPackCache:
     ``mgk.pack``, stacking and the transfer run under ``mgk.stack``.
     """
 
-    def __init__(self, tile: int = 8, edge_kernel=None,
-                 max_entries: int = 65536, with_grad: bool = False,
+    def __init__(self, tile: int = 8, max_entries: int = 65536,
                  pack_dtype=None):
         import collections
         self.tile = tile
-        self.edge_kernel = edge_kernel
         self.max_entries = max_entries
-        self.with_grad = with_grad   # also bake values_grad companions
         self.pack_dtype = pack_dtype
         self._packs: "collections.OrderedDict" = collections.OrderedDict()
         self._factors: "collections.OrderedDict" = \
@@ -167,8 +163,7 @@ class GraphPackCache:
         }
         # as_numpy: the cache re-pads and stacks host-side; the single
         # device transfer happens in stacked()
-        p = pack_row_panels(oset, edge_kernel=self.edge_kernel,
-                            as_numpy=True, with_grad=self.with_grad,
+        p = pack_row_panels(oset, as_numpy=True,
                             pack_dtype=self.pack_dtype)
         return {f: getattr(p, f) for f in type(p)._fields}
 
@@ -253,8 +248,6 @@ class GraphPackCache:
             k_max = max(e["col"].shape[1] for e in entries)
 
             def stack(field):
-                if entries[0][field] is None:
-                    return None
                 if field == "count":
                     return np.stack([e[field] for e in entries])
                 return np.stack(
@@ -267,8 +260,7 @@ class GraphPackCache:
     def send(pack):
         """A host-stacked pack on the device, field by field."""
         with obs.span("mgk.stack"):
-            return type(pack)(*(None if x is None else obs.to_device(x)
-                                for x in pack))
+            return type(pack)(*(obs.to_device(x) for x in pack))
 
     def stacked(self, indices, batch: GraphBatch, tile: int | None = None):
         """The stacked RowPanelPack for one (padded) pair batch at
@@ -371,7 +363,7 @@ def _axis_structure(rows, cols):
     return urows, ucols
 
 
-def _gram_tile_packs(cache: GraphPackCache, tile: int | None, mxu: bool,
+def _gram_tile_packs(cache: GraphPackCache, tile: int | None,
                      row_axis: tuple, col_axis: tuple) -> tuple | None:
     """Both axes' packs of a Gram-tile block, on the device, at the
     octile edge the block runs at; None where no edge fits VMEM (the
@@ -393,7 +385,7 @@ def _gram_tile_packs(cache: GraphPackCache, tile: int | None, mxu: bool,
             continue
         p1 = cache.stacked_axis(urows, g1u, t)
         p2 = cache.stacked_axis(ucols, g2u, t)
-        if gram_tile_vmem_bytes(p1, p2, mxu) <= _GRAM_TILE_VMEM_BUDGET:
+        if gram_tile_vmem_bytes(p1, p2) <= _GRAM_TILE_VMEM_BUDGET:
             return cache.send(p1), cache.send(p2)
     return None
 
@@ -403,7 +395,6 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                    tol: float = 1e-8, max_iter: int = 256,
                    fixed_iters: int | None = None,
                    pcg_variant: str = "classic",
-                   sparse_mode: str = "auto",
                    tile: int | None = None,
                    gram_tile: bool = False,
                    segment_size: int | None = None,
@@ -438,11 +429,10 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
     the hyperparameter gradients ∂K/∂θ of every pair, computed by the
     adjoint-PCG custom VJP (core/adjoint.py) in the SAME pass (one
     forward + one adjoint solve per block; DESIGN.md §7). On the sparse
-    path the pack cache bakes the ``values_w``/``values_grad`` operand
-    buffers once per graph and both solves trust them
-    (``trust_pack_weights``), so a graph is decomposed-and-weighted once
-    per bucket size for the whole gradient Gram. Gradient steps run
-    host-driven (pair-data-parallel over blocks, no "model" sharding).
+    path both solves read the same cached packs, so a graph is
+    decomposed once per bucket size for the whole gradient Gram.
+    Gradient steps run host-driven (pair-data-parallel over blocks, no
+    "model" sharding).
 
     ``pcg_variant="pipelined"`` halves the per-iteration all-reduce rounds
     when the product rows are sharded over "model" (DESIGN.md §3/§4);
@@ -459,14 +449,8 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
     tensors), served from a :class:`GraphPackCache` keyed by dataset
     index so each graph is decomposed once per bucket size instead of
     once per pair block; the whole bucket then solves in one row-panel
-    kernel launch per CG matvec. ``sparse_mode`` "auto" runs the
-    elementwise contraction, the one measured faster on the chip at
-    every octile edge and rank a workload runs (DESIGN.md §3.4);
-    "mxu" (the low-rank contraction; needs a feature-expandable edge
-    kernel) and "elementwise" are honoured as given. Only an MXU step
-    builds, stacks and sends the weighted operands. Each block solve
-    counts ``xmv.contraction.mxu`` or ``xmv.contraction.elementwise``,
-    and ``xmv.octile_edge.<t>`` by the octile edge it ran at
+    kernel launch per CG matvec. Each block solve counts
+    ``xmv.octile_edge.<t>`` by the octile edge it ran at
     (:mod:`repro.obs`).
     ``tile`` sets the octile edge (buckets must pad to a multiple);
     ``None`` picks it: Gram-tile blocks as below, every other route at
@@ -508,27 +492,16 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 "segment_size is forward-only: the adjoint custom_vjp"
                 " (run_with_grad) solves with lockstep pcg_solve —"
                 " unset segment_size for gradient runs")
-        if sparse_mode not in ("auto", "mxu", "elementwise"):
-            raise ValueError(f"unknown sparse_mode {sparse_mode!r}")
-        if sparse_mode == "mxu" and edge_kernel.feature_rank() is None:
-            raise ValueError(
-                f"sparse_mode='mxu' needs a feature-expandable edge"
-                f" kernel, got {type(edge_kernel).__name__}")
-        mode = "mxu" if sparse_mode == "mxu" else "elementwise"
-        # only an MXU step builds, stacks and sends the weighted operands
         cache = GraphPackCache(
             tile=_DEFAULT_TILE if tile is None else tile,
-            edge_kernel=edge_kernel if mode == "mxu" else None,
-            max_entries=pack_cache_entries, with_grad=with_grad,
-            pack_dtype=pack_dtype)
+            max_entries=pack_cache_entries, pack_dtype=pack_dtype)
 
         kron = precond == "kron"
 
         def _note_block(g1, g2, packs):
-            """Once per block solve: count the contraction and the octile
-            edge it runs, and keep the edge for the scheduler's reads of
-            the pack stats (``GramDriver._block_densities``)."""
-            obs.count(f"xmv.contraction.{mode}")
+            """Once per block solve: count the octile edge it runs, and
+            keep the edge for the scheduler's reads of the pack stats
+            (``GramDriver._block_densities``)."""
             obs.count(f"xmv.octile_edge.{packs.tile}")
             cache.edges[(g1.adjacency.shape[1], g2.adjacency.shape[1])] = \
                 packs.tile
@@ -552,20 +525,16 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 # column graphs are the first Bj entries
                 g1u = jax.tree.map(lambda x: x[::Bj], g1)
                 g2u = jax.tree.map(lambda x: x[:Bj], g2)
-                packs = _gram_tile_packs(cache, tile, mode == "mxu",
-                                         (urows, g1u), (ucols, g2u))
+                packs = _gram_tile_packs(cache, tile, (urows, g1u),
+                                         (ucols, g2u))
                 if packs is not None:
                     facs = (cache.stacked_factors(urows, g1u),
                             cache.stacked_factors(ucols, g2u)) \
                         if kron else (None, None)
                     return (*packs, (Bi, Bj), facs)
             if rows is None or cols is None:
-                p1 = row_panel_packs_for_batch(g1, tile=cache.tile,
-                                               edge_kernel=cache.edge_kernel,
-                                               with_grad=with_grad)
-                p2 = row_panel_packs_for_batch(g2, tile=cache.tile,
-                                               edge_kernel=cache.edge_kernel,
-                                               with_grad=with_grad)
+                p1 = row_panel_packs_for_batch(g1, tile=cache.tile)
+                p2 = row_panel_packs_for_batch(g2, tile=cache.tile)
                 facs = (None, None)   # uncached: factors derived in-trace
             else:
                 p1 = cache.stacked(rows, g1)
@@ -585,8 +554,7 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
                 _note_block(g1, g2, p1)
                 fn = mgk_value_fn(g1, g2, vertex_kernel, edge_kernel,
                                   method="sparse", packs1=p1, packs2=p2,
-                                  sparse_mode=mode,
-                                  trust_pack_weights=True, gram_tile=gt,
+                                  gram_tile=gt,
                                   precond_factors=facs,
                                   **solve_kw, **precond_kw)
                 vals, grads, sol = fn.value_and_pair_grads(theta,
@@ -605,9 +573,8 @@ def gram_pair_step(mesh: Mesh, vertex_kernel: BaseKernel,
         def _solve_args(g1, g2, rows, cols):
             p1, p2, gt, (f1, f2) = _block_packs(g1, g2, rows, cols)
             return ((g1, g2, p1, p2, vertex_kernel, edge_kernel),
-                    dict(sparse_mode=mode, gram_tile=gt,
-                         factors1=f1, factors2=f2, guard=guard,
-                         **precond_kw))
+                    dict(gram_tile=gt, factors1=f1, factors2=f2,
+                         guard=guard, **precond_kw))
 
         def sparse_step(g1: GraphBatch, g2: GraphBatch,
                         rows=None, cols=None, fault=None,
@@ -794,6 +761,11 @@ class GramDriver:
     stats) and observed per-pair CG iteration counts (finished blocks in
     the store) back into the scheduler's cost model.
 
+    ``sparse_mode`` accepts only ``"auto"`` (any other value raises
+    ``ValueError``): the sparse path has one contraction. The field is
+    kept because the benchmark's gram-tile traffic file names it, and
+    goes when a benchmark change drops that key.
+
     SELF-HEALING (module docstring; DESIGN.md §10.2): with ``guard``
     on (default), each block's per-pair PCG status is health-checked and
     an unhealthy block walks :meth:`_ladder` — ``max_block_retries``
@@ -822,7 +794,7 @@ class GramDriver:
     max_iter: int = 256
     fixed_iters: int | None = None
     pcg_variant: str = "classic"
-    sparse_mode: str = "auto"     # pallas_sparse: "auto" | "mxu" | ...
+    sparse_mode: str = "auto"     # only "auto" (class docstring)
     tile: int | None = None       # octile edge; None picks (§3.4)
     pairs_per_block: int = 64
     gram_tile: bool = False       # Gram-tile execution (sparse only)
@@ -844,6 +816,10 @@ class GramDriver:
         self._step_cache: dict = {}   # (with_grad, overrides) -> step
         self._block_failures: dict[int, int] = {}
         self.health: dict = self._fresh_health()
+        if self.sparse_mode != "auto":
+            raise ValueError(
+                f"sparse_mode {self.sparse_mode!r}: the sparse path has"
+                f" one contraction, only 'auto' is accepted")
         if self.gram_tile and self.method != "pallas_sparse":
             raise ValueError(
                 "gram_tile execution needs method='pallas_sparse'")
@@ -992,8 +968,7 @@ class GramDriver:
             cfg = dict(method=self.method, tol=self.tol,
                        max_iter=self.max_iter,
                        fixed_iters=self.fixed_iters,
-                       pcg_variant=self.pcg_variant,
-                       sparse_mode=self.sparse_mode, tile=self.tile,
+                       pcg_variant=self.pcg_variant, tile=self.tile,
                        gram_tile=self.gram_tile,
                        segment_size=self.segment_size,
                        segment_pad=self.segment_pad,

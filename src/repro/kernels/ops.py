@@ -10,41 +10,19 @@ import jax.numpy as jnp
 
 from .flash_attention import flash_attention
 from .ref import attention_ref, xmv_batched_ref, xmv_ref
-from .xmv_block_sparse import RowPanelPack, TilePack, \
-    device_weighted_pack, from_tiles, pack_graph, pack_graph_row_panels, \
-    pack_octiles, pack_row_panels, to_tiles, xmv_block_sparse, \
-    xmv_block_sparse_batched, xmv_gram_tile, xmv_row_panel, \
-    xmv_row_panel_batched
+from .xmv_block_sparse import RowPanelPack, from_tiles, \
+    pack_graph_row_panels, pack_row_panels, to_tiles, xmv_gram_tile, \
+    xmv_row_panel, xmv_row_panel_batched
 from .xmv_dense import dense_row_panels, xmv_dense, xmv_dense_batched
 
 __all__ = [
-    "xmv_dense", "xmv_dense_batched", "xmv_block_sparse",
-    "xmv_block_sparse_batched", "xmv_block_sparse_unrolled", "stack_packs",
-    "pack_graph", "pack_octiles", "TilePack", "RowPanelPack",
-    "pack_row_panels", "pack_graph_row_panels", "xmv_row_panel",
-    "xmv_row_panel_batched", "xmv_gram_tile", "stack_row_panel_packs",
-    "device_weighted_pack", "take_row_panel_pack",
-    "row_panel_packs_for_batch", "flash_attention",
-    "attention_ref", "xmv_ref", "xmv_batched_ref", "to_tiles",
-    "from_tiles", "dense_row_panels",
+    "xmv_dense", "xmv_dense_batched", "RowPanelPack", "pack_row_panels",
+    "pack_graph_row_panels", "xmv_row_panel", "xmv_row_panel_batched",
+    "xmv_gram_tile", "stack_row_panel_packs", "take_row_panel_pack",
+    "row_panel_packs_for_batch", "flash_attention", "attention_ref",
+    "xmv_ref", "xmv_batched_ref", "to_tiles", "from_tiles",
+    "dense_row_panels",
 ]
-
-
-def _stack_field(packs, field):
-    """Stack one optional pack field: all-None -> None, else jnp.stack."""
-    vals = [getattr(p, field) for p in packs]
-    if any(v is None for v in vals):
-        if not all(v is None for v in vals):
-            raise ValueError(
-                f"cannot stack packs mixing {field} and None")
-        return None
-    return jnp.stack(vals)
-
-
-def stack_packs(packs: list[TilePack]) -> TilePack:
-    """Stack per-pair TilePacks (same bucket => same shapes) to [B, ...];
-    optional fields (``values_grad``) must be present in all or none."""
-    return TilePack(*(_stack_field(packs, f) for f in TilePack._fields))
 
 
 def take_row_panel_pack(pack: RowPanelPack, indices) -> RowPanelPack:
@@ -52,19 +30,22 @@ def take_row_panel_pack(pack: RowPanelPack, indices) -> RowPanelPack:
     (``indices`` int array) — the segmented-PCG pair-retirement remap
     and the Gram-tile -> per-pair pack expansion (core/mgk.py)."""
     idx = jnp.asarray(indices)
-    return RowPanelPack(*(None if f is None else jnp.take(f, idx, axis=0)
-                          for f in pack))
+    return RowPanelPack(*(jnp.take(f, idx, axis=0) for f in pack))
 
 
 def stack_row_panel_packs(packs: list[RowPanelPack]) -> RowPanelPack:
     """Stack per-pair RowPanelPacks (same bucket => same shapes) to
-    [B, ...]; optional fields (``values_w``/``values_grad``) must be
-    present in all packs or in none."""
-    return RowPanelPack(*(_stack_field(packs, f)
-                          for f in RowPanelPack._fields))
+    [B, ...]."""
+    return RowPanelPack(*(jnp.stack(f) for f in zip(*packs)))
 
 
-def _bucket_osets(batch, tile: int):
+def row_panel_packs_for_batch(batch, tile: int = 8,
+                              pack_dtype=None) -> RowPanelPack:
+    """Host-side: octile-decompose every graph of a GraphBatch into
+    row-panel packs stacked to shared shapes (slot counts padded to the
+    bucket max). ``pack_dtype=jnp.bfloat16`` streams the value buffers
+    at half the HBM bytes per matvec (f32 in-kernel accumulation,
+    DESIGN.md §9.4)."""
     import numpy as np
     from repro.core.octile import octile_decompose
     n = batch.adjacency.shape[1]
@@ -72,65 +53,14 @@ def _bucket_osets(batch, tile: int):
         raise ValueError(
             f"batch padded to {n}, not a multiple of tile={tile}; pad the"
             f" bucket to a multiple of the tile edge")
-    B = batch.adjacency.shape[0]
-    return [octile_decompose(np.asarray(batch.adjacency[b]),
-                             np.asarray(batch.edge_labels[b]), tile=tile)
-            for b in range(B)]
-
-
-def packs_for_batch(batch, tile: int = 8) -> TilePack:
-    """Host-side: octile-decompose every graph of a GraphBatch and stack
-    the legacy TilePacks to shared shapes (pads tile counts to the bucket
-    max)."""
-    import numpy as np
-    osets = _bucket_osets(batch, tile)
-    K = max(max(o.n_nonempty for o in osets), 1)
-    k_max = max(max((np.bincount(o.coords[:, 0]).max(initial=0)
-                     if o.n_nonempty else 0) for o in osets), 1)
-    return stack_packs([pack_octiles(o.padded(K), k_max=int(k_max))
-                        for o in osets])
-
-
-def row_panel_packs_for_batch(batch, tile: int = 8, edge_kernel=None,
-                              with_grad: bool = False,
-                              pack_dtype=None) -> RowPanelPack:
-    """Host-side: octile-decompose every graph of a GraphBatch into
-    row-panel packs stacked to shared shapes (slot counts padded to the
-    bucket max). Pass ``edge_kernel`` with a feature expansion to also
-    precompute the MXU contraction operands (``values_w``);
-    ``with_grad`` adds the ``values_grad`` adjoint companions.
-    ``pack_dtype=jnp.bfloat16`` streams the value buffers at half the
-    HBM bytes per matvec (f32 in-kernel accumulation, DESIGN.md §9.4)."""
-    import numpy as np
-    osets = _bucket_osets(batch, tile)
+    osets = [octile_decompose(np.asarray(batch.adjacency[b]),
+                              np.asarray(batch.edge_labels[b]), tile=tile)
+             for b in range(batch.adjacency.shape[0])]
     k_max = max(max((np.bincount(o.coords[:, 0]).max(initial=0)
                      if o.n_nonempty else 0) for o in osets), 1)
     return stack_row_panel_packs(
-        [pack_row_panels(o, edge_kernel=edge_kernel, k_max=int(k_max),
-                         with_grad=with_grad, pack_dtype=pack_dtype)
+        [pack_row_panels(o, k_max=int(k_max), pack_dtype=pack_dtype)
          for o in osets])
-
-
-def xmv_block_sparse_unrolled(packs1: TilePack, packs2: TilePack, P,
-                              edge_kernel, *, diag=None, **kw):
-    """Legacy loop-of-launches batched block-sparse XMV: one ``pallas_call``
-    (and one jit dispatch) per pair. Superseded by the batched-grid
-    :func:`~repro.kernels.xmv_block_sparse.xmv_block_sparse_batched`
-    (one launch for the whole bucket); kept as the baseline arm of the
-    BENCH_xmv comparison and the parity tests."""
-    B = P.shape[0]
-
-    def take(pack, b):
-        return TilePack(*(None if arr is None else arr[b] for arr in pack))
-
-    ys = [
-        xmv_block_sparse(
-            take(packs1, b), take(packs2, b),
-            P[b], edge_kernel,
-            diag=None if diag is None else diag[b], **kw)
-        for b in range(B)
-    ]
-    return jnp.stack(ys)
 
 
 def attention_chunked(q, k, v, *, causal: bool = True,

@@ -48,8 +48,7 @@ def dense_row_panels(A, E, tile: int = DENSE_TILE) -> RowPanelPack:
     col = jnp.broadcast_to(jnp.arange(nt, dtype=jnp.int32), lead + (nt, nt))
     count = jnp.full(lead + (nt,), nt, jnp.int32)
     return RowPanelPack(values_adj=values_adj,
-                        values_lab=to_tiles(E, tile), values_w=None,
-                        col=col, count=count)
+                        values_lab=to_tiles(E, tile), col=col, count=count)
 
 
 @functools.partial(jax.jit,
@@ -70,7 +69,7 @@ def xmv_dense(A, E, Ap, Ep, P, edge_kernel, *, diag=None, interpret=None,
     t = P.shape[-1]
     return xmv_row_panel(dense_row_panels(A, E, t),
                          dense_row_panels(Ap, Ep, t), P, edge_kernel,
-                         diag=diag, mode="elementwise", interpret=interpret,
+                         diag=diag, interpret=interpret,
                          acc_dtype=acc_dtype, theta=theta)
 
 
@@ -86,5 +85,5 @@ def xmv_dense_batched(A, E, Ap, Ep, P, edge_kernel, *, diag=None,
     t = P.shape[-1]
     return xmv_row_panel_batched(
         dense_row_panels(A, E, t), dense_row_panels(Ap, Ep, t), P,
-        edge_kernel, diag=diag, mode="elementwise", interpret=interpret,
-        acc_dtype=acc_dtype, theta=theta)
+        edge_kernel, diag=diag, interpret=interpret, acc_dtype=acc_dtype,
+        theta=theta)

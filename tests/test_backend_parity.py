@@ -1,9 +1,9 @@
 """Cross-backend parity: every XMV backend — dense Pallas, block-sparse
-(legacy per-pair loop AND batched grid), elementwise, lowrank — must apply
-the same operator on random masked batches; classic and pipelined PCG must
-produce the same iterates; and the batched block-sparse bucket matvec must
-be exactly ONE pallas_call (the tentpole claim of PR 1, checked on the
-jaxpr)."""
+(per-pair row-panel loop AND the batched grid), elementwise, lowrank —
+must apply the same operator on random masked batches; classic and
+pipelined PCG must produce the same iterates; and the batched
+block-sparse bucket matvec must be exactly ONE pallas_call (checked on
+the jaxpr)."""
 import numpy as np
 import pytest
 import jax
@@ -15,9 +15,10 @@ from repro.core.mgk import build_product_system, mgk_pairs, mgk_pairs_sparse
 from repro.core.pcg import pcg_solve
 from repro.core.xmv import xmv_elementwise, xmv_full, xmv_lowrank
 from repro.data import make_drugbank_like_dataset
-from repro.kernels.ops import packs_for_batch, xmv_block_sparse_unrolled
-from repro.kernels.xmv_block_sparse import xmv_block_sparse_batched
-from repro.kernels.xmv_block_sparse import from_tiles, to_tiles
+from repro.kernels.ops import row_panel_packs_for_batch, \
+    take_row_panel_pack
+from repro.kernels.xmv_block_sparse import from_tiles, to_tiles, \
+    xmv_row_panel, xmv_row_panel_batched
 from repro.kernels.xmv_dense import DENSE_TILE
 from repro.kernels.xmv_dense import xmv_dense_batched as _xmv_dense_batched
 
@@ -34,6 +35,25 @@ def xmv_dense_batched(A, E, Ap, Ep, P, ek, diag=None):
         diag=None if diag is None else to_tiles(diag, t)))
 
 
+def xmv_sparse_batched(p1, p2, P, ek, diag=None):
+    """Node-major view of the whole-bucket row-panel kernel."""
+    t = p1.tile
+    return from_tiles(xmv_row_panel_batched(
+        p1, p2, to_tiles(P, t), ek,
+        diag=None if diag is None else to_tiles(diag, t)))
+
+
+def xmv_sparse_per_pair(p1, p2, P, ek):
+    """The same bucket as one per-pair ``xmv_row_panel`` launch per
+    pair, node-major."""
+    t = p1.tile
+    return jnp.stack([
+        from_tiles(xmv_row_panel(take_row_panel_pack(p1, b),
+                                 take_row_panel_pack(p2, b),
+                                 to_tiles(P[b], t), ek))
+        for b in range(P.shape[0])])
+
+
 @pytest.fixture(scope="module")
 def masked_batch():
     """Two aligned batches of real-ish sparse graphs + their tile packs."""
@@ -42,7 +62,8 @@ def masked_batch():
     assert len(gs) == 8
     g1 = batch_from_graphs(gs[:4], pad_to=48)
     g2 = batch_from_graphs(gs[4:], pad_to=48)
-    return g1, g2, packs_for_batch(g1), packs_for_batch(g2)
+    return g1, g2, row_panel_packs_for_batch(g1), \
+        row_panel_packs_for_batch(g2)
 
 
 def _random_p(g1, g2, seed=0):
@@ -53,7 +74,7 @@ def _random_p(g1, g2, seed=0):
 
 
 def test_all_backends_agree(masked_batch):
-    """dense pallas / block-sparse (old loop + new batched grid) /
+    """dense pallas / block-sparse (per-pair loop + batched grid) /
     elementwise / lowrank vs the full-materialization oracle."""
     g1, g2, p1, p2 = masked_batch
     P = _random_p(g1, g2)
@@ -66,13 +87,13 @@ def test_all_backends_agree(masked_batch):
     y_lr = jax.vmap(
         lambda a, e, ap, ep, p: xmv_lowrank(a, e, ap, ep, p, EK))(*args)
     y_dense = xmv_dense_batched(*args, EK)
-    y_sp_old = xmv_block_sparse_unrolled(p1, p2, P, EK)
-    y_sp_new = xmv_block_sparse_batched(p1, p2, P, EK)
+    y_sp_pair = xmv_sparse_per_pair(p1, p2, P, EK)
+    y_sp_batched = xmv_sparse_batched(p1, p2, P, EK)
 
     ref = np.asarray(y_full)
     for name, y in [("elementwise", y_elem), ("lowrank", y_lr),
-                    ("pallas_dense", y_dense), ("sparse_unrolled", y_sp_old),
-                    ("sparse_batched", y_sp_new)]:
+                    ("pallas_dense", y_dense), ("sparse_per_pair", y_sp_pair),
+                    ("sparse_batched", y_sp_batched)]:
         np.testing.assert_allclose(np.asarray(y), ref, err_msg=name, **TOL)
 
 
@@ -101,16 +122,16 @@ def _count_primitive(jaxpr, name):
 
 
 def test_batched_sparse_is_single_launch(masked_batch):
-    """The tentpole: one bucket matvec == ONE pallas_call, however many
-    pairs are in the bucket (vs B calls in the legacy loop)."""
+    """One bucket matvec == ONE pallas_call, however many pairs are in
+    the bucket (vs B calls in a per-pair loop)."""
     g1, g2, p1, p2 = masked_batch
     P = _random_p(g1, g2)
 
     def batched(P):
-        return xmv_block_sparse_batched(p1, p2, P, EK)
+        return xmv_sparse_batched(p1, p2, P, EK)
 
     def unrolled(P):
-        return xmv_block_sparse_unrolled(p1, p2, P, EK)
+        return xmv_sparse_per_pair(p1, p2, P, EK)
 
     B = P.shape[0]
     assert B >= 4
@@ -131,9 +152,9 @@ def test_fused_epilogue_matches_unfused(masked_batch):
     diag = jnp.asarray(
         rng.random(P.shape).astype(np.float32) + 1.0)
 
-    y_sp = xmv_block_sparse_batched(p1, p2, P, EK)
+    y_sp = xmv_sparse_batched(p1, p2, P, EK)
     ref_sp = np.asarray(diag) * np.asarray(P) - np.asarray(y_sp)
-    fused_sp = xmv_block_sparse_batched(p1, p2, P, EK, diag=diag)
+    fused_sp = xmv_sparse_batched(p1, p2, P, EK, diag=diag)
     np.testing.assert_allclose(np.asarray(fused_sp), ref_sp, **TOL)
 
     args = (g1.adjacency, g1.edge_labels, g2.adjacency, g2.edge_labels, P)

@@ -160,46 +160,61 @@ def test_sparse_step_caches_packs_per_graph(monkeypatch):
 
 
 def test_sparse_step_domain_guard_falls_back_to_elementwise():
-    """sparse_mode='auto' must not use the Taylor expansion outside its
-    accuracy domain: the block runs exact elementwise, as "auto" does
-    for every block, and matches the elementwise reference."""
+    """The sparse step must not use the Taylor expansion outside its
+    accuracy domain: the block runs exact elementwise, as the sparse
+    step does for every block, and matches the elementwise reference."""
     from repro.distributed.gram import gram_pair_step, solve_pair_block
     ds = _dataset(6)
     blocks = list(pair_blocks(ds, pairs_per_block=6))
     ek = SquareExponential(1.0, rank=10, domain=0.0)   # always out of domain
     step = gram_pair_step(_mesh(), VK, ek, method="pallas_sparse")
-    before = obs.counters()
     out = solve_pair_block(ds, blocks[0], step, 1)
-    assert obs.delta(before).get("xmv.contraction.elementwise") == 1
-    assert step.pack_cache.edge_kernel is None
     ref_step = gram_pair_step(_mesh(), VK, ek, method="elementwise")
     ref = solve_pair_block(ds, blocks[0], ref_step, 1)
     np.testing.assert_allclose(out["values"], ref["values"], rtol=1e-4)
 
 
-@pytest.mark.parametrize("mode", ["auto", "mxu"])
-def test_sparse_driver_contraction_and_counters(mode):
-    """Under "auto" the Gram-tile driver runs the elementwise
-    contraction: the pack cache holds no weighted operands and every block solve counts
-    ``xmv.contraction.elementwise``. An explicit "mxu" builds the weights
-    and counts ``xmv.contraction.mxu``. Either way the Gram matches the
-    direct dense solve."""
+@pytest.mark.parametrize("pack_dtype", [None, "bfloat16"],
+                         ids=["float32", "bfloat16"])
+def test_sparse_driver_contraction_and_counters(pack_dtype):
+    """The Gram-tile driver runs the one elementwise contraction: the
+    pack cache holds adjacency and label tiles only, at the stored
+    dtype, and every block solve counts the octile edge it ran at (no
+    contraction counter). The Gram matches the direct dense solve, at
+    bf16 resolution for bf16 packs (the benchmark's ``bf16-packs``
+    control)."""
+    import jax.numpy as jnp
     from repro.core.reference import mgk_direct
     ds = _dataset(5)
     drv = GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
-                     gram_tile=True, tile_shape=(2, 2), sparse_mode=mode,
-                     normalize=False, tol=1e-10)
+                     gram_tile=True, tile_shape=(2, 2),
+                     pack_dtype=pack_dtype, normalize=False, tol=1e-10)
     K = drv.run()
-    want = "elementwise" if mode == "auto" else "mxu"
-    contractions = {k: v for k, v in drv.health["counters"].items()
-                    if k.startswith("xmv.contraction.")}
-    assert contractions == {f"xmv.contraction.{want}": len(drv.blocks())}
+    counters = drv.health["counters"]
+    assert not [k for k in counters if k.startswith("xmv.contraction.")]
+    assert sum(v for k, v in counters.items()
+               if k.startswith("xmv.octile_edge.")) == len(drv.blocks())
     packs = list(drv._pack_cache._packs.values())
-    assert packs and all((p["values_w"] is None) == (mode == "auto")
-                         for p in packs)
+    want = jnp.bfloat16 if pack_dtype else np.float32
+    assert packs and all(
+        set(p) == {"values_adj", "values_lab", "col", "count"}
+        and p["values_adj"].dtype == want for p in packs)
+    rel = 3e-2 if pack_dtype else 1e-4
     for i, j in [(0, 0), (0, 3), (2, 4)]:
         ref = mgk_direct(ds.graphs[i], ds.graphs[j], VK, EK)
-        assert K[i, j] == pytest.approx(ref, rel=1e-4)
+        assert K[i, j] == pytest.approx(ref, rel=rel)
+
+
+@pytest.mark.parametrize("sparse_mode", ["mxu", "elementwise", "vpu"])
+def test_sparse_mode_accepts_only_auto(sparse_mode):
+    """``GramDriver.sparse_mode`` is kept for the benchmark's traffic
+    file, which passes "auto"; any other value is refused."""
+    ds = _dataset(4)
+    GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
+               gram_tile=True, sparse_mode="auto")
+    with pytest.raises(ValueError, match="sparse_mode"):
+        GramDriver(ds, _mesh(), VK, EK, method="pallas_sparse",
+                   gram_tile=True, sparse_mode=sparse_mode)
 
 
 def test_pack_cache_lru_eviction_roundtrip():
@@ -213,7 +228,7 @@ def test_pack_cache_lru_eviction_roundtrip():
     batch = batch_from_graphs(gs, pad_to=32)
     one = lambda b: jax.tree.map(lambda x: x[b:b + 1], batch)  # noqa
 
-    cache = GraphPackCache(tile=8, edge_kernel=EK, max_entries=2)
+    cache = GraphPackCache(tile=8, max_entries=2)
     first = cache.stacked(np.array([0]), one(0))
     for b in (1, 2, 3):          # push graph 0 out of the LRU window
         cache.stacked(np.array([b]), one(b))
@@ -224,10 +239,7 @@ def test_pack_cache_lru_eviction_roundtrip():
     again = cache.stacked(np.array([0]), one(0))
     assert obs.delta(before).get("pack_cache.miss") == 1   # re-packed
     for a, b in zip(first, again):
-        if a is None:
-            assert b is None
-        else:
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_plan_uses_measured_density_and_iterations(tmp_path):
@@ -299,14 +311,14 @@ def test_gram_tile_octile_edge(pads, tile, want):
     from repro.kernels.xmv_block_sparse import gram_tile_vmem_bytes
     cache = GraphPackCache(tile=8 if tile is None else tile)
     rows, cols = _nws_axis(pads[0], [0, 1]), _nws_axis(pads[1], [2, 3])
-    packs = _gram_tile_packs(cache, tile, False, rows, cols)
+    packs = _gram_tile_packs(cache, tile, rows, cols)
     if want is None:
         assert packs is None and cache.tile == 8
         return
     p1, p2 = packs
     assert (p1.tile, p2.tile) == (want, want)
     assert (p1.n_tile_rows * want, p2.n_tile_rows * want) == pads
-    assert gram_tile_vmem_bytes(p1, p2, False) <= _GRAM_TILE_VMEM_BUDGET
+    assert gram_tile_vmem_bytes(p1, p2) <= _GRAM_TILE_VMEM_BUDGET
     # the stats of every edge tried are kept under that edge
     for t in (32, 16, 8):
         tried = (tile is None and t >= want and not pads[0] % t
@@ -455,16 +467,20 @@ def test_gram_driver_kron_precond_matches_jacobi():
     assert entry["values_adj"].dtype == jnp.bfloat16
 
 
-@pytest.mark.parametrize("mode", ["auto", "mxu"])
-def test_gram_driver_kron_grad_matches_jacobi(mode):
+@pytest.mark.parametrize("edge_kernel", ["square-exponential",
+                                         "compact-polynomial"])
+def test_gram_driver_kron_grad_matches_jacobi(edge_kernel):
     """run_with_grad under precond='kron' (adjoint reuses the cached
-    factors via precond_factors/trust_pack_weights) matches Jacobi's
-    gradient Gram blocks, in the contraction "auto" picks and in the
-    MXU one."""
+    factors via precond_factors) matches Jacobi's gradient Gram blocks,
+    for an expandable edge kernel and one without a feature
+    expansion."""
+    from repro.core import CompactPolynomial
+    ek = EK if edge_kernel == "square-exponential" \
+        else CompactPolynomial(0.8)
     ds = _dataset(5)
     mesh = _mesh()
-    base = dict(ds=ds, mesh=mesh, vertex_kernel=VK, edge_kernel=EK,
-                method="pallas_sparse", tol=1e-10, sparse_mode=mode)
+    base = dict(ds=ds, mesh=mesh, vertex_kernel=VK, edge_kernel=ek,
+                method="pallas_sparse", tol=1e-10)
     Kj, Gj = GramDriver(**base).run_with_grad()
     Kk, Gk = GramDriver(**base, precond="kron",
                         gram_tile=True,
@@ -488,17 +504,13 @@ def test_gram_tile_vmem_bytes_tracks_pack_dtype():
           if 6 <= g.n_nodes <= 24][:4]
     g1 = batch_from_graphs(gs[:2], pad_to=24)
     g2 = batch_from_graphs(gs[2:], pad_to=24)
-    pf1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-    pf2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
-    pb1 = row_panel_packs_for_batch(g1, edge_kernel=EK,
-                                    pack_dtype=jnp.bfloat16)
-    pb2 = row_panel_packs_for_batch(g2, edge_kernel=EK,
-                                    pack_dtype=jnp.bfloat16)
-    for mxu in (False, True):
-        f32 = gram_tile_vmem_bytes(pf1, pf2, mxu)
-        bf16 = gram_tile_vmem_bytes(pb1, pb2, mxu)
-        assert bf16 < f32
-        # operand share halves exactly; the f32 share (the lane-
-        # replicated P panel, t x the pair's n*m, plus diag/out strips)
-        # stays
-        assert f32 - bf16 == (f32 - 8 * (24 * 24 * 8 + 2 * 8 * 24)) // 2
+    pf1 = row_panel_packs_for_batch(g1)
+    pf2 = row_panel_packs_for_batch(g2)
+    pb1 = row_panel_packs_for_batch(g1, pack_dtype=jnp.bfloat16)
+    pb2 = row_panel_packs_for_batch(g2, pack_dtype=jnp.bfloat16)
+    f32 = gram_tile_vmem_bytes(pf1, pf2)
+    bf16 = gram_tile_vmem_bytes(pb1, pb2)
+    assert bf16 < f32
+    # operand share halves exactly; the f32 share (the lane-replicated
+    # P panel, t x the pair's n*m, plus diag/out strips) stays
+    assert f32 - bf16 == (f32 - 8 * (24 * 24 * 8 + 2 * 8 * 24)) // 2

@@ -1,7 +1,7 @@
 """Gradcheck: the adjoint-PCG custom VJP vs central finite differences,
 for EVERY dispatch path of the mgk_adaptive table (DESIGN.md §3.4/§7) —
 dense tiling&blocking (pallas), dense low-rank MXU, sparse row-panel
-VPU, sparse row-panel MXU — plus the jnp reference backends, over
+per pair and as a Gram tile — plus the jnp reference backends, over
 vertex-kernel params, edge-kernel params, and the stopping probability
 ``q``. Also pins the cost contract: the gradient jaxpr contains exactly
 TWO PCG solves (forward + adjoint)."""
@@ -42,6 +42,21 @@ def _sparse_batches():
             batch_from_graphs(gs[2:], pad_to=32))
 
 
+def _gram_tile_value_fn(ek, **kw):
+    """``mgk_value_fn`` over ``_sparse_batches`` as one 2 x 2 Gram tile:
+    per-axis packs at t = 16 and the row-major pair-flattened batches,
+    as the Gram driver's gradient step builds them."""
+    g1u, g2u = _sparse_batches()
+    Bi, Bj = g1u.adjacency.shape[0], g2u.adjacency.shape[0]
+    g1f = jax.tree.map(lambda x: jnp.repeat(x, Bj, axis=0), g1u)
+    g2f = jax.tree.map(lambda x: jnp.tile(x, (Bi,) + (1,) * (x.ndim - 1)),
+                       g2u)
+    return mgk_value_fn(g1f, g2f, VK, ek, method="sparse",
+                        packs1=row_panel_packs_for_batch(g1u, tile=16),
+                        packs2=row_panel_packs_for_batch(g2u, tile=16),
+                        gram_tile=(Bi, Bj), **kw)
+
+
 def gradcheck(fn, theta, h0=3e-3, rtol=RTOL, atol=ATOL):
     """Central finite differences of fn(theta).sum() vs jax.grad through
     the custom VJP, leaf by leaf."""
@@ -80,18 +95,19 @@ def test_dense_paths_match_fd(method, ek):
 
 # -- sparse dispatch paths -------------------------------------------------
 
-@pytest.mark.parametrize("mode,ek", [
-    ("elementwise", CP),      # adaptive: sparse, no expansion (VPU)
-    ("elementwise", SE),
-    ("mxu", SE),              # adaptive: sparse + expansion (MXU)
-], ids=["vpu-cp", "vpu-se", "mxu-se"])
-def test_sparse_paths_match_fd(mode, ek):
-    g1, g2 = _sparse_batches()
-    ek_pack = ek if mode == "mxu" else None
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=ek_pack)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=ek_pack)
-    fn = mgk_value_fn(g1, g2, VK, ek, method="sparse", packs1=p1,
-                      packs2=p2, sparse_mode=mode, tol=1e-12)
+@pytest.mark.parametrize("execution,ek", [
+    ("per-pair", CP),         # adaptive: sparse, no expansion
+    ("per-pair", SE),
+    ("gram-tile", SE),        # the Gram driver's step at t = 16
+], ids=["vpu-cp", "vpu-se", "gram-tile-se"])
+def test_sparse_paths_match_fd(execution, ek):
+    if execution == "gram-tile":
+        fn = _gram_tile_value_fn(ek, tol=1e-12)
+    else:
+        g1, g2 = _sparse_batches()
+        fn = mgk_value_fn(g1, g2, VK, ek, method="sparse",
+                          packs1=row_panel_packs_for_batch(g1),
+                          packs2=row_panel_packs_for_batch(g2), tol=1e-12)
     gradcheck(fn, kernel_theta(VK, ek, q=0.05))
 
 
@@ -138,7 +154,7 @@ def test_per_pair_grads_sum_to_vjp():
 # adjoint solve share the identical SPD M^{-1} closure.
 
 @pytest.mark.parametrize("route", ["lowrank", "pallas", "sparse-vpu",
-                                   "sparse-mxu"])
+                                   "sparse-gram-tile"])
 def test_kron_precond_paths_match_fd(route):
     if route in ("lowrank", "pallas"):
         g1, g2 = _dense_batches()
@@ -147,16 +163,16 @@ def test_kron_precond_paths_match_fd(route):
                           precond="kron")
         gradcheck(fn, kernel_theta(VK, ek, q=0.2))
         return
+    if route == "sparse-gram-tile":
+        fn = _gram_tile_value_fn(SE, tol=1e-12, precond="kron")
+        gradcheck(fn, kernel_theta(VK, SE, q=0.05))
+        return
     g1, g2 = _sparse_batches()
-    mode = "mxu" if route == "sparse-mxu" else "elementwise"
-    ek = SE if mode == "mxu" else CP
-    ek_pack = ek if mode == "mxu" else None
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=ek_pack)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=ek_pack)
-    fn = mgk_value_fn(g1, g2, VK, ek, method="sparse", packs1=p1,
-                      packs2=p2, sparse_mode=mode, tol=1e-12,
+    fn = mgk_value_fn(g1, g2, VK, CP, method="sparse",
+                      packs1=row_panel_packs_for_batch(g1),
+                      packs2=row_panel_packs_for_batch(g2), tol=1e-12,
                       precond="kron")
-    gradcheck(fn, kernel_theta(VK, ek, q=0.05))
+    gradcheck(fn, kernel_theta(VK, CP, q=0.05))
 
 
 def test_kron_adaptive_entry_matches_jacobi_grads():
@@ -200,14 +216,8 @@ def _count_pcg_solves(jaxpr, acc=0):
              kernel_theta(VK, SE, q=0.2)),
     lambda: (mgk_value_fn(*_dense_batches(), VK, CP, method="pallas"),
              kernel_theta(VK, CP, q=0.2)),
-    lambda: (mgk_value_fn(
-        *_sparse_batches(), VK, SE, method="sparse",
-        packs1=row_panel_packs_for_batch(_sparse_batches()[0],
-                                         edge_kernel=SE),
-        packs2=row_panel_packs_for_batch(_sparse_batches()[1],
-                                         edge_kernel=SE),
-        sparse_mode="mxu"), kernel_theta(VK, SE, q=0.05)),
-], ids=["lowrank", "pallas", "sparse-mxu"])
+    lambda: (_gram_tile_value_fn(SE), kernel_theta(VK, SE, q=0.05)),
+], ids=["lowrank", "pallas", "sparse-gram-tile"])
 def test_exactly_two_pcg_solves_in_grad_jaxpr(make):
     fn, theta = make()
     jaxpr = jax.make_jaxpr(jax.grad(lambda t: fn(t).sum()))(theta)
@@ -219,11 +229,10 @@ def test_exactly_two_pcg_solves_with_kron_precond():
     still contains exactly two while-loop PCG solves (the M^{-1}
     applications live INSIDE the loop bodies)."""
     g1, g2 = _sparse_batches()
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=SE)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=SE)
+    p1 = row_panel_packs_for_batch(g1)
+    p2 = row_panel_packs_for_batch(g2)
     for spec in (dict(method="lowrank"),
-                 dict(method="sparse", packs1=p1, packs2=p2,
-                      sparse_mode="mxu")):
+                 dict(method="sparse", packs1=p1, packs2=p2)):
         gd, gs = _dense_batches() if spec["method"] == "lowrank" \
             else (g1, g2)
         fn = mgk_value_fn(gd, gs, VK, SE, precond="kron", **spec)

@@ -2,7 +2,8 @@
 ``xmv_gram_tile`` kernel (per-axis packs, (Bi, nt, Bj) grid) against
 ``mgk_direct``/``xmv_gram_full`` AND the per-pair row-panel kernel,
 covering ragged Bi != Bj tiles, ragged n != m pads, zero-octile rows,
-both contraction modes, the fused epilogue, and the single-launch jaxpr;
+every octile edge and edge kernel (static and traced hyperparameters),
+the fused epilogue, and the single-launch jaxpr;
 plus convergence-segmented PCG pinned iterate-for-iterate against masked
 lockstep with strictly fewer pair-matvec evaluations."""
 import numpy as np
@@ -10,7 +11,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.base_kernels import KroneckerDelta, SquareExponential
+from repro.core.base_kernels import CompactPolynomial, Constant, \
+    KroneckerDelta, ParamDerivative, SquareExponential, pack_theta
 from repro.core.graph import batch_from_graphs
 from repro.core.mgk import mgk_pairs_sparse, mgk_pairs_sparse_segmented
 from repro.core.pcg import pcg_solve, pcg_solve_segmented
@@ -56,14 +58,13 @@ def _sparse_pair(rng, n, density=0.08, dead_band=None):
     return a, e
 
 
-def _axis_packs(graphs, edge_kernel=None):
+def _axis_packs(graphs, tile=8):
     """Stack per-graph row-panel packs at the axis-shared k_max."""
-    loose = [pack_graph_row_panels(a, e, edge_kernel=edge_kernel)
-             for a, e in graphs]
+    loose = [pack_graph_row_panels(a, e, tile=tile) for a, e in graphs]
     k_max = max(p.k_max for p in loose)
     return stack_row_panel_packs(
-        [pack_graph_row_panels(a, e, edge_kernel=edge_kernel,
-                               k_max=k_max) for a, e in graphs])
+        [pack_graph_row_panels(a, e, tile=tile, k_max=k_max)
+         for a, e in graphs])
 
 
 def _stack(graphs, which):
@@ -72,9 +73,9 @@ def _stack(graphs, which):
 
 @pytest.mark.parametrize("Bi,Bj,n,m", [(3, 5, 32, 48), (4, 2, 40, 40)])
 def test_gram_tile_matches_oracle_ragged(rng, Bi, Bj, n, m):
-    """Ragged Bi != Bj and n != m cross tiles, both modes, vs the
-    doubly-vmapped full-materialization oracle; graph 0 carries
-    zero-octile tile-row bands (count = 0 rows)."""
+    """Ragged Bi != Bj and n != m cross tiles vs the doubly-vmapped
+    full-materialization oracle; graph 0 carries zero-octile tile-row
+    bands (count = 0 rows)."""
     rows = [_sparse_pair(rng, n, dead_band=(8, 16) if i == 0 else None)
             for i in range(Bi)]
     cols = [_sparse_pair(rng, m, dead_band=(0, 8) if j == 1 else None)
@@ -83,14 +84,57 @@ def test_gram_tile_matches_oracle_ragged(rng, Bi, Bj, n, m):
     ref = np.asarray(xmv_gram_full(_stack(rows, 0), _stack(rows, 1),
                                    _stack(cols, 0), _stack(cols, 1),
                                    P, EK))
-    for mode, ek in (("elementwise", None), ("mxu", EK)):
-        p1 = _axis_packs(rows, ek)
-        p2 = _axis_packs(cols, ek)
-        if mode == "elementwise":
-            assert int(np.asarray(p1.count).min()) == 0  # truly empty
-        y = xmv_gram_tile(p1, p2, P, EK, mode=mode)
-        np.testing.assert_allclose(np.asarray(y), ref, err_msg=mode,
-                                   **TOL)
+    p1 = _axis_packs(rows)
+    p2 = _axis_packs(cols)
+    assert int(np.asarray(p1.count).min()) == 0  # truly empty
+    y = xmv_gram_tile(p1, p2, P, EK)
+    np.testing.assert_allclose(np.asarray(y), ref, **TOL)
+
+
+GRAM_TILE_EDGE_KERNELS = {
+    "constant": Constant(1.0),
+    "square-exponential": EK,
+    "compact-polynomial": CompactPolynomial(0.5),
+}
+
+
+@pytest.mark.parametrize("ek_name", list(GRAM_TILE_EDGE_KERNELS))
+@pytest.mark.parametrize("tile", [8, 16, 32])
+def test_gram_tile_matches_oracle_at_every_edge(rng, tile, ek_name):
+    """The Gram-tile kernel vs ``xmv_gram_full`` at each octile edge the
+    step can pick, for an expandable, a constant and a non-expandable
+    edge kernel (the body regenerates kappa elementwise for all)."""
+    ek = GRAM_TILE_EDGE_KERNELS[ek_name]
+    Bi, Bj, n, m = 2, 3, 64, 32
+    rows = [_sparse_pair(rng, n) for _ in range(Bi)]
+    cols = [_sparse_pair(rng, m) for _ in range(Bj)]
+    P = jnp.asarray(rng.random((Bi, Bj, n, m)).astype(np.float32))
+    ref = np.asarray(xmv_gram_full(_stack(rows, 0), _stack(rows, 1),
+                                   _stack(cols, 0), _stack(cols, 1),
+                                   P, ek))
+    y = xmv_gram_tile(_axis_packs(rows, tile), _axis_packs(cols, tile),
+                      P, ek)
+    np.testing.assert_allclose(np.asarray(y), ref, **TOL)
+
+
+@pytest.mark.parametrize("tile", [8, 16, 32])
+def test_gram_tile_traced_theta_matches_oracle(rng, tile):
+    """The adjoint's call: ``ParamDerivative`` as the edge kernel and
+    its hyperparameters as a traced ``theta`` vector (away from the
+    kernel's static value) vs the oracle at the same theta."""
+    dk = ParamDerivative(EK, "alpha")
+    theta = {"alpha": 0.6}
+    Bi, Bj, n = 2, 2, 64
+    rows = [_sparse_pair(rng, n) for _ in range(Bi)]
+    cols = [_sparse_pair(rng, n) for _ in range(Bj)]
+    P = jnp.asarray(rng.random((Bi, Bj, n, n)).astype(np.float32))
+    ref = np.asarray(xmv_gram_full(_stack(rows, 0), _stack(rows, 1),
+                                   _stack(cols, 0), _stack(cols, 1),
+                                   P, dk, theta=theta))
+    p1, p2 = _axis_packs(rows, tile), _axis_packs(cols, tile)
+    y = jax.jit(lambda tv: xmv_gram_tile(p1, p2, P, dk, theta=tv))(
+        pack_theta(dk, theta))
+    np.testing.assert_allclose(np.asarray(y), ref, **TOL)
 
 
 def test_gram_tile_matches_per_pair_kernel(rng):
@@ -103,15 +147,12 @@ def test_gram_tile_matches_per_pair_kernel(rng):
     P = jnp.asarray(rng.random((Bi, Bj, n, n)).astype(np.float32))
     flat_rows = [rows[b // Bj] for b in range(Bi * Bj)]
     flat_cols = [cols[b % Bj] for b in range(Bi * Bj)]
-    for mode, ek in (("elementwise", None), ("mxu", EK)):
-        y = xmv_gram_tile(_axis_packs(rows, ek), _axis_packs(cols, ek),
-                          P, EK, mode=mode)
-        yp = xmv_row_panel_batched(_axis_packs(flat_rows, ek),
-                                   _axis_packs(flat_cols, ek),
-                                   P.reshape(Bi * Bj, n, n), EK,
-                                   mode=mode)
-        np.testing.assert_allclose(np.asarray(y).reshape(Bi * Bj, n, n),
-                                   np.asarray(yp), err_msg=mode, **TOL)
+    y = xmv_gram_tile(_axis_packs(rows), _axis_packs(cols), P, EK)
+    yp = xmv_row_panel_batched(_axis_packs(flat_rows),
+                               _axis_packs(flat_cols),
+                               P.reshape(Bi * Bj, n, n), EK)
+    np.testing.assert_allclose(np.asarray(y).reshape(Bi * Bj, n, n),
+                               np.asarray(yp), **TOL)
 
 
 def test_gram_tile_fused_epilogue(rng):
@@ -120,13 +161,11 @@ def test_gram_tile_fused_epilogue(rng):
     cols = [_sparse_pair(rng, n) for _ in range(Bj)]
     P = jnp.asarray(rng.random((Bi, Bj, n, n)).astype(np.float32))
     diag = jnp.asarray(rng.random(P.shape).astype(np.float32) + 1.0)
-    for mode, ek in (("elementwise", None), ("mxu", EK)):
-        p1, p2 = _axis_packs(rows, ek), _axis_packs(cols, ek)
-        y = xmv_gram_tile(p1, p2, P, EK, mode=mode)
-        fused = xmv_gram_tile(p1, p2, P, EK, diag=diag, mode=mode)
-        ref = np.asarray(diag) * np.asarray(P) - np.asarray(y)
-        np.testing.assert_allclose(np.asarray(fused), ref, err_msg=mode,
-                                   **TOL)
+    p1, p2 = _axis_packs(rows), _axis_packs(cols)
+    y = xmv_gram_tile(p1, p2, P, EK)
+    fused = xmv_gram_tile(p1, p2, P, EK, diag=diag)
+    ref = np.asarray(diag) * np.asarray(P) - np.asarray(y)
+    np.testing.assert_allclose(np.asarray(fused), ref, **TOL)
 
 
 def _count_primitive(jaxpr, name):
@@ -149,13 +188,12 @@ def test_gram_tile_is_single_launch(rng):
     rows = [_sparse_pair(rng, n) for _ in range(Bi)]
     cols = [_sparse_pair(rng, n) for _ in range(Bj)]
     P = jnp.asarray(rng.random((Bi, Bj, n, n)).astype(np.float32))
-    for mode, ek in (("elementwise", None), ("mxu", EK)):
-        p1, p2 = _axis_packs(rows, ek), _axis_packs(cols, ek)
-        n_calls = _count_primitive(
-            jax.make_jaxpr(
-                lambda P: _xmv_gram_tile(p1, p2, P, EK, mode=mode)
-            )(to_tiles(P, p1.tile)).jaxpr, "pallas_call")
-        assert n_calls == 1, f"{mode}: traced {n_calls} pallas_calls"
+    p1, p2 = _axis_packs(rows), _axis_packs(cols)
+    n_calls = _count_primitive(
+        jax.make_jaxpr(
+            lambda P: _xmv_gram_tile(p1, p2, P, EK)
+        )(to_tiles(P, p1.tile)).jaxpr, "pallas_call")
+    assert n_calls == 1, f"traced {n_calls} pallas_calls"
 
 
 @pytest.fixture(scope="module")
@@ -175,7 +213,7 @@ def tile_batches():
 
 def test_mgk_gram_tile_matches_direct_and_per_pair(tile_batches):
     """mgk_pairs_sparse(gram_tile=...) vs the LAPACK oracle (mgk_direct)
-    and the per-pair sparse solve, both modes."""
+    and the per-pair sparse solve."""
     from repro.core.graph import Graph
     from repro.core.reference import mgk_direct
     (Bi, Bj), g1u, g2u, g1f, g2f = tile_batches
@@ -192,23 +230,18 @@ def test_mgk_gram_tile_matches_direct_and_per_pair(tile_batches):
     direct = np.array([
         mgk_direct(to_graph(g1u, b // Bj), to_graph(g2u, b % Bj), VK, EK)
         for b in range(Bi * Bj)])
-    for mode, ek in (("elementwise", None), ("mxu", EK)):
-        a1 = row_panel_packs_for_batch(g1u, edge_kernel=ek)
-        a2 = row_panel_packs_for_batch(g2u, edge_kernel=ek)
-        res = mgk_pairs_sparse(g1f, g2f, a1, a2, VK, EK,
-                               sparse_mode=mode, tol=1e-10,
-                               gram_tile=(Bi, Bj))
-        np.testing.assert_allclose(np.asarray(res.values), direct,
-                                   rtol=1e-4, err_msg=mode)
-        p1 = row_panel_packs_for_batch(g1f, edge_kernel=ek)
-        p2 = row_panel_packs_for_batch(g2f, edge_kernel=ek)
-        ref = mgk_pairs_sparse(g1f, g2f, p1, p2, VK, EK,
-                               sparse_mode=mode, tol=1e-10)
-        np.testing.assert_allclose(np.asarray(res.values),
-                                   np.asarray(ref.values), rtol=1e-5,
-                                   err_msg=mode)
-        assert np.array_equal(np.asarray(res.iterations),
-                              np.asarray(ref.iterations))
+    a1 = row_panel_packs_for_batch(g1u)
+    a2 = row_panel_packs_for_batch(g2u)
+    res = mgk_pairs_sparse(g1f, g2f, a1, a2, VK, EK, tol=1e-10,
+                           gram_tile=(Bi, Bj))
+    np.testing.assert_allclose(np.asarray(res.values), direct, rtol=1e-4)
+    p1 = row_panel_packs_for_batch(g1f)
+    p2 = row_panel_packs_for_batch(g2f)
+    ref = mgk_pairs_sparse(g1f, g2f, p1, p2, VK, EK, tol=1e-10)
+    np.testing.assert_allclose(np.asarray(res.values),
+                               np.asarray(ref.values), rtol=1e-5)
+    assert np.array_equal(np.asarray(res.iterations),
+                          np.asarray(ref.iterations))
 
 
 def test_gram_tile_adjoint_grads_match_per_pair(tile_batches):
@@ -218,15 +251,14 @@ def test_gram_tile_adjoint_grads_match_per_pair(tile_batches):
     from repro.core.adjoint import kernel_theta, mgk_value_fn
     (Bi, Bj), g1u, g2u, g1f, g2f = tile_batches
     theta = kernel_theta(VK, EK)
-    a1 = row_panel_packs_for_batch(g1u, edge_kernel=EK)
-    a2 = row_panel_packs_for_batch(g2u, edge_kernel=EK)
-    p1 = row_panel_packs_for_batch(g1f, edge_kernel=EK)
-    p2 = row_panel_packs_for_batch(g2f, edge_kernel=EK)
+    a1 = row_panel_packs_for_batch(g1u)
+    a2 = row_panel_packs_for_batch(g2u)
+    p1 = row_panel_packs_for_batch(g1f)
+    p2 = row_panel_packs_for_batch(g2f)
     fn_t = mgk_value_fn(g1f, g2f, VK, EK, method="sparse", packs1=a1,
-                        packs2=a2, sparse_mode="mxu",
-                        gram_tile=(Bi, Bj))
+                        packs2=a2, gram_tile=(Bi, Bj))
     fn_p = mgk_value_fn(g1f, g2f, VK, EK, method="sparse", packs1=p1,
-                        packs2=p2, sparse_mode="mxu")
+                        packs2=p2)
     vt, gt = fn_t.value_and_pair_grads(theta)
     vp, gp = fn_p.value_and_pair_grads(theta)
     np.testing.assert_allclose(np.asarray(vt), np.asarray(vp), rtol=1e-5)
@@ -312,8 +344,8 @@ def test_mgk_segmented_sparse_gram_tile(tile_batches):
     lockstep, strictly fewer pair-matvec evaluations; per-pair packs
     path included."""
     (Bi, Bj), g1u, g2u, g1f, g2f = tile_batches
-    a1 = row_panel_packs_for_batch(g1u, edge_kernel=EK)
-    a2 = row_panel_packs_for_batch(g2u, edge_kernel=EK)
+    a1 = row_panel_packs_for_batch(g1u)
+    a2 = row_panel_packs_for_batch(g2u)
     lock = mgk_pairs_sparse(g1f, g2f, a1, a2, VK, EK, tol=1e-10,
                             gram_tile=(Bi, Bj))
     its = np.asarray(lock.iterations)
@@ -326,8 +358,8 @@ def test_mgk_segmented_sparse_gram_tile(tile_batches):
     assert np.array_equal(its, np.asarray(seg.iterations))
     assert int(seg.matvec_pairs) < int(lock.matvec_pairs)
     # per-pair packs, same contract
-    p1 = row_panel_packs_for_batch(g1f, edge_kernel=EK)
-    p2 = row_panel_packs_for_batch(g2f, edge_kernel=EK)
+    p1 = row_panel_packs_for_batch(g1f)
+    p2 = row_panel_packs_for_batch(g2f)
     seg_p = mgk_pairs_sparse_segmented(g1f, g2f, p1, p2, VK, EK,
                                        tol=1e-10, segment_size=4)
     np.testing.assert_allclose(np.asarray(seg_p.values),

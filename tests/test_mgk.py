@@ -11,7 +11,7 @@ from repro.core import (KroneckerDelta, SquareExponential, batch_from_graphs,
 from repro.core.mgk import mgk_pairs_sparse
 from repro.core.reference import mgk_direct, mgk_walk_sum
 from repro.data import make_drugbank_like_dataset, make_synthetic_dataset
-from repro.kernels.ops import packs_for_batch
+from repro.kernels.ops import row_panel_packs_for_batch
 
 VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=12)
@@ -118,8 +118,8 @@ def test_sparse_path_matches_dense():
     gs = [g for g in gs if g.n_nodes >= 6][:4]
     a = batch_from_graphs(gs[:2], pad_to=64)
     b = batch_from_graphs(gs[2:], pad_to=64)
-    packs_a = packs_for_batch(a)
-    packs_b = packs_for_batch(b)
+    packs_a = row_panel_packs_for_batch(a)
+    packs_b = row_panel_packs_for_batch(b)
     rs = mgk_pairs_sparse(a, b, packs_a, packs_b, VK, EK, tol=1e-12)
     rd = mgk_pairs(a, b, VK, EK, method="full", tol=1e-12)
     np.testing.assert_allclose(np.asarray(rs.values),

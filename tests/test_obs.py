@@ -19,14 +19,14 @@ from repro.distributed.faults import FaultInjector, FaultPlan
 VK = KroneckerDelta(0.5, n_labels=8)
 EK = SquareExponential(1.0, rank=10)
 
-# "gram-tile" names the MXU contraction, "gram-tile-vpu" runs what
-# "auto" picks: the elementwise one; both pin the octile edge at 8.
+# "gram-tile" streams bf16 packs (the benchmark's bf16-packs control),
+# "gram-tile-vpu" f32 packs; both pin the octile edge at 8.
 # "gram-tile-edge" leaves the edge to the step: t = 16 on the 16-node
 # bucket
 CASES = {
     "lowrank": dict(method="lowrank", pairs_per_block=3),
     "gram-tile": dict(method="pallas_sparse", gram_tile=True,
-                      tile_shape=(2, 2), sparse_mode="mxu", tile=8),
+                      tile_shape=(2, 2), pack_dtype="bfloat16", tile=8),
     "gram-tile-vpu": dict(method="pallas_sparse", gram_tile=True,
                           tile_shape=(2, 2), tile=8),
     "gram-tile-edge": dict(method="pallas_sparse", gram_tile=True,
@@ -167,11 +167,11 @@ def test_lowrank_counts_batches_and_one_read_per_block(builds):
     assert "pack_cache.miss" not in b["spent"]
 
 
-def _check_gram_tile_counts(b, edge_kernel, tile=8):
+def _check_gram_tile_counts(b, tile=8, pack_dtype=None):
     """Pack-cache lookups, blocking reads and bytes sent of a Gram-tile
-    build, counted from its blocks; ``edge_kernel`` is the pack cache's
-    (None: no weighted operands), ``tile`` the octile edge every block
-    ran at. Returns the number of blocks."""
+    build, counted from its blocks; ``tile`` is the octile edge every
+    block ran at, ``pack_dtype`` the packs' stored dtype. Returns the
+    number of blocks."""
     ds, blocks = b["ds"], b["drv"].blocks()
     axes = [(np.unique(blk.rows), np.unique(blk.cols), blk.pad_row)
             for blk in blocks]
@@ -183,12 +183,12 @@ def _check_gram_tile_counts(b, edge_kernel, tile=8):
     assert b["spent"]["host_syncs"] == sum(
         2 * (len(r) + len(c)) + 1 for r, c, _ in axes)
     # both pair batches of each block, then each axis's stacked pack
-    cache = GraphPackCache(tile=tile, edge_kernel=edge_kernel)
+    cache = GraphPackCache(tile=tile, pack_dtype=pack_dtype)
     packs = 0
     for (r, c, pad), blk in zip(axes, blocks):
         for idx in (r, c):
             pack = cache.stacked_axis(idx, ds.batch(idx, pad_to=pad))
-            packs += sum(x.nbytes for x in pack if x is not None)
+            packs += sum(x.nbytes for x in pack)
     assert b["spent"]["h2d_bytes"] == packs + sum(
         _batch_bytes(ds, blk.rows, blk.pad_row)
         + _batch_bytes(ds, blk.cols, blk.pad_col) for blk in blocks)
@@ -196,21 +196,21 @@ def _check_gram_tile_counts(b, edge_kernel, tile=8):
 
 
 def test_gram_tile_counts_packs_slices_and_label_checks(builds):
-    """The MXU step: weighted packs, and no label check (the step runs
-    the contraction it is given), so no label reads."""
+    """The bf16-pack step: half the pack bytes of f32 packs sent, and no
+    label check, so no label reads beyond the axis slices."""
     b = builds["gram-tile"]
-    n_blocks = _check_gram_tile_counts(b, EK)
-    assert b["spent"]["xmv.contraction.mxu"] == n_blocks
-    assert "xmv.contraction.elementwise" not in b["spent"]
+    n_blocks = _check_gram_tile_counts(b, pack_dtype="bfloat16")
+    assert b["spent"]["xmv.octile_edge.8"] == n_blocks
 
 
 def test_gram_tile_elementwise_counts_packs_and_slices(builds):
-    """The step "auto" runs: unweighted packs, the elementwise
-    contraction on every block."""
+    """The f32-pack step: adjacency and label tiles sent once per axis
+    graph per block, and no contraction counter (the step has one
+    contraction)."""
     b = builds["gram-tile-vpu"]
-    n_blocks = _check_gram_tile_counts(b, None)
-    assert b["spent"]["xmv.contraction.elementwise"] == n_blocks
-    assert "xmv.contraction.mxu" not in b["spent"]
+    n_blocks = _check_gram_tile_counts(b)
+    assert b["spent"]["xmv.octile_edge.8"] == n_blocks
+    assert not [k for k in b["spent"] if k.startswith("xmv.contraction.")]
 
 
 def test_octile_edge_counts_once_per_block_solve(builds):
@@ -224,7 +224,7 @@ def test_octile_edge_counts_once_per_block_solve(builds):
                  if k.startswith("xmv.octile_edge.")}
         assert edges == {"xmv.octile_edge.8": len(b["drv"].blocks())}
     b = builds["gram-tile-edge"]
-    n_blocks = _check_gram_tile_counts(b, None, tile=16)
+    n_blocks = _check_gram_tile_counts(b, tile=16)
     edges = {k: v for k, v in b["drv"].health["counters"].items()
              if k.startswith("xmv.octile_edge.")}
     assert edges == {"xmv.octile_edge.16": n_blocks}

@@ -31,7 +31,7 @@ CP = CompactPolynomial(1.0)
 BACKENDS = [
     ("full", SE), ("elementwise", SE), ("lowrank", SE),
     ("pallas", SE), ("pallas", CP),
-    ("sparse_vpu", CP), ("sparse_vpu", SE), ("sparse_mxu", SE),
+    ("sparse_vpu", CP), ("sparse_vpu", SE), ("sparse_gram_tile", SE),
     ("adaptive", SE), ("adaptive", CP),
 ]
 
@@ -45,14 +45,19 @@ def _graph_pair(gtype: str, n: int, seed: int, q: float):
 def _run_backend(mode, ek, g1b, g2b):
     if mode == "adaptive":
         return mgk_adaptive(g1b, g2b, VK, ek, tol=1e-12)
+    if mode == "sparse_gram_tile":
+        # a 1 x 1 Gram tile at the largest edge the pads allow, as the
+        # Gram driver picks it
+        n = g1b.adjacency.shape[1]
+        t = next(t for t in (32, 16, 8) if n % t == 0)
+        p1 = row_panel_packs_for_batch(g1b, tile=t)
+        p2 = row_panel_packs_for_batch(g2b, tile=t)
+        return mgk_pairs_sparse(g1b, g2b, p1, p2, VK, ek, tol=1e-12,
+                                gram_tile=(1, 1))
     if mode.startswith("sparse"):
-        ek_pack = ek if mode == "sparse_mxu" else None
-        p1 = row_panel_packs_for_batch(g1b, edge_kernel=ek_pack)
-        p2 = row_panel_packs_for_batch(g2b, edge_kernel=ek_pack)
-        return mgk_pairs_sparse(
-            g1b, g2b, p1, p2, VK, ek,
-            sparse_mode="mxu" if mode == "sparse_mxu" else "elementwise",
-            tol=1e-12)
+        p1 = row_panel_packs_for_batch(g1b)
+        p2 = row_panel_packs_for_batch(g2b)
+        return mgk_pairs_sparse(g1b, g2b, p1, p2, VK, ek, tol=1e-12)
     return mgk_pairs(g1b, g2b, VK, ek, method=mode, tol=1e-12)
 
 
@@ -71,7 +76,7 @@ def test_backend_matches_direct_oracle(mode, ek, gtype, n, seed, q):
     res = _run_backend(mode, ek, g1b, g2b)
     ref = mgk_direct(g1, g2, VK, ek)
     # rtol covers f32 accumulation + the SE expansion's rank-12
-    # truncation on the MXU paths
+    # truncation on the lowrank path
     np.testing.assert_allclose(float(res.values[0]), ref, rtol=2e-3)
     assert bool(res.converged.all())
 

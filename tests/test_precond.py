@@ -139,20 +139,17 @@ def test_property_kron_matches_jacobi_solution_dense_routes(seed):
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 100))
 def test_property_kron_matches_jacobi_solution_sparse_routes(seed):
-    """Sparse routes (row-panel VPU / MXU), drugbank-like pairs."""
+    """Sparse route (row-panel), drugbank-like pairs."""
     from repro.kernels.ops import row_panel_packs_for_batch
     g1, g2 = _sparse_batches(seed=4 + seed % 3)
-    for mode, ek_pack in (("elementwise", None), ("mxu", EK)):
-        p1 = row_panel_packs_for_batch(g1, edge_kernel=ek_pack)
-        p2 = row_panel_packs_for_batch(g2, edge_kernel=ek_pack)
-        rj = mgk_pairs_sparse(g1, g2, p1, p2, VKD, EK,
-                              sparse_mode=mode, tol=1e-10)
-        rk = mgk_pairs_sparse(g1, g2, p1, p2, VKD, EK,
-                              sparse_mode=mode, tol=1e-10,
-                              precond="kron")
-        assert bool(np.asarray(rk.converged).all())
-        np.testing.assert_allclose(np.asarray(rj.values),
-                                   np.asarray(rk.values), rtol=1e-5)
+    p1 = row_panel_packs_for_batch(g1)
+    p2 = row_panel_packs_for_batch(g2)
+    rj = mgk_pairs_sparse(g1, g2, p1, p2, VKD, EK, tol=1e-10)
+    rk = mgk_pairs_sparse(g1, g2, p1, p2, VKD, EK, tol=1e-10,
+                          precond="kron")
+    assert bool(np.asarray(rk.converged).all())
+    np.testing.assert_allclose(np.asarray(rj.values),
+                               np.asarray(rk.values), rtol=1e-5)
 
 
 def test_adaptive_routes_accept_precond():
@@ -206,8 +203,8 @@ def test_segmented_matches_lockstep_with_kron(variant, rng):
     from repro.kernels.ops import row_panel_packs_for_batch, \
         take_row_panel_pack
     g1, g2 = _sparse_batches()
-    p1 = row_panel_packs_for_batch(g1, edge_kernel=EK)
-    p2 = row_panel_packs_for_batch(g2, edge_kernel=EK)
+    p1 = row_panel_packs_for_batch(g1)
+    p2 = row_panel_packs_for_batch(g2)
     lock = mgk_pairs_sparse(g1, g2, p1, p2, VKD, EK, tol=1e-10,
                             precond="kron", pcg_variant=variant)
     seg = mgk_pairs_sparse_segmented(g1, g2, p1, p2, VKD, EK, tol=1e-10,
@@ -231,8 +228,8 @@ def test_segmented_gram_tile_with_kron():
     g1f = jax.tree.map(lambda x: jnp.repeat(x, Bj, axis=0), g1u)
     g2f = jax.tree.map(
         lambda x: jnp.tile(x, (Bi,) + (1,) * (x.ndim - 1)), g2u)
-    a1 = row_panel_packs_for_batch(g1u, edge_kernel=EK)
-    a2 = row_panel_packs_for_batch(g2u, edge_kernel=EK)
+    a1 = row_panel_packs_for_batch(g1u)
+    a2 = row_panel_packs_for_batch(g2u)
     lock = mgk_pairs_sparse(g1f, g2f, a1, a2, VKD, EK, tol=1e-10,
                             gram_tile=(Bi, Bj), precond="kron")
     seg = mgk_pairs_sparse_segmented(

@@ -44,14 +44,12 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _packs(sharding, B, n, rank, t=T):
+def _packs(sharding, B, n, dtype, t=T):
     nt = n // t
     k_max = nt        # every tile of a row occupied: the worst case
     return RowPanelPack(
-        values_adj=_spec(sharding, (B, nt, k_max, t, t)),
-        values_lab=_spec(sharding, (B, nt, k_max, t, t)),
-        values_w=None if rank is None else _spec(
-            sharding, (B, nt, k_max, rank, t, t)),
+        values_adj=_spec(sharding, (B, nt, k_max, t, t), dtype),
+        values_lab=_spec(sharding, (B, nt, k_max, t, t), dtype),
         col=_spec(sharding, (B, nt, k_max), jnp.int32),
         count=_spec(sharding, (B, nt), jnp.int32))
 
@@ -61,42 +59,48 @@ def _assert_kernel_compiles(fn, *args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("mode", ["mxu", "elementwise"])
-def test_row_panel_compiles(one_chip, mode):
+# bf16 packs: the benchmark's ``bf16-packs`` control (``pack_dtype``)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_row_panel_compiles(one_chip, dtype):
     """Whole-bucket row-panel matvec, B = 64 pairs of 96-node graphs,
     fused CG epilogue."""
     B, nt = 64, N // T
-    packs = _packs(one_chip, B, N, 12 if mode == "mxu" else None)
+    packs = _packs(one_chip, B, N, dtype)
     P = _spec(one_chip, (B, nt, nt, T, T))
     _assert_kernel_compiles(
         lambda p1, p2, P, d: xmv_row_panel_batched(
-            p1, p2, P, EK, diag=d, mode=mode, interpret=False),
+            p1, p2, P, EK, diag=d, interpret=False),
         packs, packs, P, P)
 
 
-@pytest.mark.parametrize("mode", ["mxu", "elementwise"])
-def test_gram_tile_compiles(one_chip, mode):
+# bf16 packs: the benchmark's ``bf16-packs`` control (``pack_dtype``)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_gram_tile_compiles(one_chip, dtype):
     """8 x 8 Gram tile of 96-node graphs, fused CG epilogue."""
     nt = N // T
-    packs = _packs(one_chip, 8, N, 12 if mode == "mxu" else None)
+    packs = _packs(one_chip, 8, N, dtype)
     P = _spec(one_chip, (8, 8, nt, nt, T, T))
     _assert_kernel_compiles(
         lambda p1, p2, P, d: xmv_gram_tile(
-            p1, p2, P, EK, diag=d, mode=mode, interpret=False),
+            p1, p2, P, EK, diag=d, interpret=False),
         packs, packs, P, P)
 
 
-@pytest.mark.parametrize("mode", ["mxu", "elementwise"])
-def test_gram_tile_compiles_at_the_picked_edge(one_chip, mode):
+# bf16 packs: the benchmark's ``bf16-packs`` control (``pack_dtype``)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_gram_tile_compiles_at_the_picked_edge(one_chip, dtype):
     """The same 8 x 8 Gram tile at t = 32, the octile edge the Gram
     driver picks for 96-node buckets (``[32, 1024]`` accumulators)."""
     t = 32
     nt = N // t
-    packs = _packs(one_chip, 8, N, 12 if mode == "mxu" else None, t)
+    packs = _packs(one_chip, 8, N, dtype, t)
     P = _spec(one_chip, (8, 8, nt, nt, t, t))
     _assert_kernel_compiles(
         lambda p1, p2, P, d: xmv_gram_tile(
-            p1, p2, P, EK, diag=d, mode=mode, interpret=False),
+            p1, p2, P, EK, diag=d, interpret=False),
         packs, packs, P, P)
 
 

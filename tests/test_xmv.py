@@ -13,12 +13,25 @@ from repro.kernels.ref import xmv_ref
 from repro.kernels.xmv_block_sparse import from_tiles, to_tiles
 from repro.kernels.xmv_dense import DENSE_TILE
 from repro.kernels.xmv_dense import xmv_dense as _xmv_dense
-from repro.kernels.xmv_block_sparse import pack_graph, xmv_block_sparse
+from repro.kernels.ops import stack_row_panel_packs
+from repro.kernels.xmv_block_sparse import pack_graph_row_panels, \
+    xmv_row_panel_batched
+
 
 def xmv_dense(A, E, Ap, Ep, P, ek):
     """Node-major view of the tile-major dense kernel."""
     return from_tiles(_xmv_dense(A, E, Ap, Ep,
                                  to_tiles(jnp.asarray(P), DENSE_TILE), ek))
+
+
+def xmv_sparse(A, E, Ap, Ep, P, ek, tile=8):
+    """Node-major view of the row-panel kernel on one pair, run as a
+    bucket of one (``xmv_row_panel_batched``)."""
+    p1 = stack_row_panel_packs([pack_graph_row_panels(A, E, tile=tile)])
+    p2 = stack_row_panel_packs([pack_graph_row_panels(Ap, Ep, tile=tile)])
+    y = xmv_row_panel_batched(p1, p2, to_tiles(jnp.asarray(P), tile)[None],
+                              ek)
+    return from_tiles(y[0])
 
 
 EDGE_KERNELS = [Constant(1.0), SquareExponential(0.8, rank=12),
@@ -78,8 +91,7 @@ def test_pallas_dense_sweep(n, m, dtype, rng):
 def test_pallas_block_sparse_sweep(n, m, density, rng):
     ek = SquareExponential(1.0, rank=10)
     A, E, Ap, Ep, P = _pair(rng, n, m, density=density)
-    y = xmv_block_sparse(pack_graph(A, E), pack_graph(Ap, Ep),
-                         jnp.asarray(P), ek)
+    y = xmv_sparse(A, E, Ap, Ep, P, ek)
     y_ref = xmv_ref(jnp.asarray(A), jnp.asarray(E), jnp.asarray(Ap),
                     jnp.asarray(Ep), jnp.asarray(P), ek)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
@@ -94,8 +106,7 @@ def test_pallas_block_sparse_empty_graph(rng):
     Ap = np.triu(Ap, 1) + np.triu(Ap, 1).T
     Ep = Ap.copy()
     P = rng.random((16, 24)).astype(np.float32)
-    y = xmv_block_sparse(pack_graph(A, E), pack_graph(Ap, Ep),
-                         jnp.asarray(P), ek)
+    y = xmv_sparse(A, E, Ap, Ep, P, ek)
     assert np.allclose(np.asarray(y), 0.0)
 
 
